@@ -7,6 +7,8 @@ import argparse
 
 import jax
 
+from capital_tpu.utils.config import PLATFORM_HELP
+
 
 def main(argv=None) -> None:
     p = argparse.ArgumentParser(prog="capital_tpu.autotune")
@@ -140,7 +142,8 @@ def main(argv=None) -> None:
         help="small: per-config latency samples (harness.latency_samples)",
     )
     p.add_argument("--devices", type=int, default=0)
-    p.add_argument("--platform", default=None)
+    p.add_argument("--platform", default=None,
+                   help=PLATFORM_HELP)
     p.add_argument("--host-devices", type=int, default=0)
     p.add_argument(
         "--ledger", default=None,
@@ -428,4 +431,7 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from capital_tpu.utils import compile_cache
+
+    compile_cache.enable()
     main()

@@ -31,6 +31,7 @@ import sys
 
 from capital_tpu.lint import baseline as baseline_mod
 from capital_tpu.lint import rules
+from capital_tpu.utils.config import PLATFORM_HELP
 
 
 def _report(pass_name: str, findings, args) -> rules.Report:
@@ -212,8 +213,7 @@ def build_parser() -> argparse.ArgumentParser:
     g.add_argument("targets", nargs="*",
                    help="target families: cholinv cacqr serve "
                         "(default: all)")
-    g.add_argument("--platform", default=None,
-                   help="jax platform override (e.g. cpu for the CI gate)")
+    g.add_argument("--platform", default=None, help=PLATFORM_HELP)
     g.add_argument("--tol-ratio", type=float, default=4.0,
                    help="collective-budget per-phase compiled/model ratio")
     g.add_argument("--slack", type=int, default=8,

@@ -476,6 +476,9 @@ class ScriptedReplica:
         self._outbox: list = []
         self._pings = 0
 
+    def claims_accelerator(self) -> bool:
+        return False
+
     def alive(self) -> bool:
         return not self._killed
 

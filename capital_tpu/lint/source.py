@@ -103,7 +103,7 @@ def _attr_chain(node: ast.AST) -> list[str]:
 
 def _is_scope_call(node: ast.AST) -> bool:
     """True for ``scope(...)`` / ``tracing.scope(...)`` context managers
-    (NOT platform_scope / named_scope — those don't tag phases)."""
+    (NOT device_scope / named_scope — those don't tag phases)."""
     if not isinstance(node, ast.Call):
         return False
     fn = node.func

@@ -54,7 +54,7 @@ from capital_tpu.robust import detect
 from capital_tpu.robust.config import RobustConfig
 from capital_tpu.parallel.summa import SyrkArgs, TrmmArgs
 from capital_tpu.parallel.topology import Grid
-from capital_tpu.utils import jax_compat, tracing
+from capital_tpu.utils import tracing
 from capital_tpu.utils.config import BaseCasePolicy
 
 
@@ -419,21 +419,21 @@ def _scoped_base_factor(
                 masking.symmetrize_from(w, "U"), uplo="U"
             )
             return (
-                jax_compat.pcast(R, axes, to="varying"),
-                jax_compat.pcast(Rinv, axes, to="varying"),
+                lax.pcast(R, axes, to="varying"),
+                lax.pcast(Rinv, axes, to="varying"),
             )
 
         def zeros():
             z = jnp.zeros_like(w)
             return (
-                jax_compat.pcast(z, axes, to="varying"),
-                jax_compat.pcast(z, axes, to="varying"),
+                lax.pcast(z, axes, to="varying"),
+                lax.pcast(z, axes, to="varying"),
             )
 
         R, Rinv = lax.cond(on, compute, zeros)
         return lax.psum(R, axes), lax.psum(Rinv, axes)
 
-    return jax_compat.shard_map(
+    return jax.shard_map(
         kernel,
         mesh=grid.mesh,
         in_specs=P(),
@@ -797,7 +797,7 @@ def factor_buffers(
     p = padded_dim(n, cfg.base_case_dim)
     node = plan(p, cfg)
     tile = _zeros_plan(grid, node, cfg)
-    with pallas_tpu.platform_scope(grid.platform):
+    with pallas_tpu.device_scope(grid.mesh.devices.flat[0]):
         if tile:
             with tracing.scope("CI::buffers"):
                 return (

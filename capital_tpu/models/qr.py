@@ -58,7 +58,7 @@ from capital_tpu.parallel.topology import Grid
 from capital_tpu.robust import faultinject, recovery
 from capital_tpu.robust import config as config_mod
 from capital_tpu.robust.config import RobustConfig, RobustInfo
-from capital_tpu.utils import jax_compat, tracing
+from capital_tpu.utils import tracing
 
 
 @dataclasses.dataclass(frozen=True)
@@ -515,7 +515,7 @@ def _cqr2_fused_sharded(
     # from psum'd grams (gated by the mesh tests' residual checks), and the
     # Mosaic path also compiles under check_vma=True (the vma-annotated
     # out_shapes stay for that).
-    Q, R = jax_compat.shard_map(
+    Q, R = jax.shard_map(
         body,
         mesh=grid.mesh,
         in_specs=P(axes, None),

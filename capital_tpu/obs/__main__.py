@@ -50,6 +50,8 @@ import sys
 
 import jax
 
+from capital_tpu.utils.config import PLATFORM_HELP
+
 
 def _build(algo: str, args, grid):
     """(step, operand, cfg, dtype) for one driver config — the same
@@ -841,7 +843,8 @@ def build_parser() -> argparse.ArgumentParser:
                    help="whole-program flops ratio allowance")
     a.add_argument("--no-strict", action="store_true",
                    help="report drift without failing the process")
-    a.add_argument("--platform", default=None)
+    a.add_argument("--platform", default=None,
+                   help=PLATFORM_HELP)
     a.add_argument("--host-devices", type=int, default=0)
     a.set_defaults(fn=_audit)
 
@@ -960,7 +963,8 @@ def build_parser() -> argparse.ArgumentParser:
         help="verify recovery/failure events round-trip through diff "
              "without reading as metric regressions",
     )
-    g.add_argument("--platform", default=None)
+    g.add_argument("--platform", default=None,
+                   help=PLATFORM_HELP)
     g.add_argument("--host-devices", type=int, default=0)
     g.set_defaults(fn=_robust_gate)
     return p

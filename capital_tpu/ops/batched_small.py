@@ -55,8 +55,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from capital_tpu.utils import jax_compat, tracing
+from capital_tpu.utils import tracing
 from capital_tpu.ops.pallas_tpu import (
+    _I0,
     _device_budget,
     _interpret_default,
     precision_dot,
@@ -176,6 +177,13 @@ def _gdot(a, b, ca: int, cb: int, precision):
     )
 
 
+def _fori(steps: int, body, init):
+    """In-kernel fori_loop over [0, steps) with an int32 index: under
+    jax_enable_x64 a Python-int bound makes the index int64, which the
+    Mosaic lowering cannot convert (it recurses until RecursionError)."""
+    return jax.lax.fori_loop(jnp.int32(0), jnp.int32(steps), body, init)
+
+
 def _iota(shape, dim):
     return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
 
@@ -232,14 +240,22 @@ def _chol(S, *, uplo: str, block: int, precision):
             S, R, info = col_step(p * block + t, S, R, info)
         return S, R, info
 
-    S, R, info = jax.lax.fori_loop(
-        0, n // block, body, (S, jnp.zeros_like(S), jnp.int32(0))
+    S, R, info = _fori(
+        n // block, body, (S, jnp.zeros_like(S), jnp.int32(0))
     )
     # off-diagonal contamination with a clean diagonal: the factor_info
     # n+1 convention (robust/detect.py)
-    off_bad = ~jnp.all(jnp.isfinite(R))
+    off_bad = _any_nonfinite(R)
     info = jnp.where((info == 0) & off_bad, jnp.int32(n + 1), info)
     return R, info
+
+
+def _any_nonfinite(M):
+    """Whether any entry of M is NaN/inf, as an f32 max — a boolean
+    reduction traces through a float64 under jax_enable_x64, which Mosaic
+    cannot lower."""
+    one, zero = jnp.float32(1), jnp.float32(0)
+    return jnp.max(jnp.where(jnp.isfinite(M), zero, one)) > zero
 
 
 def _safe_div(d):
@@ -269,7 +285,7 @@ def _fwd_solve(T, B, *, from_upper: bool, block: int, precision):
             Y = col_step(p * block + t, Y)
         return Y
 
-    return jax.lax.fori_loop(0, n // block, body, B)
+    return _fori(n // block, body, B)
 
 
 def _bwd_solve(T, Y, *, from_upper: bool, block: int, precision):
@@ -292,7 +308,7 @@ def _bwd_solve(T, Y, *, from_upper: bool, block: int, precision):
             Y = col_step(n - 1 - (p * block + t), Y)
         return Y
 
-    return jax.lax.fori_loop(0, n // block, body, Y)
+    return _fori(n // block, body, Y)
 
 
 def _rsolve_upper(R, V, *, block: int, precision):
@@ -315,7 +331,7 @@ def _rsolve_upper(R, V, *, block: int, precision):
             W = col_step(p * block + t, W)
         return W
 
-    return jax.lax.fori_loop(0, n // block, body, V)
+    return _fori(n // block, body, V)
 
 
 # --------------------------------------------------------------------------
@@ -328,10 +344,23 @@ def _out_struct(shape, dtype, *operands):
     varying mesh axes so the kernels stay legal inside shard_map bodies."""
     vma: frozenset = frozenset()
     for r in operands:
-        vma |= jax_compat.vma_of(r)
+        vma |= jax.typeof(r).vma
     if vma:
         return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
     return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _info_shape(batch: int, width: int = 1):
+    """Per-problem int32 info output laid out as (batch, 1, width): its
+    (1, 1, width) block spans the array's last two dims, the form Mosaic
+    accepts for a batch-indexed block (a (1, 1) block of a (batch, 1)
+    array is refused).  Kernels store it whole with `_store_info` —
+    Mosaic cannot store a scalar to VMEM."""
+    return (batch, 1, width), jnp.int32
+
+
+def _store_info(ref, info):
+    ref[0] = jnp.broadcast_to(info, ref.shape[1:]).astype(jnp.int32)
 
 
 def _bspec(shape):
@@ -339,7 +368,7 @@ def _bspec(shape):
     nd = len(shape)
     return pl.BlockSpec(
         (1,) + tuple(shape[1:]),
-        lambda b, _nd=nd: (b,) + (0,) * (_nd - 1),
+        lambda b, _nd=nd: (b,) + (_I0,) * (_nd - 1),
         memory_space=pltpu.VMEM,
     )
 
@@ -361,8 +390,7 @@ def _batched_call(kernel, inputs, out_shapes, *, interpret, flops,
         in_specs=[_bspec(a.shape) for a in inputs],
         out_specs=[_bspec(s) for s, _ in out_shapes],
         out_shape=[_out_struct(s, d, *inputs) for s, d in out_shapes],
-        compiler_params=jax_compat.pallas_compiler_params(
-            pltpu,
+        compiler_params=pltpu.CompilerParams(
             # problems are independent: the batch dimension is parallel
             # (no cross-step VMEM state — each step's blocks are its own)
             dimension_semantics=("parallel",),
@@ -414,13 +442,13 @@ def potrf(A, *, uplo: str = "U", block: int = 0,
         mask = (_iota((n, n), 0) <= _iota((n, n), 1)) if uplo == "U" else (
             _iota((n, n), 0) >= _iota((n, n), 1))
         r_ref[0] = jnp.where(mask, R, 0.0).astype(a_ref.dtype)
-        info_ref[0, 0] = info
+        _store_info(info_ref, info)
 
     with tracing.scope("OP::batched_small"):
         tracing.emit(flops=batch * tracing.batched_chol_flops(n))
         R, info = _batched_call(
             kernel, [A],
-            [((batch, n, n), A.dtype), ((batch, 1), jnp.int32)],
+            [((batch, n, n), A.dtype), _info_shape(batch)],
             interpret=interpret,
             flops=batch * tracing.batched_chol_flops(n),
             bytes_accessed=batch * 2 * n * n * jnp.dtype(A.dtype).itemsize,
@@ -528,13 +556,13 @@ def posv(A, B, *, uplo: str = "U", block: int = 0,
         x = _bwd_solve(R, y, from_upper=(uplo == "U"), block=bs,
                        precision=precision)
         x_ref[0] = x.astype(b_ref.dtype)
-        info_ref[0, 0] = info
+        _store_info(info_ref, info)
 
     with tracing.scope("SV::fused_posv"):
         tracing.emit(flops=batch * tracing.fused_posv_flops(n, k))
         X, info = _batched_call(
             kernel, [A, B],
-            [((batch, n, k), B.dtype), ((batch, 1), jnp.int32)],
+            [((batch, n, k), B.dtype), _info_shape(batch)],
             interpret=interpret, alias_rhs=True,
             flops=batch * tracing.fused_posv_flops(n, k),
             bytes_accessed=batch * (n * n + 2 * n * k)
@@ -577,13 +605,13 @@ def lstsq(A, B, *, block: int = 0, precision: str | None = "highest",
         R = _gdot(_triu(R2), _triu(R1), 1, 0, precision)  # R2·R1, upper
         x = _bwd_solve(R, t2, from_upper=True, block=bs, precision=precision)
         x_ref[0] = x.astype(b_ref.dtype)
-        info_ref[0, 0] = jnp.maximum(i1, i2)
+        _store_info(info_ref, jnp.maximum(i1, i2))
 
     with tracing.scope("SV::fused_lstsq"):
         tracing.emit(flops=batch * tracing.fused_lstsq_flops(m, n, k))
         X, info = _batched_call(
             kernel, [A, B],
-            [((batch, n, k), B.dtype), ((batch, 1), jnp.int32)],
+            [((batch, n, k), B.dtype), _info_shape(batch)],
             interpret=interpret,
             flops=batch * tracing.fused_lstsq_flops(m, n, k),
             bytes_accessed=batch * (m * n + m * k + n * k)

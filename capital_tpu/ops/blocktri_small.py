@@ -62,8 +62,10 @@ from capital_tpu.ops.batched_small import (
     _chol,
     _fwd_solve,
     _gdot,
+    _info_shape,
     _iota,
     _resolve_block,
+    _store_info,
     dtype_capable,
 )
 
@@ -152,6 +154,12 @@ def _factor_block(d, c, Lp, *, bs: int, precision):
     return _lower(L), wt, info
 
 
+def _set_info(infos, s: int, info):
+    """Lane `s` of the (1, seg) per-block info row := info (a vector
+    select: Mosaic stores the row once, never a scalar)."""
+    return jnp.where(_iota(infos.shape, 1) == s, info, infos)
+
+
 def _check_steps(name, seg_operands, carries, b, k=None):
     for nm, x, nd in seg_operands:
         if x.ndim != 4 or x.shape[2:] != (b, b):
@@ -198,6 +206,7 @@ def fused_forward_step(D, C, B, Lc, yc, *, block: int = 0,
                l_ref, wt_ref, y_ref, info_ref):
         Lp = lc_ref[0].astype(jnp.float32)
         yp = yc_ref[0].astype(jnp.float32)
+        infos = jnp.zeros((1, seg), jnp.int32)
         for s in range(seg):
             d = d_ref[0, s].astype(jnp.float32)
             c = c_ref[0, s].astype(jnp.float32)
@@ -209,21 +218,22 @@ def fused_forward_step(D, C, B, Lc, yc, *, block: int = 0,
             l_ref[0, s] = L.astype(d_ref.dtype)
             wt_ref[0, s] = wt.astype(d_ref.dtype)
             y_ref[0, s] = y.astype(b_ref.dtype)
-            info_ref[0, s] = info
+            infos = _set_info(infos, s, info)
             Lp, yp = L, y
+        _store_info(info_ref, infos)
 
     item = jnp.dtype(B.dtype).itemsize
     L, Wt, y, info = _batched_call(
         kernel, [D, C, B, Lc, yc],
         [((batch, seg, b, b), D.dtype), ((batch, seg, b, b), D.dtype),
-         ((batch, seg, b, k), B.dtype), ((batch, seg), jnp.int32)],
+         ((batch, seg, b, k), B.dtype), _info_shape(batch, seg)],
         interpret=interpret,
         flops=batch * (tracing.blocktri_chol_flops(seg, b)
                        + tracing.blocktri_solve_flops(seg, b, k)),
         bytes_accessed=batch * item
         * (2 * seg * (2 * b * b + b * k) + b * b + b * k),
     )
-    return L, Wt, y, info
+    return L, Wt, y, info.reshape(batch, seg)
 
 
 def factor_step(D, C, Lc, *, block: int = 0,
@@ -242,25 +252,27 @@ def factor_step(D, C, Lc, *, block: int = 0,
 
     def kernel(d_ref, c_ref, lc_ref, l_ref, wt_ref, info_ref):
         Lp = lc_ref[0].astype(jnp.float32)
+        infos = jnp.zeros((1, seg), jnp.int32)
         for s in range(seg):
             d = d_ref[0, s].astype(jnp.float32)
             c = c_ref[0, s].astype(jnp.float32)
             L, wt, info = _factor_block(d, c, Lp, bs=bs, precision=precision)
             l_ref[0, s] = L.astype(d_ref.dtype)
             wt_ref[0, s] = wt.astype(d_ref.dtype)
-            info_ref[0, s] = info
+            infos = _set_info(infos, s, info)
             Lp = L
+        _store_info(info_ref, infos)
 
     item = jnp.dtype(D.dtype).itemsize
     L, Wt, info = _batched_call(
         kernel, [D, C, Lc],
         [((batch, seg, b, b), D.dtype), ((batch, seg, b, b), D.dtype),
-         ((batch, seg), jnp.int32)],
+         _info_shape(batch, seg)],
         interpret=interpret,
         flops=batch * tracing.blocktri_chol_flops(seg, b),
         bytes_accessed=batch * item * (4 * seg * b * b + b * b),
     )
-    return L, Wt, info
+    return L, Wt, info.reshape(batch, seg)
 
 
 def forward_solve_step(L, Wt, B, yc, *, block: int = 0,

@@ -72,23 +72,27 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from capital_tpu.utils import jax_compat
+
+#: Block-index zero for BlockSpec index maps.  Index maps must return int32
+#: (Mosaic's grid indices): under jax_enable_x64 a bare Python 0 traces to
+#: an int64 literal that Mosaic cannot lower ("func.return (i32, i64)").
+_I0 = np.int32(0)
 
 # Platform resolution for interpret/tile decisions.  The process default
 # backend is the wrong thing to key off in a mixed environment: a CPU mesh in
-# a TPU-backed process (the driver's dryrun_multichip with
-# --xla_force_host_platform_device_count) would pick the Mosaic lowering and
-# die with "Only interpret mode is supported on CPU backend".  Kernels must
-# follow the platform of the devices that will run them — threaded from the
-# Grid via `platform_scope` (every grid-taking entry point is wrapped with
-# `scoped_by_grid`); direct kernel calls without a scope fall back to the
-# process default.
+# a TPU-backed process would pick the Mosaic lowering and die with "Only
+# interpret mode is supported on CPU backend", and a program compiled for a
+# described (not attached) TPU would get the interpreter.  Kernels must
+# follow the platform and device kind of the devices that will run them —
+# threaded from the Grid via `device_scope` (every grid-taking entry point
+# is wrapped with `scoped_by_grid`); direct kernel calls without a scope
+# fall back to the process default.
 # A ContextVar, not a module list: JAX permits tracing from multiple
 # threads, and a shared stack would leak one thread's platform into
-# another's kernels.
-_PLATFORM_SCOPE: contextvars.ContextVar[tuple[str, ...]] = contextvars.ContextVar(
-    "capital_tpu_platform_scope", default=()
-)
+# another's kernels.  Entries are (platform, device_kind).
+_PLATFORM_SCOPE: contextvars.ContextVar[
+    tuple[tuple[str, str], ...]
+] = contextvars.ContextVar("capital_tpu_platform_scope", default=())
 
 
 def _default_backend() -> str:
@@ -98,13 +102,13 @@ def _default_backend() -> str:
 
 
 @contextlib.contextmanager
-def platform_scope(platform: str | None):
-    """Resolve interpret-mode and tile-budget decisions against `platform`
-    (e.g. the mesh devices' platform) instead of jax.default_backend()."""
-    if platform is None:
-        yield
-        return
-    token = _PLATFORM_SCOPE.set(_PLATFORM_SCOPE.get() + (platform,))
+def device_scope(device):
+    """Resolve interpret-mode and tile-budget decisions against `device`'s
+    ``platform`` and ``device_kind`` (a real or a described device)
+    instead of jax.default_backend() and jax.devices()."""
+    token = _PLATFORM_SCOPE.set(
+        _PLATFORM_SCOPE.get() + ((device.platform, device.device_kind),)
+    )
     try:
         yield
     finally:
@@ -114,11 +118,12 @@ def platform_scope(platform: str | None):
 def scoped_by_grid(fn):
     """Decorator for `fn(grid, ...)` entry points: every Pallas call traced
     inside runs under the grid's platform scope, so a CPU mesh gets the
-    interpreter even when the process default backend is a TPU."""
+    interpreter even when the process default backend is a TPU, and a
+    described TPU gets Mosaic with its own tile budget."""
 
     @functools.wraps(fn)
     def wrapper(grid, *args, **kwargs):
-        with platform_scope(grid.platform):
+        with device_scope(grid.mesh.devices.flat[0]):
             return fn(grid, *args, **kwargs)
 
     return wrapper
@@ -126,7 +131,12 @@ def scoped_by_grid(fn):
 
 def _platform() -> str:
     stack = _PLATFORM_SCOPE.get()
-    return stack[-1] if stack else _default_backend()
+    return stack[-1][0] if stack else _default_backend()
+
+
+def _device_kind() -> str:
+    stack = _PLATFORM_SCOPE.get()
+    return stack[-1][1] if stack else jax.devices()[0].device_kind
 
 
 def _interpret_default() -> bool:
@@ -137,22 +147,39 @@ def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def _device_budget() -> tuple[int, int | None]:
-    """(max square tile, vmem_limit_bytes) for this backend's chips.
+#: (max square tile, vmem_limit_bytes) per TPU ``device_kind`` (the
+#: spellings jax's own pallas tpu_info knows).  v5e/v5p/v6e measured:
+#: Mosaic's default scoped-VMEM budget (16MB) rejects 1024-square
+#: double-buffered tiles, but these chips accept a raised limit and the
+#: large tiles are what reach peak — at 8192^2 bf16, (1024,1024,1024) @
+#: 100MB runs the dense kernel at 171 TF/s vs 160 for (512,512,2048) @
+#: default (XLA's own gemm: 167), trmm 140 / syrk 142 TF/s useful vs
+#: 124/132.  v4 keeps the conservative 512 tiles and Mosaic's own limit.
+_TILE_BUDGET: dict[str, tuple[int, int | None]] = {
+    "TPU v5 lite": (1024, 100 * 2**20),
+    "TPU v5e": (1024, 100 * 2**20),
+    "TPU v5": (1024, 100 * 2**20),
+    "TPU v5p": (1024, 100 * 2**20),
+    "TPU v6 lite": (1024, 100 * 2**20),
+    "TPU v6e": (1024, 100 * 2**20),
+    "TPU v4": (512, None),
+}
 
-    v5e/v6 measured: Mosaic's default scoped-VMEM budget (16MB) rejects
-    1024-square double-buffered tiles, but these chips accept a raised limit
-    and the large tiles are what reach peak — at 8192^2 bf16,
-    (1024,1024,1024) @ 100MB runs the dense kernel at 171 TF/s vs 160 for
-    (512,512,2048) @ default (XLA's own gemm: 167), trmm 140 / syrk 142 TF/s
-    useful vs 124/132.  Other/unknown chips keep the conservative 512 tiles
-    and Mosaic's own limit, which fit everywhere."""
+
+def _device_budget() -> tuple[int, int | None]:
+    """(max square tile, vmem_limit_bytes) for the scoped device kind
+    (`_TILE_BUDGET`).  Off-TPU (interpret mode) the tiles are 512 and no
+    limit applies; a TPU kind missing from the table is an error."""
     if _platform() != "tpu":
         return 512, None
-    kind = jax.devices("tpu")[0].device_kind.lower()
-    if any(t in kind for t in ("v5 lite", "v5e", "v5p", "v6")):
-        return 1024, 100 * 2**20
-    return 512, None
+    kind = _device_kind()
+    try:
+        return _TILE_BUDGET[kind]
+    except KeyError:
+        raise ValueError(
+            f"no tile budget for TPU device kind {kind!r}: add it to "
+            "pallas_tpu._TILE_BUDGET"
+        ) from None
 
 
 def default_blocks(
@@ -513,8 +540,7 @@ def sched_matmul(
             transcendentals=0,
         ),
         interpret=interpret,
-        compiler_params=jax_compat.pallas_compiler_params(
-            pltpu,
+        compiler_params=pltpu.CompilerParams(
             # q sweeps distinct output tiles of the dense side — no
             # cross-step VMEM state, so it is parallel (same semantics as
             # the static trmm_kernel below); only the pair dimension p
@@ -561,7 +587,7 @@ def write_diag_blocks(
         kernel,
         grid=(count,),
         in_specs=[
-            pl.BlockSpec((1, s, s), lambda q: (q, 0, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, s, s), lambda q: (q, _I0, _I0), memory_space=pltpu.VMEM),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((s, s), lambda q: (q, q), memory_space=pltpu.VMEM),
@@ -803,7 +829,8 @@ def fused_tail(
     from capital_tpu.utils import tracing
 
     bs = batched_small._resolve_block(n, block)
-    io, do = off // n, dest // n
+    # int32 block indices (a bare Python int traces to i64 under x64)
+    io, do = np.int32(off // n), np.int32(dest // n)
 
     def kernel(w_ref, rp_ref, rip_ref, r_out, ri_out, info_ref):
         del rp_ref, rip_ref  # aliased storage; never read
@@ -822,7 +849,7 @@ def fused_tail(
         upper = r <= c
         r_out[:] = jnp.where(upper, R, 0.0).astype(r_out.dtype)
         ri_out[:] = jnp.where(upper, Rinv, 0.0).astype(ri_out.dtype)
-        info_ref[0, 0] = info
+        info_ref[...] = jnp.broadcast_to(info, (1, 1))  # no scalar VMEM stores
 
     Rp2, RIp2, info = pl.pallas_call(
         kernel,
@@ -835,7 +862,7 @@ def fused_tail(
         out_specs=[
             pl.BlockSpec((n, n), lambda q: (do, do), memory_space=pltpu.VMEM),
             pl.BlockSpec((n, n), lambda q: (do, do), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1), lambda q: (0, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1), lambda q: (_I0, _I0), memory_space=pltpu.VMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(Rp.shape, Rp.dtype),
@@ -848,8 +875,7 @@ def fused_tail(
             bytes_accessed=3 * n * n * jnp.dtype(Rp.dtype).itemsize,
             transcendentals=n,
         ),
-        compiler_params=jax_compat.pallas_compiler_params(
-            pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_device_budget()[1],
         ),
@@ -1111,8 +1137,7 @@ def tri_matmul(
                 memory_space=pltpu.VMEM,
             ),
             input_output_aliases=aliases,
-            compiler_params=jax_compat.pallas_compiler_params(
-                pltpu,
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
                 vmem_limit_bytes=vmem_limit,
             ),
@@ -1203,8 +1228,7 @@ def tri_matmul(
             cost_estimate=common["cost_estimate"],
             input_output_aliases=aliases,
             interpret=interpret,
-            compiler_params=jax_compat.pallas_compiler_params(
-                pltpu,
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary", "arbitrary"),
                 vmem_limit_bytes=vmem_limit,
             ),
@@ -1310,8 +1334,7 @@ def tri_matmul(
             cost_estimate=common["cost_estimate"],
             input_output_aliases=aliases,
             interpret=interpret,
-            compiler_params=jax_compat.pallas_compiler_params(
-                pltpu,
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary"),
                 vmem_limit_bytes=vmem_limit,
             ),

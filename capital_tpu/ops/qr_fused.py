@@ -49,12 +49,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from capital_tpu.utils import jax_compat
 from capital_tpu.ops.pallas_tpu import (
+    _I0,
     _device_budget,
     _interpret_default,
     _platform,
-    platform_scope,
+    device_scope,
 )
 
 
@@ -86,7 +86,7 @@ def _out_struct(shape, dtype, *operands):
     set is empty and this is a plain ShapeDtypeStruct."""
     vma: frozenset = frozenset()
     for r in operands:
-        vma |= jax_compat.vma_of(r)
+        vma |= jax.typeof(r).vma
     if vma:
         return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
     return jax.ShapeDtypeStruct(shape, dtype)
@@ -182,12 +182,11 @@ def gram_blocked(
         kernel,
         grid=(nsteps,),
         in_specs=[
-            pl.BlockSpec((bm, n), lambda i: (i, 0), memory_space=pltpu.VMEM)
+            pl.BlockSpec((bm, n), lambda i: (i, _I0), memory_space=pltpu.VMEM)
         ],
-        out_specs=pl.BlockSpec((n, n), lambda i: (0, 0), memory_space=pltpu.VMEM),
+        out_specs=pl.BlockSpec((n, n), lambda i: (_I0, _I0), memory_space=pltpu.VMEM),
         out_shape=_out_struct((n, n), acc, A),
-        compiler_params=jax_compat.pallas_compiler_params(
-            pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_device_budget()[1],
         ),
@@ -261,19 +260,18 @@ def scale_gram(
         kernel,
         grid=(nsteps,),
         in_specs=[
-            pl.BlockSpec((bm, n), lambda i: (i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((n, n), lambda i: (0, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((bm, n), lambda i: (i, _I0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((n, n), lambda i: (_I0, _I0), memory_space=pltpu.VMEM),
         ],
         out_specs=[
-            pl.BlockSpec((bm, n), lambda i: (i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((n, n), lambda i: (0, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((bm, n), lambda i: (i, _I0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((n, n), lambda i: (_I0, _I0), memory_space=pltpu.VMEM),
         ],
         out_shape=[
             _out_struct((m, n), A.dtype, A, Rinv),
             _out_struct((n, n), acc, A, Rinv),
         ],
-        compiler_params=jax_compat.pallas_compiler_params(
-            pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_device_budget()[1],
         ),
@@ -329,13 +327,12 @@ def scale_blocked(
         kernel,
         grid=(m // bm,),
         in_specs=[
-            pl.BlockSpec((bm, n), lambda i: (i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((n, n), lambda i: (0, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((bm, n), lambda i: (i, _I0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((n, n), lambda i: (_I0, _I0), memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((bm, n), lambda i: (i, 0), memory_space=pltpu.VMEM),
+        out_specs=pl.BlockSpec((bm, n), lambda i: (i, _I0), memory_space=pltpu.VMEM),
         out_shape=_out_struct((m, n), A.dtype, A, Rinv),
-        compiler_params=jax_compat.pallas_compiler_params(
-            pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_device_budget()[1],
         ),
@@ -391,7 +388,7 @@ def fused_plan(grid, m: int, n: int, mode: str, bm: int = 1024, g: int = 2,
     # resolve interpret/VMEM against the GRID's platform, not the process
     # default: callers outside a scoped entry point (e.g. the multichip
     # dryrun probing eligibility) must not touch the default backend
-    with platform_scope(getattr(grid, "platform", None)):
+    with device_scope(grid.mesh.devices.flat[0]):
         if _interpret_default():
             # interpret mode has no VMEM: applying the hardware envelope
             # here would route the CPU test rig differently from v5e (fused

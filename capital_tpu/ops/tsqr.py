@@ -50,6 +50,7 @@ from jax import lax
 from capital_tpu.ops import batched_small
 from capital_tpu.ops.batched_small import (
     _batched_call,
+    _fori,
     _gdot,
     _iota,
     _oh_row,
@@ -154,7 +155,7 @@ def _house_panel(a, *, block: int, precision):
         ohj = (rows == j).astype(jnp.float32)
         xj = jnp.sum(x * ohj)
         sig = jnp.sqrt(jnp.sum(x * x))
-        alpha = -jnp.where(xj >= 0, 1.0, -1.0) * sig
+        alpha = -jnp.where(xj >= 0, jnp.float32(1), jnp.float32(-1)) * sig
         v = x - alpha * ohj
         vn2 = jnp.sum(v * v)
         v = v * jnp.where(
@@ -172,7 +173,7 @@ def _house_panel(a, *, block: int, precision):
             W, V = col_step(q * block + t, W, V)
         return W, V
 
-    W, V = jax.lax.fori_loop(0, n // block, sweep_body, (W0, V0))
+    W, V = _fori(n // block, sweep_body, (W0, V0))
 
     # R = top n rows of the swept panel, upper-masked (sub-diagonal residue
     # is reflector roundoff, exactly like geqrf's packed storage)
@@ -193,7 +194,7 @@ def _house_panel(a, *, block: int, precision):
             E = q_step(n - 1 - (q * block + t), E)
         return E
 
-    Q = jax.lax.fori_loop(0, n // block, q_body, E0)
+    Q = _fori(n // block, q_body, E0)
     return Q, R
 
 

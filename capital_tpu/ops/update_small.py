@@ -60,13 +60,17 @@ import jax.numpy as jnp
 
 from capital_tpu.ops.batched_small import (
     SMALL_N_MAX,
+    _any_nonfinite,
     _batched_call,
+    _fori,
     _gdot,
+    _info_shape,
     _iota,
     _oh_row,
     _oh_col,
     _resolve_block,
     _safe_div,
+    _store_info,
     _triu,
     dtype_capable,
 )
@@ -194,20 +198,18 @@ def _pallas_sweep(R, V, sign: float, *, block, precision, interpret):
         def rank_step(q, carry):
             Rc, info = carry
             v = _gdot(_oh_row(q, k), Vm, 1, 1, precision)  # V[:, q] as row
-            Rc, _, info = jax.lax.fori_loop(
-                0, n // bs, col_block, (Rc, v, info))
+            Rc, _, info = _fori(n // bs, col_block, (Rc, v, info))
             return Rc, info
 
-        Rm, info = jax.lax.fori_loop(
-            0, k, rank_step, (Rm, jnp.int32(0)))
-        off_bad = ~jnp.all(jnp.isfinite(Rm))
+        Rm, info = _fori(k, rank_step, (Rm, jnp.int32(0)))
+        off_bad = _any_nonfinite(Rm)
         info = jnp.where((info == 0) & off_bad, jnp.int32(n + 1), info)
         out_ref[0] = _triu(Rm).astype(r_ref.dtype)
-        info_ref[0, 0] = info
+        _store_info(info_ref, info)
 
     R2, info = _batched_call(
         kernel, [R, V],
-        [((batch, n, n), R.dtype), ((batch, 1), jnp.int32)],
+        [((batch, n, n), R.dtype), _info_shape(batch)],
         interpret=interpret,
         flops=batch * tracing.chol_update_flops(n, k),
         bytes_accessed=batch * (2 * n * n + n * k)

@@ -55,7 +55,7 @@ from jax.sharding import PartitionSpec as P
 
 from capital_tpu.ops import masking, pallas_tpu
 from capital_tpu.parallel.topology import Grid
-from capital_tpu.utils import jax_compat, tracing
+from capital_tpu.utils import tracing
 
 
 @dataclasses.dataclass(frozen=True)
@@ -777,10 +777,10 @@ def _explicit_matmul(
             # union of the operands' axes
             vma: set = set()
             for r in operands:
-                vma |= jax_compat.vma_of(r)
+                vma |= jax.typeof(r).vma
             zeros = jnp.zeros(shape or (mb, nb), dtype=acc_dtype)
             if vma:
-                zeros = jax_compat.pcast(zeros, tuple(sorted(vma)), to="varying")
+                zeros = lax.pcast(zeros, tuple(sorted(vma)), to="varying")
             return lax.cond(live, mm, lambda: zeros)
 
         def matmul_term(live, a_op, b_op):
@@ -936,7 +936,7 @@ def _explicit_matmul(
                 off += wd
         return pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces, axis=1)
 
-    return jax_compat.shard_map(
+    return jax.shard_map(
         kernel,
         mesh=grid.mesh,
         in_specs=(P("x", "y"), P("x", "y")),

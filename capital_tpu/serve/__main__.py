@@ -43,6 +43,8 @@ import time
 
 import jax
 
+from capital_tpu.utils.config import PLATFORM_HELP
+
 
 def _workload(requests: int, seed: int):
     """Deterministic mixed workload touching >= 3 dense n-buckets, all
@@ -283,6 +285,17 @@ def _smoke(args) -> int:
     return 0
 
 
+def _make_replica(args, rid: str, slot: int, cfg):
+    """A replica of the CLI's router: thread replicas spread one per local
+    device (slot i -> jax.devices()[i % count]); process children cannot
+    be pinned and the parent must not touch the chips they need."""
+    from capital_tpu.serve import make_replica
+
+    device = (slot % len(jax.devices())
+              if args.replica_mode == "thread" else None)
+    return make_replica(args.replica_mode, rid, cfg, device=device)
+
+
 def _replicas(args) -> int:
     """Multi-replica router smoke (docs/SERVING.md "Multi-replica
     serving"): N replicas behind one Router sharing --persist-dir, the
@@ -298,7 +311,6 @@ def _replicas(args) -> int:
     from capital_tpu.bench.drivers import _tolerance
     from capital_tpu.serve import loadgen
     from capital_tpu.serve.engine import ServeConfig
-    from capital_tpu.serve.replica import make_replica
     from capital_tpu.serve.router import Router, RouterConfig
 
     cfg = ServeConfig(
@@ -317,7 +329,7 @@ def _replicas(args) -> int:
     specs = loadgen.warmup_specs(wl)
     router = Router(RouterConfig(policy=args.policy))
     for i in range(args.replicas):
-        router.add_replica(make_replica(args.replica_mode, f"r{i}", cfg))
+        router.add_replica(_make_replica(args, f"r{i}", i, cfg))
     fresh = router.warmup(specs)
     print(f"# serve-replicas: warmup fresh compiles {fresh}")
     router.start()
@@ -335,7 +347,8 @@ def _replicas(args) -> int:
             # pump must observe it and re-dispatch, and the replacement
             # must warm from the SHARED disk tier, not recompile
             router.kill_replica("r0")
-            rep = make_replica(args.replica_mode, f"r{args.replicas}", cfg)
+            # the replacement takes over r0's slot (and its chip)
+            rep = _make_replica(args, f"r{args.replicas}", 0, cfg)
             router.add_replica(rep)
             rep_fresh = router.warmup(specs)
             print(f"# serve-replicas: killed r0, replacement "
@@ -594,7 +607,8 @@ def build_parser() -> argparse.ArgumentParser:
     s.add_argument("--seed", type=int, default=0)
     s.add_argument("--ledger", default=None,
                    help="append the request_stats record to this JSONL file")
-    s.add_argument("--platform", default=None)
+    s.add_argument("--platform", default=None,
+                   help=PLATFORM_HELP)
     s.add_argument("--small-n-impl", default="auto",
                    choices=("auto", "vmap", "pallas", "pallas_split"),
                    help="batched implementation for the bucket executables "
@@ -627,7 +641,8 @@ def build_parser() -> argparse.ArgumentParser:
     g.add_argument("--dtype", default="float32")
     g.add_argument("--ledger", default=None,
                    help="append one request_stats record per mode here")
-    g.add_argument("--platform", default=None)
+    g.add_argument("--platform", default=None,
+                   help=PLATFORM_HELP)
     g.add_argument("--small-n-impl", default="auto",
                    choices=("auto", "vmap", "pallas", "pallas_split"))
     g.add_argument("--max-inflight", type=int, default=2,
@@ -687,7 +702,8 @@ def build_parser() -> argparse.ArgumentParser:
     r.add_argument("--ledger", default=None,
                    help="append per-replica + aggregate request_stats "
                         "records here")
-    r.add_argument("--platform", default=None)
+    r.add_argument("--platform", default=None,
+                   help=PLATFORM_HELP)
     r.add_argument("--small-n-impl", default="pallas",
                    choices=("auto", "vmap", "pallas", "pallas_split"),
                    help="pallas (interpret on CPU) keeps every executable "
@@ -722,4 +738,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from capital_tpu.utils import compile_cache
+
+    compile_cache.enable()
     sys.exit(main())

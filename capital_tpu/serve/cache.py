@@ -45,7 +45,7 @@ import logging
 import os
 import pickle
 import uuid
-from typing import Callable, Optional
+from typing import Callable, Optional, Sequence
 
 import jax
 
@@ -78,18 +78,20 @@ def persistable_program(exe) -> bool:
         return False
 
 
-def fingerprint() -> dict:
+def fingerprint(devices) -> dict:
     """What must match for a serialized executable to be loadable: the
-    compiler that produced it and the device it was compiled for."""
+    compiler that produced it and the devices it was compiled for — kind
+    AND ids: a program compiled for one chip does not load onto another,
+    so engines pinned to different chips keep separate entries."""
     import jaxlib
 
-    dev = jax.devices()[0]
     return {
         "entry_version": ENTRY_VERSION,
         "jax": jax.__version__,
         "jaxlib": jaxlib.__version__,
-        "platform": jax.default_backend(),
-        "device": getattr(dev, "device_kind", dev.platform),
+        "platform": devices[0].platform,
+        "device": devices[0].device_kind,
+        "device_ids": [d.id for d in devices],
     }
 
 
@@ -110,10 +112,15 @@ class ExecutableCache:
       non-fatal by contract).
     """
 
-    def __init__(self, persist_dir: Optional[str] = None):
+    def __init__(self, persist_dir: Optional[str] = None,
+                 devices: Optional[Sequence] = None):
         self.persist_dir = persist_dir
+        # the devices a loaded executable runs on (the engine grid's): a
+        # deserialized program is otherwise bound to every device of the
+        # backend and refuses single-device arguments
+        self.devices = list(devices) if devices else jax.devices()[:1]
         self._mem: dict[tuple, object] = {}
-        self._fp = fingerprint() if persist_dir else None
+        self._fp = fingerprint(self.devices) if persist_dir else None
         self.hits = 0
         self.misses = 0
         self.warmup_compiles = 0
@@ -134,6 +141,10 @@ class ExecutableCache:
 
     def __contains__(self, key: tuple) -> bool:
         return key in self._mem
+
+    def items(self) -> list[tuple[tuple, object]]:
+        """(key, executable) pairs of the memory tier."""
+        return list(self._mem.items())
 
     # ---- the one entry point ----------------------------------------------
 
@@ -217,6 +228,7 @@ class ExecutableCache:
                 return None
             exe = serialize_executable.deserialize_and_load(
                 entry["payload"], entry["in_tree"], entry["out_tree"],
+                execution_devices=self.devices,
             )
             self.disk_hits += 1
             return exe

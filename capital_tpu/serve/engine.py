@@ -210,7 +210,8 @@ class SolveEngine:
         # the batch buffer double-resident for the cache entry's lifetime.
         self.validate = validate  # guarded-by: <frozen>
         self.stats = stats.Collector()  # guarded-by: <owner-thread>
-        self.cache = ExecutableCache(cfg.persist_dir)  # guarded-by: <owner-thread>
+        self.cache = ExecutableCache(  # guarded-by: <owner-thread>
+            cfg.persist_dir, devices=list(self.grid.mesh.devices.flat))
         # host-side resident-factor pool (serve/factorcache.py): never part
         # of a traced program, so residency changes never recompile
         self.factors = FactorCache(cfg.factor_cache_bytes)  # guarded-by: <owner-thread>

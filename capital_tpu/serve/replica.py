@@ -83,6 +83,9 @@ class Result:
     #: the request's exported span chain (spans.RequestTrace.asdict() —
     #: plain JSON-safe dict, so it crosses the pipe transport freely)
     trace: Optional[dict] = None
+    #: ids of the devices that held the solution (its addressable shards)
+    #: before marshalling; None for a host-side or failed answer
+    devices: Optional[tuple] = None
 
 
 def _marshal(rid: int, resp) -> dict:
@@ -95,6 +98,9 @@ def _marshal(rid: int, resp) -> dict:
     trace = getattr(resp, "trace", None)
     if trace is not None:
         trace = dict(trace.asdict(), request_id=rid)
+    shards = getattr(resp.x, "addressable_shards", None)
+    devices = (tuple(sorted({sh.device.id for sh in shards}))
+               if shards is not None else None)
     return {
         "request_id": rid,
         "op": resp.op,
@@ -108,18 +114,22 @@ def _marshal(rid: int, resp) -> dict:
         "queue_wait_s": resp.queue_wait_s,
         "device_s": resp.device_s,
         "trace": trace,
+        "devices": devices,
     }
 
 
 def _serve_loop(replica_id: str, cfg_kwargs: dict,
                 recv: Callable[[float], Optional[tuple]],
                 send: Callable[[tuple], None],
-                killed: Callable[[], bool]) -> None:
+                killed: Callable[[], bool],
+                device: Optional[int] = None) -> None:
     """The worker: one engine, one loop.  `recv(timeout)` returns the next
     inbox tuple or None; `send` posts to the outbox; `killed()` polled each
     iteration simulates (thread mode) or observes (process mode never needs
     it) an abrupt crash — the loop exits WITHOUT landing or acking, which
-    is exactly the failure the router's re-dispatch path exists for."""
+    is exactly the failure the router's re-dispatch path exists for.
+    `device` pins the engine to ``jax.devices()[device]`` (default: the
+    engine's own default, device 0)."""
     from capital_tpu.serve.engine import ServeConfig, SolveEngine
 
     robust = cfg_kwargs.get("robust")
@@ -127,7 +137,14 @@ def _serve_loop(replica_id: str, cfg_kwargs: dict,
         from capital_tpu.robust.config import RobustConfig
 
         cfg_kwargs = dict(cfg_kwargs, robust=RobustConfig(**robust))
-    eng = SolveEngine(cfg=ServeConfig(**cfg_kwargs))
+    grid = None
+    if device is not None:
+        import jax
+
+        from capital_tpu.parallel.topology import Grid
+
+        grid = Grid.square(c=1, devices=[jax.devices()[device]])
+    eng = SolveEngine(grid=grid, cfg=ServeConfig(**cfg_kwargs))
     eng.stats.replica_id = replica_id
     outstanding: dict[int, object] = {}  # rid -> Ticket, insertion-ordered
 
@@ -166,6 +183,7 @@ def _serve_loop(replica_id: str, cfg_kwargs: dict,
             fresh = eng.warmup(msg[2])
             send(("warmed", msg[1], {
                 "fresh": fresh, "cache": eng.cache_stats(),
+                "device": eng.grid.mesh.devices.flat[0].id,
             }))
         elif kind == "ping":
             send(("pong", msg[1], {
@@ -221,6 +239,17 @@ def _serve_loop(replica_id: str, cfg_kwargs: dict,
             return
 
 
+def _serve_pinned(device: int, *args) -> None:
+    """`_serve_loop` on ``jax.devices()[device]``: the engine's grid is
+    that chip, and every implicit placement of the worker thread (AOT
+    compiles of unsharded shapes, eager staging, resident factors)
+    follows it — ``jax.default_device`` is per thread."""
+    import jax
+
+    with jax.default_device(jax.devices()[device]):
+        _serve_loop(*args, device=device)
+
+
 class EngineReplica:
     """Parent-side handle: lifecycle + transport for one engine worker.
 
@@ -256,6 +285,12 @@ class EngineReplica:
 
     def _recv_nowait(self) -> Optional[tuple]:
         raise NotImplementedError
+
+    def claims_accelerator(self) -> bool:
+        """Whether this replica's worker starts its own jax runtime that
+        may take the host's chips (a process child).  In-process workers
+        share the parent's runtime: False."""
+        return False
 
     # -- protocol ---------------------------------------------------------
 
@@ -384,8 +419,9 @@ class ThreadReplica(EngineReplica):
     which is exactly the crash race the router's first-wins rule covers).
     """
 
-    def __init__(self, replica_id: str, cfg):
+    def __init__(self, replica_id: str, cfg, device: Optional[int] = None):
         super().__init__(replica_id, cfg)
+        self.device = device
         self._inbox: queue.Queue = queue.Queue()
         self._outbox: queue.Queue = queue.Queue()
         self._killed = threading.Event()
@@ -401,10 +437,13 @@ class ThreadReplica(EngineReplica):
             except queue.Empty:
                 return None
 
+        args = (self.replica_id, cfg_kwargs, recv, self._outbox.put,
+                self._killed.is_set)
+        if self.device is not None:
+            args = (self.device,) + args
         self._thread = threading.Thread(
-            target=_serve_loop,
-            args=(self.replica_id, cfg_kwargs, recv, self._outbox.put,
-                  self._killed.is_set),
+            target=_serve_loop if self.device is None else _serve_pinned,
+            args=args,
             name=f"replica-{self.replica_id}",
             daemon=True,
         )
@@ -469,6 +508,14 @@ class ProcessReplica(EngineReplica):
         self._proc = None
         self._conn = None
 
+    def claims_accelerator(self) -> bool:
+        """Whether the child's jax may take the accelerator: its
+        JAX_PLATFORMS (env override, else inherited) is anything but
+        'cpu'.  Such a child claims every chip of the host."""
+        platforms = (self.env or {}).get(
+            "JAX_PLATFORMS", os.environ.get("JAX_PLATFORMS", ""))
+        return platforms != "cpu"
+
     def start(self) -> None:
         import multiprocessing as mp
 
@@ -510,12 +557,18 @@ class ProcessReplica(EngineReplica):
 
 
 def make_replica(mode: str, replica_id: str, cfg,
-                 env: Optional[dict] = None) -> EngineReplica:
-    """'thread' or 'process' -> a started replica handle (not yet
-    start()ed — the router starts what it registers)."""
+                 env: Optional[dict] = None,
+                 device: Optional[int] = None) -> EngineReplica:
+    """'thread' or 'process' -> a replica handle (not yet start()ed — the
+    router starts what it registers).  `device` pins a thread replica's
+    engine to ``jax.devices()[device]`` — the way to put one replica on
+    each chip of a host; a process child cannot be pinned."""
     if mode == "thread":
-        return ThreadReplica(replica_id, cfg)
+        return ThreadReplica(replica_id, cfg, device=device)
     if mode == "process":
+        if device is not None:
+            raise ValueError("device pinning needs thread replicas: a "
+                             "process child's jax claims every chip")
         return ProcessReplica(replica_id, cfg, env=env)
     raise ValueError(f"unknown replica mode {mode!r}: expected 'thread' "
                      "or 'process'")

@@ -231,6 +231,17 @@ class Router:
             rid = replica.replica_id
             if rid in self._states and not self._states[rid].dead:
                 raise ValueError(f"replica id {rid!r} already registered")
+            if replica.claims_accelerator() and any(
+                    st.replica.claims_accelerator()
+                    for st in self._states.values() if not st.dead):
+                # each child's jax claims EVERY chip of the host, so a
+                # second one fails or hangs at backend start
+                raise ValueError(
+                    f"replica {rid!r}: only one process replica per host "
+                    "may use the accelerator — run thread replicas "
+                    "pinned one per chip (make_replica('thread', ..., "
+                    "device=i)) or give the children JAX_PLATFORMS=cpu"
+                )
             if start and not replica.alive():
                 replica.start()
             lad = replica.ladders()

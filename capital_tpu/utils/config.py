@@ -13,6 +13,13 @@ from __future__ import annotations
 
 import enum
 
+#: help text of every CLI's ``--platform`` flag (the flag sets
+#: ``jax_platforms`` through the config API before the first backend use)
+PLATFORM_HELP = (
+    "JAX platform to run on (e.g. cpu); default: JAX_PLATFORMS, else "
+    "JAX's own choice"
+)
+
 
 class BaseCasePolicy(enum.Enum):
     """Base-case execution strategies (reference cholinv/policy.h:160-514).

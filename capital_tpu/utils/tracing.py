@@ -55,7 +55,8 @@ import jax.numpy as jnp
 @dataclasses.dataclass(frozen=True)
 class DeviceSpec:
     """Per-chip hardware model: peak matmul throughput + interconnect/memory
-    bandwidth.  The analog of the alpha-beta machine parameters critter fits.
+    bandwidth + HBM capacity.  The analog of the alpha-beta machine
+    parameters critter fits.
 
     ``alpha_s`` is the per-collective launch/synchronization latency — the
     alpha of the alpha-beta model (CA-CQR2's S term, arXiv:1710.08471 §2).
@@ -66,35 +67,46 @@ class DeviceSpec:
     name: str
     peak_bf16_tflops: float
     hbm_gbps: float
-    ici_gbps: float  # per-direction aggregate ICI bandwidth per chip
+    ici_gbps: float  # chip-to-chip interconnect bandwidth per chip, GB/s
+    hbm_bytes: float
     alpha_s: float = 1e-6  # per-collective latency (seconds)
 
     def peak_tflops(self, dtype) -> float:
         if jnp.dtype(dtype).itemsize >= 4:
-            return self.peak_bf16_tflops / 2.0
+            return self.peak_bf16_tflops / 2.0  # f32 via 2-pass bf16 (bound)
         return self.peak_bf16_tflops
 
 
-_SPECS = (
-    DeviceSpec("v6e", 918.0, 1640.0, 448.0),
-    DeviceSpec("v6", 918.0, 1640.0, 448.0),
-    DeviceSpec("v5p", 459.0, 2765.0, 600.0),
-    DeviceSpec("v5", 197.0, 819.0, 400.0),
-    DeviceSpec("lite", 197.0, 819.0, 400.0),
-    DeviceSpec("v4", 275.0, 1228.0, 300.0),
-    DeviceSpec("v3", 123.0, 900.0, 200.0),
-    DeviceSpec("cpu", 0.2, 50.0, 10.0),  # virtual-device test rig
-)
-_DEFAULT = DeviceSpec("unknown", 197.0, 819.0, 400.0)
+_V5E = DeviceSpec("v5e", 197.0, 819.0, 200.0, 16e9)
+_V5P = DeviceSpec("v5p", 459.0, 2765.0, 600.0, 95e9)
+_V6E = DeviceSpec("v6e", 918.0, 1640.0, 448.0, 32e9)
+
+#: THE peak table, keyed by jax ``device_kind`` (both spellings jax's pallas
+#: tpu_info knows).  Source: Google Cloud TPU documentation, system
+#: architecture pages "TPU v5e", "TPU v5p", "TPU v6e" — peak bf16 compute
+#: per chip, HBM capacity and bandwidth, inter-chip interconnect bandwidth
+#: (v5e 1,600 Gbit/s = 200 GB/s, v5p 4,800 Gbit/s, v6e 3,584 Gbit/s).
+#: ``cpu`` is the virtual-device test rig's model parameters (relative
+#: ranking only — no CPU number is a device metric).  A kind missing here is
+#: an error (`device_spec`), never a default.
+SPECS: dict[str, DeviceSpec] = {
+    "TPU v5 lite": _V5E, "TPU v5e": _V5E,
+    "TPU v5": _V5P, "TPU v5p": _V5P,
+    "TPU v6 lite": _V6E, "TPU v6e": _V6E,
+    "cpu": DeviceSpec("cpu", 0.2, 50.0, 10.0, 8e9),
+}
 
 
 def device_spec(device: Optional[jax.Device] = None) -> DeviceSpec:
+    """`SPECS` entry of `device` (default: the first JAX device)."""
     device = device or jax.devices()[0]
-    kind = getattr(device, "device_kind", device.platform).lower()
-    for s in _SPECS:
-        if s.name in kind:
-            return s
-    return _DEFAULT
+    try:
+        return SPECS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no DeviceSpec for device kind {device.device_kind!r}: add its "
+            "published peaks to tracing.SPECS"
+        ) from None
 
 
 # --------------------------------------------------------------------------

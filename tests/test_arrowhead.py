@@ -536,7 +536,7 @@ class TestTracing:
                 tracing.emit(flops=1e9)
             with tracing.scope("AH::schur"):
                 tracing.emit(flops=1e9)
-        spec = tracing.DeviceSpec("test", 100.0, 1000.0, 100.0)
+        spec = tracing.DeviceSpec("test", 100.0, 1000.0, 100.0, 16e9)
         one = rec.estimate_seconds(spec, jnp.float32, refine_sweeps=1.0)
         three = rec.estimate_seconds(spec, jnp.float32, refine_sweeps=3.0)
         assert three["IR::residual"][0] == pytest.approx(

@@ -48,7 +48,7 @@ def test_interpret_keys_off_mesh_platform(tpu_default_backend):
     # without a scope the (simulated) TPU default backend selects Mosaic...
     assert pallas_tpu._interpret_default() is False
     # ...but inside a CPU grid's scope the interpreter must win
-    with pallas_tpu.platform_scope("cpu"):
+    with pallas_tpu.device_scope(jax.devices("cpu")[0]):
         assert pallas_tpu._interpret_default() is True
         # and the tile budget must follow the scope too (never touching
         # jax.devices('tpu'), which does not exist on this rig)

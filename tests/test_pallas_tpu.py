@@ -129,6 +129,28 @@ def test_flag_validation(mats):
         tri_matmul(A, B.T)
 
 
+@pytest.mark.parametrize("kind,want", [
+    ("TPU v5 lite", (1024, 100 * 2**20)),
+    ("TPU v4", (512, None)),
+    ("TPU v99", None),  # unknown TPU kind: an error, never a default
+])
+def test_device_budget_reads_scoped_kind(kind, want):
+    """The tile budget follows the scoped device's kind (a described chip
+    works with no TPU attached) and refuses a kind it has no row for."""
+    import types
+
+    from capital_tpu.ops import pallas_tpu
+
+    dev = types.SimpleNamespace(platform="tpu", device_kind=kind)
+    with pallas_tpu.device_scope(dev):
+        assert pallas_tpu._interpret_default() is False
+        if want is None:
+            with pytest.raises(ValueError, match="no tile budget"):
+                pallas_tpu._device_budget()
+        else:
+            assert pallas_tpu._device_budget() == want
+
+
 def test_default_blocks_budget():
     from capital_tpu.ops.pallas_tpu import _device_budget
 

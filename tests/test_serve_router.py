@@ -190,6 +190,81 @@ class TestRouterBasics:
             r.stop()
 
 
+class TestPlacement:
+    """One replica per chip: thread replicas pinned with device=i run
+    their whole engine (compiles, staging, answers) on jax.devices()[i];
+    process children cannot be pinned, so the router admits at most one
+    that may take the accelerator."""
+
+    def test_pinned_thread_replicas_answer_on_their_device(self):
+        import jax
+
+        from capital_tpu.serve.replica import make_replica
+
+        rng = np.random.default_rng(5)
+        r = Router(RouterConfig(policy="least_loaded"))
+        reps = [r.add_replica(make_replica("thread", f"d{i}", _cfg(),
+                                           device=i)) for i in range(4)]
+        try:
+            for i, rep in enumerate(reps):
+                assert rep.warmup(_SPECS)["device"] == jax.devices()[i].id
+            work = [_posv(rng) for _ in range(16)]
+            tickets = [r.submit("posv", A, B) for A, B in work]
+            _pump_until_done(r, tickets)
+            served = set()
+            for (A, B), t in zip(work, tickets):
+                res = t.result(timeout=1.0)
+                assert res.ok, res.error
+                i = int(res.replica_id[1:])
+                assert res.devices == (jax.devices()[i].id,)
+                served.add(i)
+                x = np.asarray(res.x, dtype=np.float64)
+                assert np.linalg.norm(A @ x - B) / np.linalg.norm(B) < 1e-4
+            assert len(served) >= 2
+        finally:
+            r.stop()
+
+    def test_disk_tier_is_per_device(self, tmp_path):
+        # a program compiled for one chip does not load onto another: a
+        # replica pinned elsewhere misses cleanly (never a disk error) and
+        # compiles; a later replica on that same chip disk-hits it
+        from capital_tpu.serve.replica import make_replica
+
+        infos = []
+        for rid, dev in (("a", 0), ("b", 1), ("c", 1)):
+            rep = make_replica("thread", rid, _cfg(tmp_path), device=dev)
+            rep.start()
+            try:
+                infos.append(rep.warmup(_SPECS))
+            finally:
+                rep.stop()
+        (a, b, c) = infos
+        assert a["fresh"] > 0 and b["fresh"] == a["fresh"]
+        assert b["cache"]["disk"]["errors"] == 0
+        assert c["fresh"] == 0 and c["cache"]["disk"]["hits"] == a["fresh"]
+
+    def test_process_replica_cannot_be_pinned(self):
+        from capital_tpu.serve.replica import make_replica
+
+        with pytest.raises(ValueError, match="thread replicas"):
+            make_replica("process", "p0", _cfg(), device=1)
+
+    @pytest.mark.parametrize("platforms,admitted", [("tpu", 1), ("cpu", 2)])
+    def test_one_accelerator_process_per_host(self, platforms, admitted):
+        r = Router()
+        reps = [ProcessReplica(f"p{i}", _cfg(),
+                               env={"JAX_PLATFORMS": platforms})
+                for i in range(2)]
+        r.add_replica(reps[0], start=False)
+        if admitted == 1:
+            with pytest.raises(ValueError, match="one process replica"):
+                r.add_replica(reps[1], start=False)
+        else:
+            r.add_replica(reps[1], start=False)
+        assert len(r.replica_ids()) == admitted
+        assert not any(rep.alive() for rep in reps)
+
+
 class TestFailurePaths:
     def test_crash_redispatch_loses_nothing(self):
         rng = np.random.default_rng(2)

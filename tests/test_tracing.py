@@ -139,7 +139,7 @@ def test_tables_with_empty_rows(tmp_path):
 def test_estimate_seconds_prices_alpha_latency():
     # the comm term is beta (bytes/bandwidth) PLUS alpha per collective;
     # same bytes at a higher synchronization count must cost more
-    spec = tracing.DeviceSpec("test", 100.0, 1000.0, 100.0, alpha_s=1e-6)
+    spec = tracing.DeviceSpec("test", 100.0, 1000.0, 100.0, 16e9, alpha_s=1e-6)
     few, many = tracing.Recorder(), tracing.Recorder()
     with few:
         with tracing.scope("CI::trsm"):
@@ -202,9 +202,16 @@ def test_measure_returns_sane_wall():
 
 
 def test_device_spec_lookup():
+    import types
+
     s = tracing.device_spec(jax.devices("cpu")[0])
     assert s.name == "cpu"
     assert tracing.device_spec().peak_tflops(jnp.float32) > 0
+    v5e = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    assert tracing.device_spec(v5e).peak_tflops(jnp.bfloat16) == 197.0
+    with pytest.raises(ValueError, match="no DeviceSpec"):
+        tracing.device_spec(types.SimpleNamespace(
+            platform="tpu", device_kind="TPU v99"))
 
 
 class TestTriFractions:

@@ -287,11 +287,11 @@ serve-replicas:
 # loadgen leg runs both schedulers with 0.2 s rolling windows and a 60 s
 # deadline, gated on >= 3 serve:window records whose internal coherence
 # (percentile ordering, histogram/count sums) validate_serve_window pins
-# on every read.  obs timeline then proves the chrome-trace export path
-# end to end — it exits non-zero on an empty or malformed trace ledger,
-# so a silently-dead producer can never pass
+# on every read.  obs timeline then renders the chains end to end — it
+# exits non-zero on an empty or malformed trace ledger, so a silently-dead
+# producer can never pass
 serve-trace:
-	rm -f serve_trace.jsonl serve_trace_chrome.json
+	rm -f serve_trace.jsonl
 	$(PY) -m capital_tpu.serve smoke --platform cpu --requests 42 \
 		--trace --bubble-tol-ms 25 --ledger serve_trace.jsonl
 	$(PY) -m capital_tpu.serve loadgen --platform cpu --requests 120 \
@@ -299,8 +299,7 @@ serve-trace:
 		--deadline-ms 60000 --trace --ledger serve_trace.jsonl
 	$(PY) -m capital_tpu.obs serve-report serve_trace.jsonl \
 		--min-trace-complete 1.0 --min-windows 3
-	$(PY) -m capital_tpu.obs timeline serve_trace.jsonl \
-		--chrome serve_trace_chrome.json
+	$(PY) -m capital_tpu.obs timeline serve_trace.jsonl
 
 # breakdown detection / shifted-CholeskyQR recovery / fault-injection suite
 # (docs/ROBUSTNESS.md); CPU rig — tests/conftest.py provides the 8-device
@@ -316,6 +315,6 @@ clean:
 		lint_report.jsonl bench_small.jsonl serve_bench.jsonl serve_cache \
 		bench_trace.jsonl serve_replicas.jsonl serve_replicas_cache \
 		bench_blocktri.jsonl bench_update.jsonl bench_refine.jsonl \
-		bench_arrowhead.jsonl serve_trace.jsonl serve_trace_chrome.json \
+		bench_arrowhead.jsonl serve_trace.jsonl \
 		bench_session.jsonl
 	find . -name __pycache__ -type d -exec rm -rf {} +

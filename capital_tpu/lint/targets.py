@@ -437,8 +437,6 @@ def serve_traced_target(
     not break phase coverage: ``SV::stage`` / ``SV::dispatch`` still name
     every flop.  ``flops_audited=False`` and no donation for the same
     interpret-rig reasons as serve_sched_target."""
-    import time
-
     from capital_tpu.obs import spans
     from capital_tpu.serve import api
     from capital_tpu.utils import tracing
@@ -450,7 +448,7 @@ def serve_traced_target(
     log = spans.TraceLog()
 
     def step(a, b):
-        tr = log.start(0, "posv", time.monotonic())
+        tr = log.start(0, "posv", spans.now())
         with tracing.scope("SV::stage"):
             # pad_operands' identity-tail symmetrization, in-program form
             a_sym = 0.5 * (a + jnp.swapaxes(a, -1, -2))

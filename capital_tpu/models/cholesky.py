@@ -679,8 +679,9 @@ def factor(
         # load-balance experiment
         raise ValueError(f"balance={cfg.balance!r} requires mode='explicit'")
     p = padded_dim(n, cfg.base_case_dim)
-    # SPD-safe pad: diag(A, I) factors to diag(R, I) without cross-talk.
-    Ap = grid.pin(pad_embed_identity(A, n, p))
+    with tracing.scope("CI::io"):
+        # SPD-safe pad: diag(A, I) factors to diag(R, I) without cross-talk.
+        Ap = grid.pin(pad_embed_identity(A, n, p))
     node = plan(p, cfg)
     # fused-tail windows report breakdown through in-kernel info scalars
     # (collected at trace time, combined with the post-hoc scan below —
@@ -702,9 +703,10 @@ def factor(
             perm, pinv = summa.tile_cyclic_perm(p, grid.dx, ptile)
             pj = jnp.asarray(perm)
             unperm = jnp.asarray(pinv)
-            Ap = grid.pin(Ap[pj][:, pj])
-            cbytes, ncoll = tracing.transpose_cost(grid, p, p, Ap.dtype)
-            tracing.emit(comm_bytes=3 * cbytes, collectives=3 * ncoll)
+            with tracing.scope("CI::io"):
+                Ap = grid.pin(Ap[pj][:, pj])
+                cbytes, ncoll = tracing.transpose_cost(grid, p, p, Ap.dtype)
+                tracing.emit(comm_bytes=3 * cbytes, collectives=3 * ncoll)
         else:
             tracing.note("cholinv::persistent_fallback")
 
@@ -728,25 +730,15 @@ def factor(
             # extra shuffles, which is why the flagship out_buffers loop
             # and the persistent layout are documented as an either/or
             # (docs/DISTRIBUTED.md).
-            Rp = grid.pin(Rp[pj][:, pj])
-            RIp = grid.pin(RIp[pj][:, pj])
-            cbytes, ncoll = tracing.transpose_cost(grid, p, p, Rp.dtype)
-            tracing.emit(comm_bytes=2 * cbytes, collectives=2 * ncoll)
+            with tracing.scope("CI::io"):
+                Rp = grid.pin(Rp[pj][:, pj])
+                RIp = grid.pin(RIp[pj][:, pj])
+                cbytes, ncoll = tracing.transpose_cost(grid, p, p, Rp.dtype)
+                tracing.emit(comm_bytes=2 * cbytes, collectives=2 * ncoll)
         _, R, Rinv = _recurse(
-        grid, Ap, 0, node, cfg, True, Rp, RIp, ptile, tail_infos
-    )
-        if ptile:
-            R = R[unperm][:, unperm]
-            Rinv = Rinv[unperm][:, unperm]
-        R, Rinv = grid.pin(R), grid.pin(Rinv)
-        if p != n:
-            R, Rinv = R[:n, :n], Rinv[:n, :n]
-        if cfg.robust is not None:
-            info = detect.factor_info(R)
-            if tail_infos:
-                info = detect.combine_block_infos(info, tail_infos, n)
-            return R, Rinv, info
-        return R, Rinv
+            grid, Ap, 0, node, cfg, True, Rp, RIp, ptile, tail_infos
+        )
+        return _exit(grid, R, Rinv, n, unperm, cfg, tail_infos)
 
     tile = _zeros_plan(grid, node, cfg)
     if tile:
@@ -769,23 +761,32 @@ def factor(
             )
             RIp = pallas_tpu.zeros_dead_lower(p, A.dtype, tile, extra=extra)
     else:
-        Rp = grid.pin(jnp.zeros((p, p), dtype=A.dtype))
-        RIp = grid.pin(jnp.zeros((p, p), dtype=A.dtype))
+        with tracing.scope("CI::buffers"):
+            Rp = grid.pin(jnp.zeros((p, p), dtype=A.dtype))
+            RIp = grid.pin(jnp.zeros((p, p), dtype=A.dtype))
     _, R, Rinv = _recurse(
         grid, Ap, 0, node, cfg, True, Rp, RIp, ptile, tail_infos
     )
-    if ptile:
-        R = R[unperm][:, unperm]
-        Rinv = Rinv[unperm][:, unperm]
-    R, Rinv = grid.pin(R), grid.pin(Rinv)
-    if p != n:
-        R, Rinv = R[:n, :n], Rinv[:n, :n]
-    if cfg.robust is not None:
+    return _exit(grid, R, Rinv, n, unperm, cfg, tail_infos)
+
+
+def _exit(grid, R, Rinv, n, unperm, cfg, tail_infos):
+    """factor's outputs, under CI::io: back in the original order (the
+    persistent layout), the padding cropped, and with cfg.robust the
+    breakdown status of the cropped factor."""
+    with tracing.scope("CI::io"):
+        if unperm is not None:
+            R = R[unperm][:, unperm]
+            Rinv = Rinv[unperm][:, unperm]
+        R, Rinv = grid.pin(R), grid.pin(Rinv)
+        if R.shape[0] != n:
+            R, Rinv = R[:n, :n], Rinv[:n, :n]
+        if cfg.robust is None:
+            return R, Rinv
         info = detect.factor_info(R)
         if tail_infos:
             info = detect.combine_block_infos(info, tail_infos, n)
         return R, Rinv, info
-    return R, Rinv
 
 
 def factor_buffers(
@@ -806,10 +807,11 @@ def factor_buffers(
                 )
     # two DISTINCT buffers: sharing one value between two aliased consumer
     # chains would be the multi-use copy hazard this API exists to avoid
-    return (
-        grid.pin(jnp.zeros((p, p), dtype=dtype)),
-        grid.pin(jnp.zeros((p, p), dtype=dtype)),
-    )
+    with tracing.scope("CI::buffers"):
+        return (
+            grid.pin(jnp.zeros((p, p), dtype=dtype)),
+            grid.pin(jnp.zeros((p, p), dtype=dtype)),
+        )
 
 
 def solve(

@@ -29,9 +29,10 @@ wall split plus bubble_frac — and optionally gates on bubble_frac
 
 ``timeline`` renders the serve:trace records of a ledger (obs/spans.py;
 ``serve smoke --trace`` / ``loadgen --trace`` producers): per-run chain
-completeness, the per-span duration split, SLO-violation attribution, and
-— with ``--chrome out.json`` — a Chrome-trace-event export for
-chrome://tracing / Perfetto waterfall inspection.  It exits 1 when the
+completeness, the per-span duration split, the slowest requests and
+SLO-violation attribution (the waterfall view is the profiler trace itself:
+the program's spans sit there beside the device ops, docs/OBSERVABILITY.md
+"Program spans").  It exits 1 when the
 ledger carries NO serve:trace records (a dead timeline never reads as a
 quiet pass) and 2 on a malformed one.
 
@@ -706,11 +707,10 @@ def _trace_report(args) -> int:
 
 def _timeline(args) -> int:
     """Render the serve:trace records of a ledger: per-run completeness,
-    the per-span duration split, the slowest requests, SLO-violation
-    attribution, and (with --chrome) the Chrome-trace-event export.  Exit
-    2 on a malformed record; exit 1 when the ledger carries NO serve_trace
-    records — a timeline with nothing to show is a producer wiring bug
-    (--trace not passed), never a quiet pass."""
+    the per-span duration split, the slowest requests and SLO-violation
+    attribution.  Exit 2 on a malformed record; exit 1 when the ledger
+    carries NO serve_trace records — a timeline with nothing to show is a
+    producer wiring bug (--trace not passed), never a quiet pass."""
     from collections import Counter, defaultdict
 
     from capital_tpu.obs import ledger, spans
@@ -773,15 +773,6 @@ def _timeline(args) -> int:
         print(
             f"#   SLO violations: {len(viol)}/{len(traces)} — attribution "
             + " ".join(f"{k}={n}" for k, n in attr.most_common())
-        )
-    if args.chrome:
-        chrome = spans.to_chrome(traces)
-        with open(args.chrome, "w") as f:
-            json.dump(chrome, f)
-        print(
-            f"# chrome trace: {len(chrome['traceEvents'])} events -> "
-            f"{args.chrome} (open in chrome://tracing or "
-            "https://ui.perfetto.dev)"
         )
     print(f"# timeline OK ({len(rows)} serve_trace record(s), "
           f"{len(traces)} trace(s))")
@@ -948,12 +939,9 @@ def build_parser() -> argparse.ArgumentParser:
     tl = sub.add_parser(
         "timeline",
         help="render serve:trace span records (per-span split, slowest "
-             "requests, SLO attribution, optional Chrome-trace export)",
+             "requests, SLO attribution)",
     )
     tl.add_argument("ledger")
-    tl.add_argument("--chrome", default=None, metavar="OUT.json",
-                    help="write the traces as Chrome-trace-event JSON "
-                         "(chrome://tracing / Perfetto)")
     tl.add_argument("--top", type=int, default=3,
                     help="print the N slowest requests' full span chains")
     tl.set_defaults(fn=_timeline)

@@ -1,43 +1,67 @@
-"""Per-request span tracing for the serve tier.
+"""Program spans and per-request span chains, on one clock.
 
-Every request the SolveEngine admits carries a `RequestTrace`: an ordered
-chain of monotonic-clock spans covering the request's whole life —
+Two span kinds share this module and its clock:
+
+* **Program spans** (`span`, `SpanLog`): ``with span(name, **tags):``
+  around any stretch of host work records one `SpanRecord` (name, start,
+  end, the id of the span open when it began, tags) in the process's
+  bounded `SPAN_LOG`.  While a JAX profiler trace records, the span also
+  opens a ``jax.profiler.TraceAnnotation`` of the same name, so it sits in
+  the trace beside the device ops it issued.  `watch_builds` turns JAX's
+  own build events into spans — ``build.trace``, ``build.lower``,
+  ``build.compile`` — and counts each backend build as a persistent-cache
+  load or a compile (`BUILDS`).  The span vocabulary is in
+  docs/OBSERVABILITY.md "Program spans".
+* **Request chains** (`RequestTrace`): every request the SolveEngine
+  admits carries an ordered chain of spans covering its whole life —
 
     admit -> enqueue -> cache_lookup -> batch_form -> device
           [-> refine] -> respond
 
-`admit` is validation + fault tap + pad + stage (submit() entry to
-scheduler admission); `enqueue` is time parked in the bucket queue until a
-flush starts; `cache_lookup` is executable resolution (near-zero on a
-cache hit — a compile shows up HERE, which is exactly the attribution the
-zero-recompile gates want); `batch_form` is assemble + async dispatch
-issue; `device` is dispatch to landing (`jax.block_until_ready`
-observed); `refine` is the landing sink when one ran (guaranteed-tier
-refinement bookkeeping, factor installs, arrowhead re-pack); `respond` is
-Response construction + stats stamping.  Oversize singles skip the
-queue/batch spans (kind "single"), never-dispatched failures collapse to
-admit -> respond (kind "failed").
+  `admit` is validation + fault tap + pad + stage (submit() entry to
+  scheduler admission); `enqueue` is time parked in the bucket queue until
+  a flush starts; `cache_lookup` is executable resolution (near-zero on a
+  cache hit — a compile shows up HERE, which is exactly the attribution
+  the zero-recompile gates want); `batch_form` is assemble + async
+  dispatch issue; `device` is dispatch to landing
+  (`jax.block_until_ready` observed); `refine` is the landing sink when
+  one ran (guaranteed-tier refinement bookkeeping, factor installs,
+  arrowhead re-pack); `respond` is Response construction + stats
+  stamping.  Oversize singles skip the queue/batch spans (kind
+  "single"), never-dispatched failures collapse to admit -> respond (kind
+  "failed").
 
-Everything here is HOST-side pure Python — `time.monotonic()` stamps
-around the dispatch path, never a device sync (the lint no-host-sync rule
-pins that via the ``serve_traced`` ProgramTarget), and the module imports
-neither jax nor numpy so the host-only router/replica modules can carry
-trace dicts freely.
+**The clock** (`now_ns`, `now`) is the wall clock, ``time.time_ns()``:
+the clock JAX's profiler stamps host events with.  A profiler trace keeps
+each event as an offset from its session's ``profile_start_time``
+(`profile_anchor_ns`), so anchor + offset is the event's time on the span
+clock, and program spans, request chains and device ops line up in one
+trace.  The wall clock is shared by every process on the host, so replica
+chains line up with engine ones too.
 
-The ledger surface is the schema-tagged ``serve:trace`` record (one per
-run, `build_block`/`emit`): per-trace tags (bucket/op/tier/replica/
-cfg-hash), per-span start/duration, completeness + monotonicity verdicts
-under a pinned bubble tolerance, and — when the request carried a
-``deadline_ms`` — slack-at-dispatch and SLO-violation *attribution* (the
-span that ate the budget), the signal ROADMAP item 3's shed/downgrade
-policy keys on.  `to_chrome` exports the same traces as Chrome-trace-event
-JSON (``obs timeline RUNS.jsonl --chrome out.json``) for waterfall
-inspection in chrome://tracing or Perfetto.
+Everything here is HOST-side pure Python — stamps around the dispatch
+path, never a device sync (the lint no-host-sync rule pins that via the
+``serve_traced`` ProgramTarget), and the module imports neither jax nor
+numpy at import time, so the host-only router/replica modules can carry
+trace dicts freely (jax is touched only once the process has imported
+it).
+
+The ledger surface of request chains is the schema-tagged ``serve:trace``
+record (one per run, `build_block`/`emit`): per-trace tags (bucket/op/
+tier/replica/cfg-hash), per-span start/duration, completeness +
+monotonicity verdicts under a pinned bubble tolerance, and — when the
+request carried a ``deadline_ms`` — slack-at-dispatch and SLO-violation
+*attribution* (the span that ate the budget), the signal ROADMAP item 3's
+shed/downgrade policy keys on.
 """
 
 from __future__ import annotations
 
+import itertools
+import sys
+import threading
 import time
+import warnings
 from collections import deque
 from typing import Optional
 
@@ -77,8 +101,252 @@ _OVERLAP_EPS_S = 1e-5
 DEFAULT_TRACE_CAP = 4096
 
 
+#: Default bound on program spans a SpanLog retains (oldest dropped first,
+#: counted in `dropped`): a cholinv n=49152 set-up records about 13,000
+#: build spans, a served request three staging spans.
+DEFAULT_SPAN_CAP = 65536
+
+
+# ---------------------------------------------------------------------------
+# the span clock
+# ---------------------------------------------------------------------------
+
+
+def now_ns() -> int:
+    """The span clock in integer nanoseconds: the wall clock, which is the
+    clock JAX's profiler stamps host events with (see the module
+    docstring)."""
+    return time.time_ns()
+
+
+def now() -> float:
+    """The span clock in float seconds (request chains stamp with it)."""
+    return time.time()
+
+
+def profile_anchor_ns(profile) -> int:
+    """The span clock's reading at the zero of a profiler trace: the
+    ``profile_start_time`` of a `jax.profiler.ProfileData`'s "Task
+    Environment" plane.  An event's ``start_ns`` plus this is its start on
+    the span clock."""
+    for plane in profile.planes:
+        if plane.name == "Task Environment":
+            with warnings.catch_warnings():
+                # the binding's stats type warns on construction
+                warnings.simplefilter("ignore", DeprecationWarning)
+                stats = dict(plane.stats)
+            if "profile_start_time" in stats:
+                return int(stats["profile_start_time"])
+    raise ValueError("the profile has no Task Environment plane with a "
+                     "profile_start_time")
+
+
+# ---------------------------------------------------------------------------
+# program spans
+# ---------------------------------------------------------------------------
+
+
+class SpanRecord:
+    """One closed program span, on the span clock."""
+
+    __slots__ = ("span_id", "name", "start_ns", "end_ns", "parent", "tags")
+
+    def __init__(self, span_id: int, name: str, start_ns: int, end_ns: int,
+                 parent: Optional[int] = None, tags: Optional[dict] = None):
+        self.span_id = span_id
+        self.name = name
+        self.start_ns = start_ns
+        self.end_ns = end_ns
+        self.parent = parent  # id of the span open when this one began
+        self.tags = tags or {}
+
+    @property
+    def dur_s(self) -> float:
+        return (self.end_ns - self.start_ns) * 1e-9
+
+
+class SpanLog:
+    """Bounded in-memory log of a process's program spans, read at the end
+    of a run.  Oldest records drop first past `cap`, counted visibly
+    (`dropped`) — the TraceLog discipline.  Any thread may add."""
+
+    def __init__(self, cap: int = DEFAULT_SPAN_CAP):
+        if cap < 1:
+            raise ValueError(f"span cap must be >= 1, got {cap}")
+        self.cap = cap  # guarded-by: <frozen>
+        self._lock = threading.Lock()  # guarded-by: <lock>
+        self.total = 0  # guarded-by: self._lock
+        self._records: deque = deque(maxlen=cap)  # guarded-by: self._lock
+
+    def add(self, rec: SpanRecord) -> None:
+        with self._lock:
+            self.total += 1
+            self._records.append(rec)
+
+    @property
+    def dropped(self) -> int:
+        with self._lock:
+            return self.total - len(self._records)
+
+    def records(self, prefix: str = "") -> list[SpanRecord]:
+        """The retained records whose name starts with `prefix`, in the
+        order they closed."""
+        with self._lock:
+            recs = list(self._records)
+        return [r for r in recs if r.name.startswith(prefix)]
+
+    def __len__(self) -> int:
+        with self._lock:
+            return len(self._records)
+
+
+#: The process's span log: every `span` and build span lands here.
+SPAN_LOG = SpanLog()
+
+_SPAN_IDS = itertools.count(1)
+_THREAD = threading.local()  # per thread: open span ids, a pending cache load
+
+
+def _open_spans() -> list:
+    stack = getattr(_THREAD, "stack", None)
+    if stack is None:
+        stack = _THREAD.stack = []
+    return stack
+
+
+def profiling() -> bool:
+    """Whether a JAX profiler trace is recording in this process.  Before
+    jax is imported there is no profiler, and jax is not imported here."""
+    prof = getattr(sys.modules.get("jax"), "profiler", None)
+    return prof is not None and prof.TraceAnnotation.is_enabled()
+
+
+class span:
+    """``with span(name, **tags):`` — one program span in `SPAN_LOG`, and
+    a ``jax.profiler.TraceAnnotation`` of the same name while a profiler
+    trace records.  Its parent is the span open on this thread when it
+    began."""
+
+    __slots__ = ("name", "tags", "span_id", "parent", "start_ns", "_ann")
+
+    def __init__(self, name: str, **tags):
+        self.name = name
+        self.tags = tags
+
+    def __enter__(self) -> "span":
+        stack = _open_spans()
+        self.parent = stack[-1] if stack else None
+        self.span_id = next(_SPAN_IDS)
+        stack.append(self.span_id)
+        self._ann = None
+        if profiling():
+            self._ann = sys.modules["jax"].profiler.TraceAnnotation(self.name)
+            self._ann.__enter__()
+        self.start_ns = time.time_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        end_ns = time.time_ns()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        _open_spans().pop()
+        SPAN_LOG.add(SpanRecord(self.span_id, self.name, self.start_ns,
+                                end_ns, self.parent, self.tags))
+
+
+# ---------------------------------------------------------------------------
+# build spans and the build counter
+# ---------------------------------------------------------------------------
+
+#: JAX's build events (jax.monitoring) and the program spans they become.
+#: Nested jits overlap, so a reader takes the union of each kind's
+#: intervals, never the sum.
+BUILD_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "build.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "build.lower",
+    "/jax/core/compile/backend_compile_duration": "build.compile",
+}
+#: fired, on the building thread, only when the persistent cache served
+#: the backend build that is under way
+CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+
+class BuildCounter:
+    """The process's backend builds, split into persistent-cache loads and
+    compiles."""
+
+    def __init__(self):
+        self._lock = threading.Lock()  # guarded-by: <lock>
+        self.compiles = 0  # guarded-by: self._lock
+        self.cache_loads = 0  # guarded-by: self._lock
+
+    def count(self, loaded: bool) -> None:
+        with self._lock:
+            if loaded:
+                self.cache_loads += 1
+            else:
+                self.compiles += 1
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return {"compiles": self.compiles,
+                    "cache_loads": self.cache_loads}
+
+
+BUILDS = BuildCounter()
+_WATCH_LOCK = threading.Lock()
+_watching = False
+
+
+def _on_duration(event: str, secs: float, **kw) -> None:
+    if event == CACHE_LOAD_EVENT:
+        _THREAD.cache_load = True
+
+
+def _on_time_span(event: str, start_s: float, end_s: float, **kw) -> None:
+    name = BUILD_EVENTS.get(event)
+    if name is None:
+        return
+    tags = {}
+    if kw.get("fun_name"):
+        tags["fun_name"] = str(kw["fun_name"])
+    if name == "build.compile":
+        loaded = getattr(_THREAD, "cache_load", False)
+        _THREAD.cache_load = False
+        tags["cache"] = "load" if loaded else "compile"
+        BUILDS.count(loaded)
+    if profiling():
+        tags["profiled"] = True
+    stack = _open_spans()
+    SPAN_LOG.add(SpanRecord(next(_SPAN_IDS), name, int(start_s * 1e9),
+                            int(end_s * 1e9), stack[-1] if stack else None,
+                            tags))
+
+
+def watch_builds() -> None:
+    """Register, once per process, the jax.monitoring listeners that turn
+    JAX's build events into build spans and counts.  JAX reports each
+    build's start and end on ``time.time()``, the span clock, so the spans
+    need no conversion.  The program's jax-side modules call this at
+    import (capital_tpu/utils/tracing.py)."""
+    global _watching
+    with _WATCH_LOCK:
+        if _watching:
+            return
+        from jax import monitoring
+
+        monitoring.register_event_duration_secs_listener(_on_duration)
+        monitoring.register_event_time_span_listener(_on_time_span)
+        _watching = True
+
+
+# ---------------------------------------------------------------------------
+# request chains
+# ---------------------------------------------------------------------------
+
+
 class Span:
-    """One contiguous phase of a request's life, on the monotonic clock."""
+    """One contiguous phase of a request's life, on the span clock."""
 
     __slots__ = ("name", "t_start", "t_end")
 
@@ -134,7 +402,7 @@ class RequestTrace:
         self.spans.append(Span(name, t_start, t_end))
 
     def extend(self, name: str, t_end: Optional[float] = None) -> None:
-        t_end = time.monotonic() if t_end is None else t_end
+        t_end = now() if t_end is None else t_end
         self.spans.append(Span(name, self.last_end, t_end))
 
     # ---- derived signals ---------------------------------------------------
@@ -190,9 +458,8 @@ class RequestTrace:
     def asdict(self) -> dict:
         """The per-trace dict inside a ``serve:trace`` record (also the
         wire form a replica marshals back to the router).  Times stay on
-        the monotonic clock — CLOCK_MONOTONIC is shared across processes
-        on one host, so replica traces normalize alongside engine ones at
-        export time."""
+        the span clock, which every process on the host shares, so replica
+        traces line up with engine ones."""
         return {
             "request_id": int(self.request_id),
             "op": self.op,
@@ -392,47 +659,3 @@ def build_block(trace_dicts: list[dict], *,
         "traces": trace_dicts,
     }
 
-
-def to_chrome(trace_dicts: list[dict]) -> dict:
-    """Chrome-trace-event JSON (the chrome://tracing / Perfetto format):
-    one complete ("ph": "X") event per span, requests as threads, engines/
-    replicas as named processes, timestamps normalized to the earliest
-    span.  Deadline signals ride the event args so the waterfall shows
-    which span ate a violated request's budget."""
-    events: list[dict] = []
-    pids: dict[str, int] = {}
-    t0 = min(
-        (sp["t_start_s"] for t in trace_dicts for sp in t.get("spans", ())
-         if isinstance(sp.get("t_start_s"), (int, float))),
-        default=0.0,
-    )
-    for t in trace_dicts:
-        label = t.get("replica_id") or "engine"
-        if label not in pids:
-            pids[label] = len(pids) + 1
-            events.append({
-                "ph": "M", "name": "process_name", "pid": pids[label],
-                "tid": 0, "args": {"name": f"serve:{label}"},
-            })
-        pid = pids[label]
-        args = {
-            "op": t.get("op"), "kind": t.get("kind"),
-            "bucket": t.get("bucket"), "tier": t.get("tier"),
-            "cfg_hash": t.get("cfg_hash"),
-            "deadline_ms": t.get("deadline_ms"),
-            "slack_at_dispatch_ms": t.get("slack_at_dispatch_ms"),
-            "violated": t.get("violated", False),
-            "attribution": t.get("attribution"),
-        }
-        for sp in t.get("spans", ()):
-            events.append({
-                "ph": "X",
-                "name": sp["name"],
-                "cat": str(t.get("op")),
-                "ts": round((sp["t_start_s"] - t0) * 1e6, 3),
-                "dur": round(sp["dur_ms"] * 1e3, 3),
-                "pid": pid,
-                "tid": int(t.get("request_id", 0)),
-                "args": args,
-            })
-    return {"displayTimeUnit": "ms", "traceEvents": events}

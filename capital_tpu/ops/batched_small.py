@@ -373,19 +373,22 @@ def _bspec(shape):
     )
 
 
-def _batched_call(kernel, inputs, out_shapes, *, interpret, flops,
+def _batched_call(kernel, inputs, out_shapes, *, name, interpret, flops,
                   bytes_accessed, alias_rhs=False):
     """One pallas_call over grid=(batch,): grid step b reads/writes ONLY
-    problem b's blocks.  alias_rhs declares input 1 -> output 0 in-place
-    reuse (posv/trsm: the RHS batch becomes the solution batch — the real
-    buffer behind the engine's TPU-side RHS donation); skipped in interpret
-    mode, which has no buffer assignment to alias."""
+    problem b's blocks.  `name` is the kernel's part of its stable name
+    (tracing.kernel_name; home phase OP::batched_small).  alias_rhs
+    declares input 1 -> output 0 in-place reuse (posv/trsm: the RHS batch
+    becomes the solution batch — the real buffer behind the engine's
+    TPU-side RHS donation); skipped in interpret mode, which has no buffer
+    assignment to alias."""
     batch = inputs[0].shape[0]
     kw = {}
     if alias_rhs and not interpret:
         kw["input_output_aliases"] = {1: 0}
     return pl.pallas_call(
         kernel,
+        name=tracing.kernel_name(name, "OP::batched_small"),
         grid=(batch,),
         in_specs=[_bspec(a.shape) for a in inputs],
         out_specs=[_bspec(s) for s, _ in out_shapes],
@@ -449,7 +452,7 @@ def potrf(A, *, uplo: str = "U", block: int = 0,
         R, info = _batched_call(
             kernel, [A],
             [((batch, n, n), A.dtype), _info_shape(batch)],
-            interpret=interpret,
+            name="potrf", interpret=interpret,
             flops=batch * tracing.batched_chol_flops(n),
             bytes_accessed=batch * 2 * n * n * jnp.dtype(A.dtype).itemsize,
         )
@@ -487,7 +490,7 @@ def trsm(T, B, *, uplo: str = "U", trans: bool = False, block: int = 0,
         (X,) = _batched_call(
             kernel, [T, B],
             [((batch, n, k), B.dtype)],
-            interpret=interpret, alias_rhs=True,
+            name="trsm", interpret=interpret, alias_rhs=True,
             flops=batch * tracing.batched_trsm_flops(n, k),
             bytes_accessed=batch * (n * n + 2 * n * k)
             * jnp.dtype(B.dtype).itemsize,
@@ -523,7 +526,7 @@ def potrs(T, B, *, uplo: str = "U", block: int = 0,
         (X,) = _batched_call(
             kernel, [T, B],
             [((batch, n, k), B.dtype)],
-            interpret=interpret, alias_rhs=True,
+            name="potrs", interpret=interpret, alias_rhs=True,
             flops=batch * 2 * tracing.batched_trsm_flops(n, k),
             bytes_accessed=batch * (n * n + 2 * n * k)
             * jnp.dtype(B.dtype).itemsize,
@@ -563,7 +566,7 @@ def posv(A, B, *, uplo: str = "U", block: int = 0,
         X, info = _batched_call(
             kernel, [A, B],
             [((batch, n, k), B.dtype), _info_shape(batch)],
-            interpret=interpret, alias_rhs=True,
+            name="posv", interpret=interpret, alias_rhs=True,
             flops=batch * tracing.fused_posv_flops(n, k),
             bytes_accessed=batch * (n * n + 2 * n * k)
             * jnp.dtype(B.dtype).itemsize,
@@ -612,7 +615,7 @@ def lstsq(A, B, *, block: int = 0, precision: str | None = "highest",
         X, info = _batched_call(
             kernel, [A, B],
             [((batch, n, k), B.dtype), _info_shape(batch)],
-            interpret=interpret,
+            name="lstsq", interpret=interpret,
             flops=batch * tracing.fused_lstsq_flops(m, n, k),
             bytes_accessed=batch * (m * n + m * k + n * k)
             * jnp.dtype(B.dtype).itemsize,

@@ -227,7 +227,7 @@ def fused_forward_step(D, C, B, Lc, yc, *, block: int = 0,
         kernel, [D, C, B, Lc, yc],
         [((batch, seg, b, b), D.dtype), ((batch, seg, b, b), D.dtype),
          ((batch, seg, b, k), B.dtype), _info_shape(batch, seg)],
-        interpret=interpret,
+        name="chain_fused_forward", interpret=interpret,
         flops=batch * (tracing.blocktri_chol_flops(seg, b)
                        + tracing.blocktri_solve_flops(seg, b, k)),
         bytes_accessed=batch * item
@@ -268,7 +268,7 @@ def factor_step(D, C, Lc, *, block: int = 0,
         kernel, [D, C, Lc],
         [((batch, seg, b, b), D.dtype), ((batch, seg, b, b), D.dtype),
          _info_shape(batch, seg)],
-        interpret=interpret,
+        name="chain_factor", interpret=interpret,
         flops=batch * tracing.blocktri_chol_flops(seg, b),
         bytes_accessed=batch * item * (4 * seg * b * b + b * b),
     )
@@ -305,7 +305,7 @@ def forward_solve_step(L, Wt, B, yc, *, block: int = 0,
     (y,) = _batched_call(
         kernel, [L, Wt, B, yc],
         [((batch, seg, b, k), B.dtype)],
-        interpret=interpret,
+        name="chain_forward", interpret=interpret,
         flops=batch * tracing.blocktri_solve_flops(seg, b, k),
         bytes_accessed=batch * item
         * (seg * (2 * b * b + 2 * b * k) + b * k),
@@ -347,7 +347,7 @@ def solve_backward_step(L, Wtn, Y, xc, *, block: int = 0,
     (x,) = _batched_call(
         kernel, [L, Wtn, Y, xc],
         [((batch, seg, b, k), Y.dtype)],
-        interpret=interpret,
+        name="chain_backward", interpret=interpret,
         flops=batch * tracing.blocktri_solve_flops(seg, b, k),
         bytes_accessed=batch * item
         * (seg * (2 * b * b + 2 * b * k) + b * k),

@@ -72,6 +72,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from capital_tpu.utils import tracing
 
 #: Block-index zero for BlockSpec index maps.  Index maps must return int32
 #: (Mosaic's grid indices): under jax_enable_x64 a bare Python 0 traces to
@@ -435,6 +436,7 @@ def zeros_dead_lower(
     )
     return pl.pallas_call(
         kernel,
+        name=tracing.kernel_name("zeros_dead_lower", "CI::buffers"),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((p, p), dtype),
         interpret=interpret,
@@ -531,6 +533,7 @@ def sched_matmul(
     )
     return pl.pallas_call(
         kernel,
+        name=tracing.kernel_name("sched_matmul", "CI::inv"),
         grid_spec=grid_spec,
         out_shape=out_struct,
         cost_estimate=pl.CostEstimate(
@@ -585,6 +588,7 @@ def write_diag_blocks(
 
     return pl.pallas_call(
         kernel,
+        name=tracing.kernel_name("write_diag_blocks", "RT::batch_write"),
         grid=(count,),
         in_specs=[
             pl.BlockSpec((1, s, s), lambda q: (q, _I0, _I0), memory_space=pltpu.VMEM),
@@ -686,6 +690,7 @@ def transpose(
             aliases = {1: 0}
     res = pl.pallas_call(
         kernel,
+        name=tracing.kernel_name("transpose", "CI::factor_diag"),
         grid=(n // bn, m // bm),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
@@ -748,6 +753,7 @@ def transpose_pair(
     oo = (dest // bn, dest // bm)
     return pl.pallas_call(
         kernel,
+        name=tracing.kernel_name("transpose_pair", "CI::factor_diag"),
         grid=(n // bn, n // bm),
         in_specs=[
             pl.BlockSpec((bm, bn), lambda i, j: (j, i), memory_space=pltpu.VMEM),
@@ -853,6 +859,7 @@ def fused_tail(
 
     Rp2, RIp2, info = pl.pallas_call(
         kernel,
+        name=tracing.kernel_name("fused_tail", "CI::tail_fused"),
         grid=(1,),
         in_specs=[
             pl.BlockSpec((n, n), lambda q: (io, io), memory_space=pltpu.VMEM),
@@ -1129,6 +1136,7 @@ def tri_matmul(
         ] + [pl.BlockSpec(memory_space=pl.ANY) for _ in extra]
         res = pl.pallas_call(
             dense_kernel,
+            name=tracing.kernel_name("gemm", "CI::tmu"),
             grid=(nm, nn, nk),
             in_specs=in_specs,
             out_specs=pl.BlockSpec(
@@ -1223,6 +1231,7 @@ def tri_matmul(
         )
         res = pl.pallas_call(
             syrk_kernel,
+            name=tracing.kernel_name("syrk", "CI::tmu"),
             grid_spec=grid_spec,
             out_shape=common["out_shape"],
             cost_estimate=common["cost_estimate"],
@@ -1329,6 +1338,8 @@ def tri_matmul(
         )
         res = pl.pallas_call(
             trmm_kernel,
+            name=tracing.kernel_name(
+                "trmm_left" if a_is_tri else "trmm_right", "CI::inv"),
             grid_spec=grid_spec,
             out_shape=common["out_shape"],
             cost_estimate=common["cost_estimate"],

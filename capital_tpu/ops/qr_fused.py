@@ -56,6 +56,7 @@ from capital_tpu.ops.pallas_tpu import (
     _platform,
     device_scope,
 )
+from capital_tpu.utils import tracing
 
 
 def _acc_dtype(dtype):
@@ -180,6 +181,7 @@ def gram_blocked(
 
     return pl.pallas_call(
         kernel,
+        name=tracing.kernel_name("gram", "CQR::gram"),
         grid=(nsteps,),
         in_specs=[
             pl.BlockSpec((bm, n), lambda i: (i, _I0), memory_space=pltpu.VMEM)
@@ -258,6 +260,7 @@ def scale_gram(
 
     Q, G = pl.pallas_call(
         kernel,
+        name=tracing.kernel_name("scale_gram", "CQR::fused"),
         grid=(nsteps,),
         in_specs=[
             pl.BlockSpec((bm, n), lambda i: (i, _I0), memory_space=pltpu.VMEM),
@@ -325,6 +328,7 @@ def scale_blocked(
 
     return pl.pallas_call(
         kernel,
+        name=tracing.kernel_name("scale", "CQR::formR"),
         grid=(m // bm,),
         in_specs=[
             pl.BlockSpec((bm, n), lambda i: (i, _I0), memory_space=pltpu.VMEM),

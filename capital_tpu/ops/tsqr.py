@@ -214,7 +214,7 @@ def _qr_pallas(P, *, block: int, precision, interpret):
     Q, R = _batched_call(
         kernel, [P],
         [((batch, p, n), P.dtype), ((batch, n, n), P.dtype)],
-        interpret=interpret,
+        name="panel_qr", interpret=interpret,
         flops=batch * 6.0 * p * n * n,
         bytes_accessed=batch * (2 * p * n + n * n)
         * jnp.dtype(P.dtype).itemsize,

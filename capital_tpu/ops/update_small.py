@@ -210,7 +210,7 @@ def _pallas_sweep(R, V, sign: float, *, block, precision, interpret):
     R2, info = _batched_call(
         kernel, [R, V],
         [((batch, n, n), R.dtype), _info_shape(batch)],
-        interpret=interpret,
+        name="rotation_sweep", interpret=interpret,
         flops=batch * tracing.chol_update_flops(n, k),
         bytes_accessed=batch * (2 * n * n + n * k)
         * jnp.dtype(R.dtype).itemsize,

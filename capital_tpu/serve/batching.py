@@ -87,10 +87,10 @@ class Bucket:
 
 
 def bucket_label(bucket) -> str:
-    """Compact human-stable bucket name for span/telemetry tags and
-    chrome-trace args, e.g. ``posv/f32/a256x256/b256x8/c8`` — the key's
-    information without tuple-repr noise (and JSON-safe).  Accepts a
-    Bucket or its `.key` tuple (the form Responses/stats carry)."""
+    """Compact human-stable bucket name for span/telemetry tags, e.g.
+    ``posv/f32/a256x256/b256x8/c8`` — the key's information without
+    tuple-repr noise (and JSON-safe).  Accepts a Bucket or its `.key`
+    tuple (the form Responses/stats carry)."""
     if isinstance(bucket, tuple):
         bucket = Bucket(*bucket)
     a = "x".join(str(d) for d in bucket.a_shape)
@@ -233,9 +233,10 @@ def bucket_for(op: str, a_shape, b_shape, dtype: str, cfg,
 def pad_operands(op: str, A, B, bucket: Bucket):
     """Pad one request's concrete operands to the bucket's per-problem
     shapes: identity-tail embed for the factored operand, zero-fill for the
-    RHS.  Host-side eager (submit time), tagged serve::pad so profiler
-    traces attribute the pad cost to the serving layer."""
-    with tracing.scope("serve::pad"):
+    RHS.  Host-side eager (submit time), under serve::pad (a phase scope
+    and a program span) so profiler traces attribute the pad cost to the
+    serving layer."""
+    with tracing.host_scope("serve::pad"):
         if op == "posv_blocktri":
             return _pad_blocktri(A, B, bucket)
         if op == "posv_arrowhead":

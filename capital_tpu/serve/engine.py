@@ -41,7 +41,6 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
-import time
 from typing import Optional
 
 import jax
@@ -462,7 +461,7 @@ class SolveEngine:
         request's trace so the serve:trace record carries
         slack-at-dispatch and, on violation, which span ate the budget
         (docs/SERVING.md 'Deadlines and SLO attribution')."""
-        t_enq = time.monotonic()
+        t_enq = spans.now()
         tid = self._next_id
         self._next_id += 1
         ticket = Ticket(tid, t_enq)
@@ -470,8 +469,9 @@ class SolveEngine:
                               if deadline_ms is not None else None)
         if A is None and op != "session_close":
             raise ValueError(f"{op} requires an A operand")
-        A = jnp.asarray(A) if A is not None else None
-        B = jnp.asarray(B) if B is not None else None
+        with tracing.host_scope("SV::stage"):
+            A = jnp.asarray(A) if A is not None else None
+            B = jnp.asarray(B) if B is not None else None
         if op not in batching.OPS and op not in batching.SESSION_OPS:
             raise ValueError(
                 f"unknown serve op {op!r}; expected one of "
@@ -610,7 +610,7 @@ class SolveEngine:
         whose oldest request has aged past max_delay_s, and land every
         in-flight batch whose results are ready.  Call from the dispatch
         loop between submits; returns the number of batches flushed."""
-        now = time.monotonic() if now is None else now
+        now = spans.now() if now is None else now
         return self.scheduler.pump(now)
 
     def drain(self) -> int:
@@ -723,7 +723,7 @@ class SolveEngine:
             # overlaps whatever batch is currently executing, so by flush
             # time the operands are already device-resident (on-device
             # no-op when eager padding placed them there)
-            with tracing.scope("SV::stage"):
+            with tracing.host_scope("SV::stage"):
                 pa = jax.device_put(pa, self._stage_device)
                 if pb is not None:
                     pb = jax.device_put(pb, self._stage_device)
@@ -1201,7 +1201,7 @@ class SolveEngine:
         """Land a host-side administrative session op (contract/close):
         no device dispatch happened, so there is no queue-wait/device
         split — latency is pure host bookkeeping."""
-        t_land = time.monotonic()
+        t_land = spans.now()
         ticket.response = Response(
             request_id=ticket.request_id, op=op, ok=True, x=x, info=None,
             error=None, bucket=None, batched=False,

@@ -35,7 +35,6 @@ per-problem `info` vector flags breakdowns one request at a time.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Optional
 
 import jax
@@ -207,7 +206,7 @@ class Executor:
         )
         with tracing.scope("SV::dispatch"):
             outputs = exe(Ab) if Bb is None else exe(Ab, Bb)
-        t0 = time.monotonic()
+        t0 = spans.now()
         fl = InFlight(bucket=bucket, pending=list(pending), outputs=outputs,
                       t0=t0, small=small)
         for p in pending:
@@ -242,7 +241,7 @@ class Executor:
         # returns (X, R, info); everything between the primary output and
         # the trailing info batch is an extra the landing sink consumes
         *xs, info = jax.block_until_ready(fl.outputs)
-        t_land = time.monotonic()
+        t_land = spans.now()
         for i, p in enumerate(fl.pending):
             tr = p.ticket.trace
             if tr is not None:
@@ -290,11 +289,11 @@ class Executor:
         through the models/ schedules, landed immediately (no batch to
         overlap against, and the models paths carry their own internal
         pipelining)."""
-        t0 = time.monotonic()
+        t0 = spans.now()
         ticket.t0 = t0
         x, raw = exe(A) if B is None else exe(A, B)
         x, raw = jax.block_until_ready((x, raw))
-        t_land = time.monotonic()
+        t_land = spans.now()
         if ticket.trace is not None:
             ticket.trace.extend("device", t_land)
         self._finish(ticket, op, x, raw, None, batched=False, t_enq=t_enq,
@@ -306,7 +305,7 @@ class Executor:
              t_enq: float) -> None:
         """Land a request that never reached a device: ingest fault or
         oversize-reject.  No queue-wait/device split exists for it."""
-        now = time.monotonic()
+        now = spans.now()
         lat = now - t_enq
         tr = ticket.trace
         if tr is not None:

@@ -39,10 +39,10 @@ requests never enter the scheduler.
 
 from __future__ import annotations
 
-import time
 from collections import deque
 from typing import Callable, Optional
 
+from capital_tpu.obs import spans
 from capital_tpu.serve import batching
 from capital_tpu.serve.executor import Executor, InFlight, _Pending
 
@@ -85,9 +85,9 @@ class Scheduler:
         q = self._queues.pop(bucket, [])
         if not q:
             return False
-        t_form = time.monotonic()
+        t_form = spans.now()
         exe, small = self._get_exe(bucket)
-        t_exe = time.monotonic()
+        t_exe = spans.now()
         for p in q:
             if p.ticket.trace is not None:
                 # enqueue = parked in the bucket queue until this flush;

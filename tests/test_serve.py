@@ -17,7 +17,6 @@ Everything runs on the conftest CPU rig (x64 on); engines default to a
 comparisons reuse the same grid.
 """
 
-import time
 
 import jax.numpy as jnp
 import numpy as np
@@ -26,7 +25,7 @@ import pytest
 from capital_tpu.bench import harness
 from capital_tpu.models import cholesky
 from capital_tpu.obs import __main__ as obs_main
-from capital_tpu.obs import ledger
+from capital_tpu.obs import ledger, spans
 from capital_tpu.ops import lapack, masking
 from capital_tpu.robust import faultinject
 from capital_tpu.robust.config import RobustConfig, RobustInfo
@@ -339,7 +338,7 @@ class TestEngineFlush:
         assert eng.pump() == 0  # younger than max_delay_s: stays queued
         assert not t.done
         # age the queue past the deadline with an explicit clock
-        assert eng.pump(now=time.monotonic() + CFG.max_delay_s + 1) == 1
+        assert eng.pump(now=spans.now() + CFG.max_delay_s + 1) == 1
         assert t.done and t.result().ok
         assert eng.stats.occupancies == [pytest.approx(1 / CFG.max_batch)]
 

@@ -10,7 +10,8 @@ Two span kinds share this module and its clock:
   the trace beside the device ops it issued.  `watch_builds` turns JAX's
   own build events into spans — ``build.trace``, ``build.lower``,
   ``build.compile`` — and counts each backend build as a persistent-cache
-  load or a compile (`BUILDS`).  The span vocabulary is in
+  load or a compile (`BUILDS`); `KERNELS` counts the Pallas call sites
+  and the distinct kernels built for them.  The span vocabulary is in
   docs/OBSERVABILITY.md "Program spans".
 * **Request chains** (`RequestTrace`): every request the SolveEngine
   admits carries an ordered chain of spans covering its whole life —
@@ -294,6 +295,32 @@ class BuildCounter:
 
 
 BUILDS = BuildCounter()
+
+
+class KernelCounter:
+    """The process's Pallas call sites that went through the kernel cache
+    of ops/pallas_tpu.py (`calls`), and the kernels that cache actually
+    traced (`built`): one per distinct kernel, however many sites call it."""
+
+    def __init__(self):
+        self._lock = threading.Lock()  # guarded-by: <lock>
+        self.calls = 0  # guarded-by: self._lock
+        self.built = 0  # guarded-by: self._lock
+
+    def call(self) -> None:
+        with self._lock:
+            self.calls += 1
+
+    def build(self) -> None:
+        with self._lock:
+            self.built += 1
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return {"calls": self.calls, "built": self.built}
+
+
+KERNELS = KernelCounter()
 _WATCH_LOCK = threading.Lock()
 _watching = False
 

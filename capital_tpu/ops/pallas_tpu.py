@@ -45,9 +45,13 @@ VMEM scratch.  On non-TPU backends everything runs in interpreter mode so
 the CPU mesh test rig exercises identical semantics (tests/conftest.py).
 
 **Buffer views and in-place outputs** (tri_matmul, transpose): operands can
-be static windows of larger buffers (offset index maps — no slice
+be windows of larger buffers (offset index maps — no slice
 materialization) and results can be written into a window of an existing
-buffer via `input_output_aliases`, preserving every untouched region.  The
+buffer via `input_output_aliases`, preserving every untouched region.
+Window sizes are static; offsets are a runtime int32 operand the index
+maps add to the block index, so one kernel serves every window of one
+size and is built once (`_kernel_cache`: cholinv's 764 call sites at
+n=49152 are 30 kernels).  The
 combination lets a blocked algorithm keep its factors in flat buffers and
 run each phase straight against them — cholinv's recursion reads R11inv /
 R12 / R22inv through views and writes leaf, TRSM, and inverse-completion
@@ -62,6 +66,7 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import dataclasses
 import functools
 import math
 
@@ -72,6 +77,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from capital_tpu.obs import spans
 from capital_tpu.utils import tracing
 
 #: Block-index zero for BlockSpec index maps.  Index maps must return int32
@@ -601,6 +607,109 @@ def write_diag_blocks(
     )(W.astype(out.dtype), out)
 
 
+def _kernel_cache(fn):
+    """Build each distinct kernel once.  ``fn(offs, *buffers, spec=...)``
+    runs under one module-level `jax.jit` whose static argument is the
+    frozen `spec` (everything the kernel is built from besides its
+    operands' shapes and dtypes), so every call site with the same spec
+    and operand shapes reuses one trace and lowers to one shared private
+    function; XLA inlines that function at each call site before buffer
+    assignment.  Only ``offs``, the int32 operand of view offsets in
+    blocks, differs between sites: the kernel reads it by scalar prefetch
+    and its index maps add it to the block index.  `spans.KERNELS` counts
+    every call and every build (a trace: a cache miss)."""
+
+    @functools.wraps(fn)
+    def build(*args, spec):
+        spans.KERNELS.build()
+        return fn(*args, spec=spec)
+
+    cached = jax.jit(build, static_argnames=("spec",))
+
+    @functools.wraps(fn)
+    def call(*args, spec):
+        spans.KERNELS.call()
+        return cached(*args, spec=spec)
+
+    return call
+
+
+def _offsets(*blocks: int) -> np.ndarray:
+    """A call's view offsets, in blocks, as the kernels' int32 operand: a
+    host array, which a trace takes in as a constant with no transfer."""
+    return np.array(blocks, np.int32)
+
+
+def _alias_form(out, *operands) -> str | None:
+    """How an in-place `out` reaches a kernel: None (a fresh result), the
+    name of the operand it IS (object identity), else "out" (a separate
+    buffer, one more operand)."""
+    if out is None:
+        return None
+    for name, x in operands:
+        if out is x:
+            return name
+    return "out"
+
+
+@dataclasses.dataclass(frozen=True)
+class _TransposeSpec:
+    m: int  # window rows; the result is (n, m)
+    n: int
+    bm: int
+    bn: int
+    out_uplo: str | None
+    out_dtype: object
+    alias: str | None  # None, "x" (out is X) or "out"
+    interpret: bool
+    phase: str
+
+
+@_kernel_cache
+def _transpose_kernel(offs, X, *rest, spec: _TransposeSpec):
+    m, n, bm, bn, out_uplo = spec.m, spec.n, spec.bm, spec.bn, spec.out_uplo
+
+    def kernel(o_ref, x_ref, *refs):
+        del o_ref  # read by the index maps
+        out_ref = refs[-1]
+        i, j = pl.program_id(0), pl.program_id(1)  # out tile (i, j): (bn, bm)
+        t = x_ref[:].T
+        if out_uplo is not None:
+            t = _global_tri_mask(t, i * bn, j * bm, out_uplo)
+        out_ref[:] = t.astype(out_ref.dtype)
+
+    # offs: the window's block offset in X, then the result's in out
+    in_specs = [pl.BlockSpec((bm, bn), lambda i, j, o: (j + o[0], i + o[1]),
+                             memory_space=pltpu.VMEM)]
+    aliases = {}
+    if spec.alias is None:
+        out_shape = jax.ShapeDtypeStruct((n, m), spec.out_dtype)
+    else:
+        buf = X if spec.alias == "x" else rest[0]
+        out_shape = jax.ShapeDtypeStruct(buf.shape, buf.dtype)
+        if spec.alias == "x":
+            aliases = {1: 0}
+        else:
+            in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+            aliases = {2: 0}
+    return pl.pallas_call(
+        kernel,
+        name=tracing.kernel_name("transpose", spec.phase),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n // bn, m // bm),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec(
+                (bn, bm), lambda i, j, o: (i + o[2], j + o[3]),
+                memory_space=pltpu.VMEM,
+            ),
+        ),
+        out_shape=out_shape,
+        input_output_aliases=aliases,
+        interpret=spec.interpret,
+    )(offs, X, *rest)
+
+
 def transpose(
     X: jnp.ndarray,
     *,
@@ -623,7 +732,9 @@ def transpose(
     among them).  A custom call is layout-opaque: the transpose stays exactly
     as big as the window it transposes.
 
-    View/in-place extensions (all offsets static):
+    View/in-place extensions (sizes static; offsets reach the kernel as a
+    runtime operand, so every window of one size shares one kernel —
+    `_kernel_cache`):
       in_view  — (r0, c0, rows, cols): transpose that window of X instead of
                  all of X (no slice materialization; the index map offsets).
       out/out_off — write the (cols x rows) result into `out` at out_off and
@@ -661,46 +772,66 @@ def transpose(
                 return lax.dynamic_update_slice(out, res.astype(out.dtype), out_off)
             return res
 
-    def kernel(x_ref, *rest):
-        out_ref = rest[-1]
-        i, j = pl.program_id(0), pl.program_id(1)  # out tile (i, j): (bn, bm)
-        t = x_ref[:].T
-        if out_uplo is not None:
-            t = _global_tri_mask(t, i * bn, j * bm, out_uplo)
-        out_ref[:] = t.astype(out_ref.dtype)
+    alias = _alias_form(out, ("x", X))
+    spec = _TransposeSpec(
+        m=m, n=n, bm=bm, bn=bn, out_uplo=out_uplo,
+        out_dtype=jnp.dtype(res_dtype), alias=alias, interpret=interpret,
+        phase=tracing.active_phase("CI::factor_diag"),
+    )
+    offs = _offsets(ir0 // bm, ic0 // bn, out_off[0] // bn, out_off[1] // bm)
+    rest = [out] if alias == "out" else []
+    return _transpose_kernel(offs, X, *rest, spec=spec)
 
-    oa = (ir0 // bm, ic0 // bn)
-    oo = (out_off[0] // bn, out_off[1] // bm)
-    in_specs = [
-        pl.BlockSpec(
-            (bm, bn), lambda i, j: (j + oa[0], i + oa[1]), memory_space=pltpu.VMEM
-        )
-    ]
-    operands = [X]
-    aliases = {}
-    if out is None:
-        out_shape = jax.ShapeDtypeStruct((n, m), res_dtype)
-    else:
-        out_shape = jax.ShapeDtypeStruct(out.shape, out.dtype)
-        if out is X:
-            aliases = {0: 0}
-        else:
-            in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
-            operands.append(out)
-            aliases = {1: 0}
-    res = pl.pallas_call(
+
+@dataclasses.dataclass(frozen=True)
+class _PairSpec:
+    n: int
+    bm: int
+    bn: int
+    interpret: bool
+    phase: str
+
+
+@_kernel_cache
+def _transpose_pair_kernel(offs, L, Linv, Rp, RIp, *, spec: _PairSpec):
+    n, bm, bn = spec.n, spec.bm, spec.bn
+
+    def kernel(o_ref, l_ref, li_ref, rp_ref, rip_ref, r_out, ri_out):
+        del o_ref, rp_ref, rip_ref  # index maps' offsets; aliased storage
+        i, j = pl.program_id(0), pl.program_id(1)
+        t = _global_tri_mask(l_ref[:].T, i * bn, j * bm, "U")
+        u = _global_tri_mask(li_ref[:].T, i * bn, j * bm, "U")
+        r_out[:] = t.astype(r_out.dtype)
+        ri_out[:] = u.astype(ri_out.dtype)
+
+    # offs: the destination window's block offset in Rp and RIp
+    dest_map = lambda i, j, o: (i + o[0], j + o[1])  # noqa: E731
+    return pl.pallas_call(
         kernel,
-        name=tracing.kernel_name("transpose", "CI::factor_diag"),
-        grid=(n // bn, m // bm),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (bn, bm), lambda i, j: (i + oo[0], j + oo[1]), memory_space=pltpu.VMEM
+        name=tracing.kernel_name("transpose_pair", spec.phase),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n // bn, n // bm),
+            in_specs=[
+                pl.BlockSpec((bm, bn), lambda i, j, o: (j, i),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((bm, bn), lambda i, j, o: (j, i),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=[
+                pl.BlockSpec((bn, bm), dest_map, memory_space=pltpu.VMEM),
+                pl.BlockSpec((bn, bm), dest_map, memory_space=pltpu.VMEM),
+            ],
         ),
-        out_shape=out_shape,
-        input_output_aliases=aliases,
-        interpret=interpret,
-    )(*operands)
-    return res
+        out_shape=[
+            jax.ShapeDtypeStruct(Rp.shape, Rp.dtype),
+            jax.ShapeDtypeStruct(RIp.shape, RIp.dtype),
+        ],
+        input_output_aliases={3: 0, 4: 1},
+        interpret=spec.interpret,
+    )(offs, L, Linv, Rp, RIp)
 
 
 def transpose_pair(
@@ -715,7 +846,8 @@ def transpose_pair(
     """Both base-case write-back transposes in ONE pallas_call: Lᵀ masked to
     'U' lands in `Rp` at (dest, dest), Linvᵀ in `RIp`, each through its own
     input_output_alias (untouched regions preserved; the caller must treat
-    the passed-in buffers as consumed).
+    the passed-in buffers as consumed).  `dest` reaches the kernel as a
+    runtime operand, so every leaf of one size shares one kernel.
 
     This is the double-buffered form of the two sequential `transpose`
     calls `_base_case_into` used to issue: one grid sweep keeps BOTH
@@ -741,43 +873,10 @@ def transpose_pair(
         RIp = transpose(Linv, out_uplo="U", out=RIp, out_off=(dest, dest),
                         interpret=interpret)
         return Rp, RIp
-
-    def kernel(l_ref, li_ref, rp_ref, rip_ref, r_out, ri_out):
-        del rp_ref, rip_ref  # aliased storage; never read
-        i, j = pl.program_id(0), pl.program_id(1)
-        t = _global_tri_mask(l_ref[:].T, i * bn, j * bm, "U")
-        u = _global_tri_mask(li_ref[:].T, i * bn, j * bm, "U")
-        r_out[:] = t.astype(r_out.dtype)
-        ri_out[:] = u.astype(ri_out.dtype)
-
-    oo = (dest // bn, dest // bm)
-    return pl.pallas_call(
-        kernel,
-        name=tracing.kernel_name("transpose_pair", "CI::factor_diag"),
-        grid=(n // bn, n // bm),
-        in_specs=[
-            pl.BlockSpec((bm, bn), lambda i, j: (j, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec((bm, bn), lambda i, j: (j, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=[
-            pl.BlockSpec(
-                (bn, bm), lambda i, j: (i + oo[0], j + oo[1]),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(
-                (bn, bm), lambda i, j: (i + oo[0], j + oo[1]),
-                memory_space=pltpu.VMEM,
-            ),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(Rp.shape, Rp.dtype),
-            jax.ShapeDtypeStruct(RIp.shape, RIp.dtype),
-        ],
-        input_output_aliases={2: 0, 3: 1},
-        interpret=interpret,
-    )(L, Linv, Rp, RIp)
+    spec = _PairSpec(n=n, bm=bm, bn=bn, interpret=interpret,
+                     phase=tracing.active_phase("CI::factor_diag"))
+    return _transpose_pair_kernel(
+        _offsets(dest // bn, dest // bm), L, Linv, Rp, RIp, spec=spec)
 
 
 def fused_tail(
@@ -891,14 +990,319 @@ def fused_tail(
     return Rp2, RIp2, info[0, 0]
 
 
-# NOTE: deliberately NOT wrapped in jax.jit.  The in-place `out` path decides
-# between "alias an operand" and "append a donated buffer operand" by object
-# identity (`out is A` / `out is B`); a jit boundary would hand the function
-# fresh tracers for each argument, the identity test would always fail, and
-# every self-updating call (e.g. cholinv's inverse completion writing one
-# window of Rinv while reading another) would silently pay a full-buffer XLA
-# copy — measured 31 x 1.6ms/iter at n=16k.  Callers jit the enclosing
-# computation instead.
+@dataclasses.dataclass(frozen=True)
+class _MatmulSpec:
+    M: int  # the product's (M x K) @ (K x N) window dims
+    K: int
+    N: int
+    blocks: tuple[int, int, int]  # (bm, bn, bk), fitted to every view
+    a_uplo: str | None
+    a_trans: bool
+    b_uplo: str | None
+    b_trans: bool
+    out_uplo: str | None
+    alpha: float
+    beta: float  # nonzero: the C operand follows A and B (syrk's beta*C)
+    # None (a fresh result), "a" / "b" (out IS that operand), "out" (a
+    # separate buffer, after A and B) or "c" (the syrk read-modify-write:
+    # out IS the C operand)
+    alias: str | None
+    out_dtype: object
+    acc_dtype: object
+    precision: str | None
+    interpret: bool
+    vmem_limit: int | None
+    phase: str
+
+
+@_kernel_cache
+def _tri_matmul_kernel(offs, A, B, *rest, spec: _MatmulSpec):
+    """The three tri_matmul kernels (module docstring).  `offs` holds the
+    views' block offsets: A's (row, col), B's, the result's in `out`, and
+    C's — the index maps add them."""
+    M, K, N = spec.M, spec.K, spec.N
+    bm, bn, bk = spec.blocks
+    a_uplo, a_trans = spec.a_uplo, spec.a_trans
+    b_uplo, b_trans = spec.b_uplo, spec.b_trans
+    out_uplo, alpha, beta = spec.out_uplo, spec.alpha, spec.beta
+    fused_c = beta != 0.0
+    nm, nk, nn = M // bm, K // bk, N // bn
+    accumulate = _make_accumulate(
+        a_uplo=a_uplo, a_trans=a_trans, b_uplo=b_uplo, b_trans=b_trans,
+        bm=bm, bn=bn, bk=bk, acc_dtype=spec.acc_dtype,
+        precision=spec.precision, operand_dtypes=(A.dtype, B.dtype),
+    )
+    a_shape = (bk, bm) if a_trans else (bm, bk)
+    b_shape = (bn, bk) if b_trans else (bk, bn)
+
+    if spec.alias is None:
+        out_shape = jax.ShapeDtypeStruct((M, N), spec.out_dtype)
+    else:
+        buf = A if spec.alias == "a" else B if spec.alias == "b" else rest[0]
+        out_shape = jax.ShapeDtypeStruct(buf.shape, buf.dtype)
+    # the in-place out's operand index, counted after the n scalar-prefetch
+    # operands: out IS A, IS B, or comes next as a buffer of its own
+    alias_at = {"a": 0, "b": 1, "out": 2}.get(spec.alias)
+    extra = rest if spec.alias == "out" else []
+
+    common = dict(
+        out_shape=out_shape,
+        cost_estimate=pl.CostEstimate(
+            flops=2 * M * N * K,
+            bytes_accessed=(M * K + K * N + M * N)
+            * jnp.dtype(jnp.result_type(A, B)).itemsize,
+            transcendentals=0,
+        ),
+        interpret=spec.interpret,
+    )
+
+    if a_uplo is None and b_uplo is None and out_uplo is None:
+        # ---- dense: plain revisit-k blocked matmul -----------------------
+        def dense_kernel(o_ref, a_ref, b_ref, *refs):
+            del o_ref  # read by the index maps
+            out_ref, acc_ref = refs[-2], refs[-1]
+            i, j, k = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+
+            @pl.when(k == 0)
+            def _():
+                acc_ref[:] = jnp.zeros_like(acc_ref)
+
+            accumulate(a_ref, b_ref, acc_ref, i, j, k)
+
+            @pl.when(k == nk - 1)
+            def _():
+                _flush(acc_ref, out_ref, alpha, None, 0, 0)
+
+        in_specs = [
+            pl.BlockSpec(
+                a_shape,
+                (lambda i, j, k, o: (k + o[0], i + o[1]))
+                if a_trans
+                else (lambda i, j, k, o: (i + o[0], k + o[1])),
+                memory_space=pltpu.VMEM,
+            ),
+            pl.BlockSpec(
+                b_shape,
+                (lambda i, j, k, o: (j + o[2], k + o[3]))
+                if b_trans
+                else (lambda i, j, k, o: (k + o[2], j + o[3])),
+                memory_space=pltpu.VMEM,
+            ),
+        ] + [pl.BlockSpec(memory_space=pl.ANY) for _ in extra]
+        return pl.pallas_call(
+            dense_kernel,
+            name=tracing.kernel_name("gemm", spec.phase),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(nm, nn, nk),
+                in_specs=in_specs,
+                out_specs=pl.BlockSpec(
+                    (bm, bn),
+                    lambda i, j, k, o: (i + o[4], j + o[5]),
+                    memory_space=pltpu.VMEM,
+                ),
+                scratch_shapes=[pltpu.VMEM((bm, bn), spec.acc_dtype)],
+            ),
+            input_output_aliases=(
+                {} if alias_at is None else {1 + alias_at: 0}),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                vmem_limit_bytes=spec.vmem_limit,
+            ),
+            **common,
+        )(offs, A, B, *extra)
+
+    if out_uplo is not None:
+        # ---- tri-output (syrk): enumerate live output tiles --------------
+        pairs = [
+            (i, j)
+            for i in range(nm)
+            for j in range(nn)
+            if (i * bm < (j + 1) * bn if out_uplo == "U" else j * bn < (i + 1) * bm)
+        ]
+        io = jnp.asarray(np.array([p[0] for p in pairs], np.int32))
+        jo = jnp.asarray(np.array([p[1] for p in pairs], np.int32))
+
+        def syrk_kernel(o_ref, io_ref, jo_ref, a_ref, b_ref, *refs):
+            del o_ref  # read by the index maps
+            out_ref, acc_ref = refs[-2], refs[-1]
+            p, k = pl.program_id(0), pl.program_id(1)
+            i, j = io_ref[p], jo_ref[p]
+
+            @pl.when(k == 0)
+            def _():
+                acc_ref[:] = jnp.zeros_like(acc_ref)
+
+            accumulate(a_ref, b_ref, acc_ref, i, j, k)
+
+            @pl.when(k == nk - 1)
+            def _():
+                _flush(
+                    acc_ref, out_ref, alpha, out_uplo, i * bm, j * bn,
+                    c_ref=refs[0] if fused_c else None, beta=beta,
+                )
+
+        in_specs = [
+            pl.BlockSpec(
+                a_shape,
+                (lambda p, k, o, io, jo: (k + o[0], io[p] + o[1]))
+                if a_trans
+                else (lambda p, k, o, io, jo: (io[p] + o[0], k + o[1])),
+                memory_space=pltpu.VMEM,
+            ),
+            pl.BlockSpec(
+                b_shape,
+                (lambda p, k, o, io, jo: (jo[p] + o[2], k + o[3]))
+                if b_trans
+                else (lambda p, k, o, io, jo: (k + o[2], jo[p] + o[3])),
+                memory_space=pltpu.VMEM,
+            ),
+        ]
+        if fused_c:
+            # C tile fetched once per output tile (index map ignores k, so
+            # consecutive k-steps revisit the same block without re-DMA)
+            in_specs.append(
+                pl.BlockSpec(
+                    (bm, bn),
+                    lambda p, k, o, io, jo: (io[p] + o[6], jo[p] + o[7]),
+                    memory_space=pltpu.VMEM,
+                )
+            )
+        # in-place RMW (out is the C buffer): each live tile is read once
+        # (the beta term, at its c_view offset) and written back at the same
+        # absolute offset — operand index 5 = 3 scalar-prefetch args + A + B.
+        # Tile-local: no other tile of the aliased buffer is ever read by
+        # this call (A/B come from different buffers), so grid order is free
+        # and no XLA copy is forced.  Untouched (dead-triangle) tiles keep
+        # the buffer's previous contents.
+        res = pl.pallas_call(
+            syrk_kernel,
+            name=tracing.kernel_name("syrk", spec.phase),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3,
+                grid=(len(pairs), nk),
+                in_specs=in_specs,
+                out_specs=pl.BlockSpec(
+                    (bm, bn),
+                    lambda p, k, o, io, jo: (io[p] + o[4], jo[p] + o[5]),
+                    memory_space=pltpu.VMEM,
+                ),
+                scratch_shapes=[pltpu.VMEM((bm, bn), spec.acc_dtype)],
+            ),
+            input_output_aliases={5: 0} if spec.alias == "c" else {},
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary"),
+                vmem_limit_bytes=spec.vmem_limit,
+            ),
+            **common,
+        )(offs, io, jo, A, B, *rest)
+        if not fused_c:
+            # tiles in the dead half are never written by the kernel; Mosaic
+            # zero-initializes outputs only per-visited-block, so blank the
+            # dead half explicitly (cheap elementwise, fuses with the crop
+            # below).  With fused beta*C the dead half stays UNDEFINED by
+            # contract — no full-matrix mask pass.
+            res = _global_tri_mask(res, 0, 0, out_uplo)
+        return res
+
+    # ---- tri-operand (trmm): enumerate live (tile-row, k) pairs ----------
+    if a_uplo is not None:
+        pairs = [
+            (i, k)
+            for i in range(nm)
+            for k in range(nk)
+            if _a_live(i, k, bm, bk, a_uplo, a_trans)
+        ]
+    else:
+        pairs = [
+            (j, k)
+            for j in range(nn)
+            for k in range(nk)
+            if _b_live(j, k, bn, bk, b_uplo, b_trans)
+        ]
+    # grid: (other-dim, pairs) — pairs innermost so each out tile is
+    # revisited consecutively across its live k run
+    to = jnp.asarray(np.array([p[0] for p in pairs], np.int32))
+    ko = jnp.asarray(np.array([p[1] for p in pairs], np.int32))
+    first = np.zeros(len(pairs), np.int32)
+    last = np.zeros(len(pairs), np.int32)
+    for idx, (t, _) in enumerate(pairs):
+        if idx == 0 or pairs[idx - 1][0] != t:
+            first[idx] = 1
+        if idx == len(pairs) - 1 or pairs[idx + 1][0] != t:
+            last[idx] = 1
+    first = jnp.asarray(first)
+    last = jnp.asarray(last)
+    a_is_tri = a_uplo is not None
+
+    def trmm_kernel(o_ref, to_ref, ko_ref, fi_ref, la_ref, a_ref, b_ref, *refs):
+        del o_ref  # read by the index maps
+        out_ref, acc_ref = refs[-2], refs[-1]
+        q, p = pl.program_id(0), pl.program_id(1)
+        t, k = to_ref[p], ko_ref[p]
+        i, j = (t, q) if a_is_tri else (q, t)
+
+        @pl.when(fi_ref[p] == 1)
+        def _():
+            acc_ref[:] = jnp.zeros_like(acc_ref)
+
+        accumulate(a_ref, b_ref, acc_ref, i, j, k)
+
+        @pl.when(la_ref[p] == 1)
+        def _():
+            _flush(acc_ref, out_ref, alpha, None, 0, 0)
+
+    if a_is_tri:
+        a_map = (
+            (lambda q, p, o, to, ko, fi, la: (ko[p] + o[0], to[p] + o[1]))
+            if a_trans
+            else (lambda q, p, o, to, ko, fi, la: (to[p] + o[0], ko[p] + o[1]))
+        )
+        b_map = (
+            (lambda q, p, o, to, ko, fi, la: (q + o[2], ko[p] + o[3]))
+            if b_trans
+            else (lambda q, p, o, to, ko, fi, la: (ko[p] + o[2], q + o[3]))
+        )
+        out_map = lambda q, p, o, to, ko, fi, la: (to[p] + o[4], q + o[5])  # noqa: E731
+        n_outer = nn
+    else:
+        a_map = (
+            (lambda q, p, o, to, ko, fi, la: (ko[p] + o[0], q + o[1]))
+            if a_trans
+            else (lambda q, p, o, to, ko, fi, la: (q + o[0], ko[p] + o[1]))
+        )
+        b_map = (
+            (lambda q, p, o, to, ko, fi, la: (to[p] + o[2], ko[p] + o[3]))
+            if b_trans
+            else (lambda q, p, o, to, ko, fi, la: (ko[p] + o[2], to[p] + o[3]))
+        )
+        out_map = lambda q, p, o, to, ko, fi, la: (q + o[4], to[p] + o[5])  # noqa: E731
+        n_outer = nm
+
+    return pl.pallas_call(
+        trmm_kernel,
+        name=tracing.kernel_name(
+            "trmm_left" if a_is_tri else "trmm_right", spec.phase),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(n_outer, len(pairs)),
+            in_specs=[
+                pl.BlockSpec(a_shape, a_map, memory_space=pltpu.VMEM),
+                pl.BlockSpec(b_shape, b_map, memory_space=pltpu.VMEM),
+            ]
+            + [pl.BlockSpec(memory_space=pl.ANY) for _ in extra],
+            out_specs=pl.BlockSpec((bm, bn), out_map, memory_space=pltpu.VMEM),
+            scratch_shapes=[pltpu.VMEM((bm, bn), spec.acc_dtype)],
+        ),
+        input_output_aliases={} if alias_at is None else {5 + alias_at: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=spec.vmem_limit,
+        ),
+        **common,
+    )(offs, to, ko, first, last, A, B, *extra)
+
+
 def tri_matmul(
     A: jnp.ndarray,
     B: jnp.ndarray,
@@ -929,7 +1333,9 @@ def tri_matmul(
     the MXU default (bf16-grade mantissa per pass): measured 7e-4 relative
     residual on an n=1000 f32 cholinv vs 2e-7 with 'highest'.
 
-    Buffer views (all offsets/sizes static):
+    Buffer views (sizes static; offsets reach the kernel as a runtime
+    operand, so every call with the same sizes, blocks and flags shares one
+    kernel — `_kernel_cache`):
       a_view/b_view — (r0, c0, rows, cols): the operand is that window of the
         passed buffer (still transposed by the *_trans flag).  No slice is
         materialized; the BlockSpec index maps are offset by whole blocks.
@@ -960,7 +1366,13 @@ def tri_matmul(
     the single output cast, while the misaligned fallback first rounds the
     product to the operand dtype and then adds at the jnp-promoted dtype
     (mode='xla' semantics) — the same call can differ by one bf16 ulp
-    depending on 128-alignment of the views."""
+    depending on 128-alignment of the views.
+
+    The in-place form is decided here, by object identity (`out is A`,
+    `out is B`, `out is c`), and handed to the cached kernel in its spec:
+    inside a jit every argument is a fresh tracer, so the test could not be
+    made there, and a missed alias makes XLA copy the whole buffer
+    (measured 31 x 1.6ms/iter at n=16k)."""
     if a_uplo is not None and b_uplo is not None:
         raise ValueError("at most one triangular operand")
     if out_uplo is not None and (a_uplo is not None or b_uplo is not None):
@@ -1049,7 +1461,6 @@ def tri_matmul(
         Ap = jnp.pad(A, ((0, pa[0]), (0, pa[1]))) if any(pa) else A
         Bp = jnp.pad(B, ((0, pb[0]), (0, pb[1]))) if any(pb) else B
 
-    nm, nk, nn = M // bm, K // bk, N // bn
     if out is not None:
         out_dtype = out.dtype
     elif fused_c:
@@ -1062,295 +1473,28 @@ def tri_matmul(
     if jnp.dtype(acc_dtype).itemsize > 4 and _platform() == "tpu":
         acc_dtype = jnp.float32
 
-    accumulate = _make_accumulate(
+    if inplace_rmw:
+        alias = "c"
+    else:
+        alias = _alias_form(out, ("a", A), ("b", B))
+    home = "CI::tmu" if a_uplo is None and b_uplo is None else "CI::inv"
+    spec = _MatmulSpec(
+        M=M, K=K, N=N, blocks=(bm, bn, bk),
         a_uplo=a_uplo, a_trans=a_trans, b_uplo=b_uplo, b_trans=b_trans,
-        bm=bm, bn=bn, bk=bk, acc_dtype=acc_dtype, precision=precision,
-        operand_dtypes=(A.dtype, B.dtype),
+        out_uplo=out_uplo, alpha=alpha, beta=beta, alias=alias,
+        out_dtype=jnp.dtype(out_dtype), acc_dtype=jnp.dtype(acc_dtype),
+        precision=precision, interpret=interpret, vmem_limit=vmem_limit,
+        phase=tracing.active_phase(home),
     )
-    a_shape = (bk, bm) if a_trans else (bm, bk)
-    b_shape = (bn, bk) if b_trans else (bk, bn)
-    # static block offsets of each view, in that operand's buffer axes
-    oa = (ar0 // a_shape[0], ac0 // a_shape[1])
-    ob = (br0 // b_shape[0], bc0 // b_shape[1])
-    oo = (out_off[0] // bm, out_off[1] // bn) if out is not None else (0, 0)
-
-    if out is None:
-        out_shape = jax.ShapeDtypeStruct((M, N), out_dtype)
-    else:
-        out_shape = jax.ShapeDtypeStruct(out.shape, out.dtype)
-
-    def alias_setup(n_scalars: int):
-        """(extra operand list, input_output_aliases) for the in-place out."""
-        if out is None:
-            return [], {}
-        if out is A:
-            return [], {n_scalars: 0}
-        if out is B:
-            return [], {n_scalars + 1: 0}
-        return [out], {n_scalars + 2: 0}
-
-    common = dict(
-        out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
-        cost_estimate=pl.CostEstimate(
-            flops=2 * M * N * K,
-            bytes_accessed=(M * K + K * N + M * N)
-            * jnp.dtype(jnp.result_type(A, B)).itemsize,
-            transcendentals=0,
-        ),
-        interpret=interpret,
+    a_blk = (bk, bm) if a_trans else (bm, bk)
+    b_blk = (bn, bk) if b_trans else (bk, bn)
+    offs = _offsets(
+        ar0 // a_blk[0], ac0 // a_blk[1], br0 // b_blk[0], bc0 // b_blk[1],
+        *((out_off[0] // bm, out_off[1] // bn) if out is not None else (0, 0)),
+        cr0 // bm, cc0 // bn,
     )
-
-    if a_uplo is None and b_uplo is None and out_uplo is None:
-        # ---- dense: plain revisit-k blocked matmul -----------------------
-        def dense_kernel(a_ref, b_ref, *rest):
-            out_ref, acc_ref = rest[-2], rest[-1]
-            i, j, k = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-
-            @pl.when(k == 0)
-            def _():
-                acc_ref[:] = jnp.zeros_like(acc_ref)
-
-            accumulate(a_ref, b_ref, acc_ref, i, j, k)
-
-            @pl.when(k == nk - 1)
-            def _():
-                _flush(acc_ref, out_ref, alpha, None, 0, 0)
-
-        extra, aliases = alias_setup(0)
-        in_specs = [
-            pl.BlockSpec(
-                a_shape,
-                (lambda i, j, k: (k + oa[0], i + oa[1]))
-                if a_trans
-                else (lambda i, j, k: (i + oa[0], k + oa[1])),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(
-                b_shape,
-                (lambda i, j, k: (j + ob[0], k + ob[1]))
-                if b_trans
-                else (lambda i, j, k: (k + ob[0], j + ob[1])),
-                memory_space=pltpu.VMEM,
-            ),
-        ] + [pl.BlockSpec(memory_space=pl.ANY) for _ in extra]
-        res = pl.pallas_call(
-            dense_kernel,
-            name=tracing.kernel_name("gemm", "CI::tmu"),
-            grid=(nm, nn, nk),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (bm, bn),
-                lambda i, j, k: (i + oo[0], j + oo[1]),
-                memory_space=pltpu.VMEM,
-            ),
-            input_output_aliases=aliases,
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary"),
-                vmem_limit_bytes=vmem_limit,
-            ),
-            **common,
-        )(Ap, Bp, *extra)
-
-    elif out_uplo is not None:
-        # ---- tri-output (syrk): enumerate live output tiles --------------
-        pairs = [
-            (i, j)
-            for i in range(nm)
-            for j in range(nn)
-            if (i * bm < (j + 1) * bn if out_uplo == "U" else j * bn < (i + 1) * bm)
-        ]
-        io = jnp.asarray(np.array([p[0] for p in pairs], np.int32))
-        jo = jnp.asarray(np.array([p[1] for p in pairs], np.int32))
-        oc = (cr0 // bm, cc0 // bn)
-
-        def syrk_kernel(io_ref, jo_ref, a_ref, b_ref, *rest):
-            out_ref, acc_ref = rest[-2], rest[-1]
-            p, k = pl.program_id(0), pl.program_id(1)
-            i, j = io_ref[p], jo_ref[p]
-
-            @pl.when(k == 0)
-            def _():
-                acc_ref[:] = jnp.zeros_like(acc_ref)
-
-            accumulate(a_ref, b_ref, acc_ref, i, j, k)
-
-            @pl.when(k == nk - 1)
-            def _():
-                _flush(
-                    acc_ref, out_ref, alpha, out_uplo, i * bm, j * bn,
-                    c_ref=rest[0] if fused_c else None, beta=beta,
-                )
-
-        in_specs = [
-            pl.BlockSpec(
-                a_shape,
-                (lambda p, k, io, jo: (k + oa[0], io[p] + oa[1]))
-                if a_trans
-                else (lambda p, k, io, jo: (io[p] + oa[0], k + oa[1])),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(
-                b_shape,
-                (lambda p, k, io, jo: (jo[p] + ob[0], k + ob[1]))
-                if b_trans
-                else (lambda p, k, io, jo: (k + ob[0], jo[p] + ob[1])),
-                memory_space=pltpu.VMEM,
-            ),
-        ]
-        operands = [io, jo, Ap, Bp]
-        if fused_c:
-            # C tile fetched once per output tile (index map ignores k, so
-            # consecutive k-steps revisit the same block without re-DMA)
-            in_specs.append(
-                pl.BlockSpec(
-                    (bm, bn),
-                    lambda p, k, io, jo: (io[p] + oc[0], jo[p] + oc[1]),
-                    memory_space=pltpu.VMEM,
-                )
-            )
-            operands.append(c)
-        # in-place RMW (out is the C buffer): each live tile is read once
-        # (the beta term, at its c_view offset) and written back at the same
-        # absolute offset — operand index 4 = 2 scalar-prefetch args + A + B.
-        # Tile-local: no other tile of the aliased buffer is ever read by
-        # this call (A/B come from different buffers), so grid order is free
-        # and no XLA copy is forced.  Untouched (dead-triangle) tiles keep
-        # the buffer's previous contents.
-        aliases = {4: 0} if inplace_rmw else {}
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(len(pairs), nk),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (bm, bn),
-                lambda p, k, io, jo: (io[p] + oo[0], jo[p] + oo[1]),
-                memory_space=pltpu.VMEM,
-            ),
-            scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
-        )
-        res = pl.pallas_call(
-            syrk_kernel,
-            name=tracing.kernel_name("syrk", "CI::tmu"),
-            grid_spec=grid_spec,
-            out_shape=common["out_shape"],
-            cost_estimate=common["cost_estimate"],
-            input_output_aliases=aliases,
-            interpret=interpret,
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("arbitrary", "arbitrary"),
-                vmem_limit_bytes=vmem_limit,
-            ),
-        )(*operands)
-        if not fused_c:
-            # tiles in the dead half are never written by the kernel; Mosaic
-            # zero-initializes outputs only per-visited-block, so blank the
-            # dead half explicitly (cheap elementwise, fuses with the crop
-            # below).  With fused beta*C the dead half stays UNDEFINED by
-            # contract — no full-matrix mask pass.
-            res = _global_tri_mask(res, 0, 0, out_uplo)
-
-    else:
-        # ---- tri-operand (trmm): enumerate live (tile-row, k) pairs ------
-        if a_uplo is not None:
-            pairs = [
-                (i, k)
-                for i in range(nm)
-                for k in range(nk)
-                if _a_live(i, k, bm, bk, a_uplo, a_trans)
-            ]
-        else:
-            pairs = [
-                (j, k)
-                for j in range(nn)
-                for k in range(nk)
-                if _b_live(j, k, bn, bk, b_uplo, b_trans)
-            ]
-        # grid: (other-dim, pairs) — pairs innermost so each out tile is
-        # revisited consecutively across its live k run
-        to = jnp.asarray(np.array([p[0] for p in pairs], np.int32))
-        ko = jnp.asarray(np.array([p[1] for p in pairs], np.int32))
-        first = np.zeros(len(pairs), np.int32)
-        last = np.zeros(len(pairs), np.int32)
-        for idx, (t, _) in enumerate(pairs):
-            if idx == 0 or pairs[idx - 1][0] != t:
-                first[idx] = 1
-            if idx == len(pairs) - 1 or pairs[idx + 1][0] != t:
-                last[idx] = 1
-        first = jnp.asarray(first)
-        last = jnp.asarray(last)
-        a_is_tri = a_uplo is not None
-
-        def trmm_kernel(to_ref, ko_ref, fi_ref, la_ref, a_ref, b_ref, *rest):
-            out_ref, acc_ref = rest[-2], rest[-1]
-            q, p = pl.program_id(0), pl.program_id(1)
-            t, k = to_ref[p], ko_ref[p]
-            i, j = (t, q) if a_is_tri else (q, t)
-
-            @pl.when(fi_ref[p] == 1)
-            def _():
-                acc_ref[:] = jnp.zeros_like(acc_ref)
-
-            accumulate(a_ref, b_ref, acc_ref, i, j, k)
-
-            @pl.when(la_ref[p] == 1)
-            def _():
-                _flush(acc_ref, out_ref, alpha, None, 0, 0)
-
-        if a_is_tri:
-            a_map = (
-                (lambda q, p, to, ko, fi, la: (ko[p] + oa[0], to[p] + oa[1]))
-                if a_trans
-                else (lambda q, p, to, ko, fi, la: (to[p] + oa[0], ko[p] + oa[1]))
-            )
-            b_map = (
-                (lambda q, p, to, ko, fi, la: (q + ob[0], ko[p] + ob[1]))
-                if b_trans
-                else (lambda q, p, to, ko, fi, la: (ko[p] + ob[0], q + ob[1]))
-            )
-            out_map = lambda q, p, to, ko, fi, la: (to[p] + oo[0], q + oo[1])
-            n_outer = nn
-        else:
-            a_map = (
-                (lambda q, p, to, ko, fi, la: (ko[p] + oa[0], q + oa[1]))
-                if a_trans
-                else (lambda q, p, to, ko, fi, la: (q + oa[0], ko[p] + oa[1]))
-            )
-            b_map = (
-                (lambda q, p, to, ko, fi, la: (to[p] + ob[0], ko[p] + ob[1]))
-                if b_trans
-                else (lambda q, p, to, ko, fi, la: (ko[p] + ob[0], to[p] + ob[1]))
-            )
-            out_map = lambda q, p, to, ko, fi, la: (q + oo[0], to[p] + oo[1])
-            n_outer = nm
-
-        extra, aliases = alias_setup(4)
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(n_outer, len(pairs)),
-            in_specs=[
-                pl.BlockSpec(a_shape, a_map, memory_space=pltpu.VMEM),
-                pl.BlockSpec(b_shape, b_map, memory_space=pltpu.VMEM),
-            ]
-            + [pl.BlockSpec(memory_space=pl.ANY) for _ in extra],
-            out_specs=pl.BlockSpec((bm, bn), out_map, memory_space=pltpu.VMEM),
-            scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
-        )
-        res = pl.pallas_call(
-            trmm_kernel,
-            name=tracing.kernel_name(
-                "trmm_left" if a_is_tri else "trmm_right", "CI::inv"),
-            grid_spec=grid_spec,
-            out_shape=common["out_shape"],
-            cost_estimate=common["cost_estimate"],
-            input_output_aliases=aliases,
-            interpret=interpret,
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary"),
-                vmem_limit_bytes=vmem_limit,
-            ),
-        )(to, ko, first, last, Ap, Bp, *extra)
-
+    rest = ([out] if alias == "out" else []) + ([c] if fused_c else [])
+    res = _tri_matmul_kernel(offs, Ap, Bp, *rest, spec=spec)
     if out is not None:
         return res
     return res[:am, :bnd] if (M != am or N != bnd) else res

@@ -378,8 +378,14 @@ def kernel_name(kernel: str, phase: str) -> str:
     if phase not in _PHASE_SET:
         raise ValueError(f"unregistered home phase {phase!r} for kernel "
                          f"{kernel!r}")
-    tag = _SCOPE_STACK[-1] if _SCOPE_STACK else phase
-    return f"{tag.replace('::', '.')}.{kernel}"
+    return f"{active_phase(phase).replace('::', '.')}.{kernel}"
+
+
+def active_phase(home: str) -> str:
+    """The phase a kernel called now is named under: the innermost active
+    `scope`, else `home`.  A cached kernel keeps it in its cache key, so a
+    kernel shared by two phases is built, and named, once under each."""
+    return _SCOPE_STACK[-1] if _SCOPE_STACK else home
 
 
 def emit(

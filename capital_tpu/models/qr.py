@@ -51,6 +51,7 @@ from jax.sharding import PartitionSpec as P
 
 from capital_tpu.models import cholesky
 from capital_tpu.models.cholesky import CholinvConfig
+from capital_tpu.obs import spans
 from capital_tpu.ops import lapack, pallas_tpu
 from capital_tpu.parallel import summa
 from capital_tpu.parallel.summa import GemmArgs, SyrkArgs, TrmmArgs
@@ -609,45 +610,30 @@ def pallas_coupled(
     """True when a 1d factor's outputs ride ops XLA cannot slice into (Q
     through pallas custom calls — the blocked/fused kernels engaged — and R
     through a whole-input potrf chain), making a one-element benchmark
-    carry measurement-safe (harness.timed_loop coupling='elem').  Lives
-    HERE, next to the kernel gating it mirrors (_sweep_1d's tri_kernel +
-    qr_fused.fused_plan): if the routing changes, this predicate must
-    change with it — a stale copy in a driver would let the simplifier
+    carry measurement-safe (harness.timed_loop coupling='elem').  It asks
+    factor()'s own routing (_route) and, on the sweeps, mirrors _sweep_1d's
+    tri_kernel gate — a stale copy in a driver would let the simplifier
     silently narrow the measured work.
 
-    On a mesh the per-shard fused pipeline (round 5) is the only pallas
-    route; deciding it needs the full (m, dtype) question — callers that
-    cannot supply them get the conservative False (full-consumption
+    The fused routes ride Mosaic custom calls (coupled); 'panels' is pure
+    XLA (one-element consumption would let the simplifier drop every other
+    panel — NOT coupled).  Deciding the route needs the full (m, dtype)
+    question; callers that cannot supply them get the sweeps' answer on
+    one device and the conservative False on a mesh (full-consumption
     coupling is always measurement-safe, just slower)."""
-    from capital_tpu.ops import qr_fused
-
-    if grid.num_devices == 1:
-        if mode != "pallas":
-            return False
-        if m is not None and dtype is not None:
-            # the authoritative answer: which tier does factor() route to?
-            # 'full'/'split' ride Mosaic custom calls (coupled); 'panels'
-            # is pure XLA (one-element consumption would let the
-            # simplifier drop every other panel — NOT coupled); None
-            # falls to the sweeps' tri-kernel predicate below
-            g = qr_fused.pick_g(n)
-            plan = (
-                qr_fused.fused_plan(grid, m, n, mode, g=g, dtype=dtype)
-                if g
-                else None
-            )
-            if plan is not None:
-                return plan != "panels"
-        # sweeps path (or an m/dtype-less caller, which never benches the
-        # wide shapes): the nb cap mirrors _sweep_1d's tri_kernel envelope
-        return _col_blocks(n) > 1 and n // _col_blocks(n) <= 2048
-    if m is None or dtype is None:
-        return False
-    g = qr_fused.pick_g(n)
-    plan = (
-        qr_fused.fused_plan(grid, m, n, mode, g=g, dtype=dtype) if g else None
+    route = "sweeps_1d"
+    if m is not None and dtype is not None:
+        route, _ = _route(grid, m, n, dtype, CacqrConfig(mode=mode), "1d")
+    if route != "sweeps_1d":
+        return route != "panels"
+    # the unfused sweeps (or an m/dtype-less caller, which never benches
+    # the wide shapes): the nb cap mirrors _sweep_1d's tri_kernel envelope
+    return (
+        grid.num_devices == 1
+        and mode == "pallas"
+        and _col_blocks(n) > 1
+        and n // _col_blocks(n) <= 2048
     )
-    return plan is not None and plan != "panels"
 
 
 def _pick_regime(grid: Grid, n: int, cfg: CacqrConfig) -> str:
@@ -665,40 +651,56 @@ def _pick_regime(grid: Grid, n: int, cfg: CacqrConfig) -> str:
     return "1d" if n <= cfg.dist_threshold else "dist"
 
 
-def _factor_core(
-    grid: Grid, A: jnp.ndarray, cfg: CacqrConfig, regime: str
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """The regime dispatch + sweep pipeline shared by the plain and robust
-    entries (factor)."""
-    m, n = A.shape
-    if regime == "1d":
-        from capital_tpu.ops import qr_fused
+def _route(
+    grid: Grid, m: int, n: int, dtype, cfg: CacqrConfig, regime: str
+) -> tuple[str, dict]:
+    """Which pipeline a build of factor() traces, and what it traces it
+    at: ``fused_sharded/<plan>`` (the fused kernels per shard on a mesh),
+    ``fused/<plan>`` (one device), ``panels``, ``sweeps_1d`` (the unfused
+    1d sweeps) or ``dist``, with the per-shard ``rows``, the column split
+    ``g`` and the fused kernels' row block ``bm`` (None off the kernels)."""
+    from capital_tpu.ops import qr_fused
 
-        g = qr_fused.pick_g(n, cfg.fused_g)
-        plan = (
-            qr_fused.fused_plan(grid, m, n, cfg.mode, g=g, dtype=A.dtype)
-            if cfg.num_iter == 2 and g
-            else None
-        )
+    rows = m // grid.num_devices
+    if regime != "1d":
+        return "dist", {"rows": rows, "g": 0, "bm": None}
+    g = qr_fused.pick_g(n, cfg.fused_g)
+    plan = (
+        qr_fused.fused_plan(grid, m, n, cfg.mode, g=g, dtype=dtype)
+        if cfg.num_iter == 2 and g
+        else None
+    )
+    fused = {"rows": rows, "g": g, "bm": qr_fused._eligible(rows, n, g=g)}
+    if grid.num_devices == 1:
         if plan == "panels":
-            # pure-XLA panel pipeline: single-device wide n (the mesh 1d
-            # path never engages the crashing kernel route)
-            if grid.num_devices == 1:
-                return _cqr2_panels(grid, A, cfg)
-        elif plan:
-            if grid.num_devices == 1:
-                return _cqr2_fused(grid, A, cfg, g, plan)
-            if not _ROBUST:
-                return _cqr2_fused_sharded(grid, A, cfg, g, plan)
-            # robust multi-device: the session's traced event values cannot
-            # escape the shard_map body — the guarded sweeps run unfused
-        Q, R = _sweep_1d(grid, A, cfg)
-        if cfg.num_iter == 2:
-            Q, R2 = _sweep_1d(grid, Q, cfg)
-            with tracing.scope("CQR::merge"):
-                tracing.emit(flops=2.0 * R.shape[0] ** 3)
-                R = jnp.matmul(jnp.triu(R2), jnp.triu(R), precision=cfg.precision)
-        return Q, R
+            return "panels", {"rows": rows, "g": n // 512, "bm": None}
+        if plan:
+            return f"fused/{plan}", fused
+    elif plan in ("full", "split") and not _ROBUST:
+        return f"fused_sharded/{plan}", fused
+    # a mesh never runs the panel pipeline, and a robust mesh build runs
+    # the guarded sweeps unfused: the session's traced event values cannot
+    # escape the shard_map body
+    return "sweeps_1d", {"rows": rows, "g": _col_blocks(n), "bm": None}
+
+
+def _sweeps_1d(
+    grid: Grid, A: jnp.ndarray, cfg: CacqrConfig
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """cfg.num_iter unfused 1d sweeps, their R factors merged."""
+    Q, R = _sweep_1d(grid, A, cfg)
+    if cfg.num_iter == 2:
+        Q, R2 = _sweep_1d(grid, Q, cfg)
+        with tracing.scope("CQR::merge"):
+            tracing.emit(flops=2.0 * R.shape[0] ** 3)
+            R = jnp.matmul(jnp.triu(R2), jnp.triu(R), precision=cfg.precision)
+    return Q, R
+
+
+def _sweeps_dist(
+    grid: Grid, A: jnp.ndarray, cfg: CacqrConfig
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """cfg.num_iter distributed sweeps, their R factors merged."""
     Q, R = _sweep_dist(grid, A, cfg)
     if cfg.num_iter == 2:
         Q, R2 = _sweep_dist(grid, Q, cfg)
@@ -710,6 +712,28 @@ def _factor_core(
                 TrmmArgs(side="L", uplo="U", precision=cfg.precision), mode=cfg.mode,
             )
     return Q, R
+
+
+def _factor_core(
+    grid: Grid, A: jnp.ndarray, cfg: CacqrConfig, regime: str
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The route dispatch shared by the plain and robust entries (factor).
+    Each build counts its route in `spans.ROUTES` and traces it under a
+    ``qr.route`` program span carrying the same tags."""
+    m, n = A.shape
+    route, tags = _route(grid, m, n, A.dtype, cfg, regime)
+    spans.ROUTES.take(route, **tags)
+    with spans.span("qr.route", route=route, **tags):
+        kind, _, plan = route.partition("/")
+        if kind == "fused_sharded":
+            return _cqr2_fused_sharded(grid, A, cfg, tags["g"], plan)
+        if kind == "fused":
+            return _cqr2_fused(grid, A, cfg, tags["g"], plan)
+        if kind == "panels":
+            return _cqr2_panels(grid, A, cfg)
+        if kind == "sweeps_1d":
+            return _sweeps_1d(grid, A, cfg)
+        return _sweeps_dist(grid, A, cfg)
 
 
 def _finish_robust(grid: Grid, A, Q, R, cfg: CacqrConfig, ses: _Session):

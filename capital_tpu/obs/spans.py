@@ -11,7 +11,8 @@ Two span kinds share this module and its clock:
   own build events into spans — ``build.trace``, ``build.lower``,
   ``build.compile`` — and counts each backend build as a persistent-cache
   load or a compile (`BUILDS`); `KERNELS` counts the Pallas call sites
-  and the distinct kernels built for them.  The span vocabulary is in
+  and the distinct kernels built for them; `ROUTES` counts the routes the
+  builds of `qr.factor` took.  The span vocabulary is in
   docs/OBSERVABILITY.md "Program spans".
 * **Request chains** (`RequestTrace`): every request the SolveEngine
   admits carries an ordered chain of spans covering its whole life —
@@ -321,6 +322,32 @@ class KernelCounter:
 
 
 KERNELS = KernelCounter()
+
+
+class RouteCounter:
+    """The routes the process's builds of an entry point took, counted at
+    trace time: ``take(route, **tags)`` once per build, the tags describing
+    what that build traced (for `qr.factor`: the per-shard rows, the
+    column split ``g`` and the row block ``bm``)."""
+
+    def __init__(self):
+        self._lock = threading.Lock()  # guarded-by: <lock>
+        self._builds: dict = {}  # guarded-by: self._lock
+        self._tags: dict = {}  # guarded-by: self._lock
+
+    def take(self, route: str, **tags) -> None:
+        with self._lock:
+            self._builds[route] = self._builds.get(route, 0) + 1
+            self._tags[route] = tags
+
+    def snapshot(self) -> dict:
+        """{route: {"builds": n, **the tags of its latest build}}."""
+        with self._lock:
+            return {r: {"builds": n, **self._tags[r]}
+                    for r, n in self._builds.items()}
+
+
+ROUTES = RouteCounter()
 _WATCH_LOCK = threading.Lock()
 _watching = False
 

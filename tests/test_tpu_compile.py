@@ -16,6 +16,7 @@ file so they share that fixture on one worker.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +24,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from capital_tpu.models import cholesky, qr
+from capital_tpu.obs import spans
 from capital_tpu.ops import batched_small, pallas_tpu, update_small
 from capital_tpu.parallel.topology import Grid
 
@@ -89,6 +91,24 @@ def test_cqr2_pallas(chip):
     cfg = qr.CacqrConfig(num_iter=2, mode="pallas")
     A = _sds(chip, (65536, 512), jnp.float32)
     assert _mosaic_calls(lambda a: qr.factor(g, a, cfg), A) > 0
+
+
+def test_cqr2_rows_over_four_chips(topo, monkeypatch):
+    """The cacqr-tall route: the fused tall-pass kernels per shard inside
+    shard_map on the four chips (g=8, plan "full"), and the two Gram
+    all-reduces between them."""
+    routes = spans.RouteCounter()
+    monkeypatch.setattr(spans, "ROUTES", routes)
+    g = Grid.flat(topo.devices[:4])
+    cfg = qr.CacqrConfig(num_iter=2, regime="1d", mode="pallas")
+    A = jax.ShapeDtypeStruct((4 * 8192, 1024), jnp.bfloat16,
+                             sharding=g.rows_sharding())
+    txt = jax.jit(lambda a: qr.factor(g, a, cfg)).lower(A).compile().as_text()
+    assert routes.snapshot() == {
+        "fused_sharded/full": {"builds": 1, "rows": 8192, "g": 8, "bm": 1024}}
+    for kernel in ("CQR.gram.gram", "CQR.fused.scale_gram", "CQR.formR.scale"):
+        assert kernel in txt, kernel
+    assert len(re.findall(r"all-reduce(?:-start)?\(", txt)) == 2
 
 
 @pytest.mark.parametrize("op,m", [("posv", 64), ("lstsq", 128)])

@@ -658,7 +658,10 @@ def _route(
     at: ``fused_sharded/<plan>`` (the fused kernels per shard on a mesh),
     ``fused/<plan>`` (one device), ``panels``, ``sweeps_1d`` (the unfused
     1d sweeps) or ``dist``, with the per-shard ``rows``, the column split
-    ``g`` and the fused kernels' row block ``bm`` (None off the kernels)."""
+    ``g`` and the row block each fused kernel is built with
+    (qr_fused.tall_bm): ``bm`` the Gram's (None off the kernels), and
+    ``bm_scale_gram`` and ``bm_scale`` the scales' where they differ from
+    it (0 where the kernel does not fit)."""
     from capital_tpu.ops import qr_fused
 
     rows = m // grid.num_devices
@@ -670,7 +673,10 @@ def _route(
         if cfg.num_iter == 2 and g
         else None
     )
-    fused = {"rows": rows, "g": g, "bm": qr_fused._eligible(rows, n, g=g)}
+    if plan in ("full", "split"):
+        bms = qr_fused.row_blocks(grid, rows, n, dtype)
+        fused = {"rows": rows, "g": g, "bm": bms.pop("gram")}
+        fused.update({f"bm_{k}": v for k, v in bms.items() if v != fused["bm"]})
     if grid.num_devices == 1:
         if plan == "panels":
             return "panels", {"rows": rows, "g": n // 512, "bm": None}

@@ -15,10 +15,11 @@ wrote it.  These kernels remove both redundancies (VERDICT r2 #3 — the
 "fused gram+scaling kernel" docs/PERF.md names as the remaining lever):
 
 * ``gram_blocked`` — one pass over A per gram: each (bm, n) row block is
-  read ONCE into VMEM and the g upper block-row products are taken from it
-  (G[jc:(j+1)c, jc:] += A_blk[:, jc:(j+1)c]ᵀ·A_blk[:, jc:]), accumulating
-  into a VMEM-resident f32 (n, n) output revisited by every grid step.
-  HBM traffic: m·n reads exactly (was 1.5 m·n).
+  read ONCE into VMEM, transposed once, and the g lower block-column
+  products are taken from it (G[jc:, jc:(j+1)c] += A_blkᵀ[jc:, :]·
+  A_blk[:, jc:(j+1)c]), accumulating into a VMEM-resident f32 (n, n)
+  output revisited by every grid step; the caller gets its transpose, the
+  upper block-row form.  HBM traffic: m·n reads exactly (was 1.5 m·n).
 * ``scale_gram`` — sweep 1's scale and sweep 2's gram in ONE pass: read a
   row block of A, Q_blk = A_blk·R⁻¹ via g column-block products (the
   zero lower blocks of the upper-triangular R⁻¹ are never touched:
@@ -32,6 +33,18 @@ reduce executed flops — (g+1)/2g of dense: 0.75 at g=2, 0.625 at g=4,
 0.5625 at g=8 — at zero extra HBM traffic, unlike the measured XLA-level
 g=4 loser (5x A reads + relayout copies, models/qr.py:_col_blocks).  The
 per-dot shapes stay MXU-aligned (every block dim a 128-multiple >= 128).
+The MXU holds one operand of a product as stationary 128×128 tiles and
+streams the other's rows through them: the Gram's products are oriented so
+that n − jc rows stream through each (bm, c) slab, not c = 128 rows
+through each tile of A_blk[:, jc:].  Both kernels that accumulate a Gram
+zero it at step 0 before they load the row block: a block value live
+across that branch cost the old Gram kernel 0.32 ms of its 3.66 a call on
+v5e at 524,288 × 1024 bf16, g=8, and the orientation with the deeper row
+block the next 0.14 (3.20 ms, 193 TF/s executed; scale_gram 6.49 → 6.41
+ms; PERF.md §6).
+
+The row block of each kernel is `tall_bm`'s: the deepest that divides m and
+fits the device's VMEM, up to a per-kernel depth measured on v5e.
 
 Kernels require n % (g*128) == 0 and bm | m; callers fall back to the
 unfused path otherwise.  The gram accumulates over row blocks in f32 (same
@@ -93,37 +106,77 @@ def _out_struct(shape, dtype, *operands):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def _pick_bm(m: int, preferred: int) -> int:
-    bm = preferred
-    while bm >= 256 and m % bm:
-        bm //= 2
-    return bm if m % bm == 0 else 0
-
-
 def live_fraction(g: int) -> float:
     """Executed fraction of the dense contraction at column split g."""
     return (g + 1) / (2.0 * g) if g > 1 else 1.0
 
 
-def _eligible(m: int, n: int, bm: int = 1024, g: int = 2) -> int:
-    """The ONE eligibility rule for every fused tall-pass kernel (and for
-    fused_ok): the g-way column split needs every block a 128-multiple of
-    at least 128 (g=2 additionally demands n/2 >= 256 — at n = 512 the
-    split's saving measured below its bookkeeping) and a row block that
-    tiles m.  Returns the picked bm, or 0 if ineligible."""
-    if g < 2 or n % (g * 128):
-        return 0
-    if g == 2 and n // 2 < 256:
-        return 0
-    return _pick_bm(m, bm)
+def _split_ok(n: int, g: int) -> bool:
+    """The ONE column-split rule for every fused tall-pass kernel: every
+    block a 128-multiple of at least 128 (g=2 additionally demands n/2 >=
+    256 — at n = 512 the split's saving measured below its bookkeeping)."""
+    return g >= 2 and n % (g * 128) == 0 and not (g == 2 and n // 2 < 256)
 
 
-def _shape_gate(name: str, m: int, n: int, bm: int, g: int) -> int:
-    bm = _eligible(m, n, bm, g)
-    if bm == 0:
+#: The deepest row block each kernel takes: own time per call on v5e at
+#: 524,288 × 1024 bf16, g=8, bm 1024/2048/4096/8192 (PERF.md §6):
+#: gram 3.31/3.24/3.20/not measured ms, scale 3.39/3.31/3.30/3.31,
+#: scale_gram 6.41/6.69/6.69/not measured (deeper blocks only slow it).
+_BM_MAX = {"gram": 4096, "scale_gram": 1024, "scale": 4096}
+
+
+def _vmem_bytes(kernel: str, bm: int, n: int, item: int) -> int:
+    """VMEM of one `kernel` call: Mosaic's scoped allocation — each
+    streamed (bm, n) block double-buffered (A for the Gram; A and Q for the
+    scales), the Gram's loaded block and its transpose, the resident
+    operands once (R⁻¹ in A's dtype, the f32 Gram) — and, for 4-byte
+    operands, the copies their bf16 MXU passes make of the block: up to 16
+    bytes a block element more at precision "highest" (compiled for v5e at
+    n = 1024-4096, PERF.md §6)."""
+    streamed = 1 if kernel == "gram" else 2
+    values = 2 * item if kernel == "gram" else 0
+    resident = {"gram": 4, "scale": item, "scale_gram": item + 4}[kernel]
+    passes = 16 if item >= 4 else 0
+    return (2 * streamed * item + values + passes) * bm * n + resident * n * n
+
+
+def tall_bm(kernel: str, m: int, n: int, dtype) -> int:
+    """The row block `kernel` ("gram", "scale_gram" or "scale") runs at over
+    m rows of width n: the deepest power of two from _BM_MAX[kernel] down to
+    128 that divides m and whose VMEM (_vmem_bytes) fits 0.85 of the limit
+    the kernels compile with, resolved against the scoped device.
+    Interpret mode has no VMEM.  0 when no block fits."""
+    limit = None
+    if not _interpret_default():
+        limit = 0.85 * (_device_budget()[1] or (16 << 20))
+    item = jnp.dtype(dtype).itemsize
+    bm = _BM_MAX[kernel]
+    while bm >= 128:
+        if m % bm == 0 and (
+            limit is None or _vmem_bytes(kernel, bm, n, item) <= limit
+        ):
+            return bm
+        bm //= 2
+    return 0
+
+
+def row_blocks(grid, rows: int, n: int, dtype) -> dict:
+    """{kernel: tall_bm(kernel, rows, n, dtype)}, resolved against the
+    GRID's platform, not the process default: callers outside a scoped
+    entry point (the multichip dryrun probing eligibility, the route
+    counter) must not touch the default backend."""
+    with device_scope(grid.mesh.devices.flat[0]):
+        return {k: tall_bm(k, rows, n, dtype) for k in _BM_MAX}
+
+
+def _shape_gate(name: str, kernel: str, A, bm: int | None, g: int) -> int:
+    m, n = A.shape
+    if bm is None:
+        bm = tall_bm(kernel, m, n, A.dtype)
+    if not (_split_ok(n, g) and bm and m % bm == 0):
         raise ValueError(
             f"{name} needs bm | m and a {g}-way 128-aligned column split "
-            f"(n % {g * 128} == 0), got {(m, n)}"
+            f"(n % {g * 128} == 0), got {(m, n)} at bm {bm}"
         )
     return bm
 
@@ -136,19 +189,24 @@ def pick_g(n: int, override: int = 0) -> int:
     g=2/4/8 (g=16 ineligible); 512k x 2048: 62.27 (g=8) vs 55.09 (g=16)
     ms.  Power-of-two n >= 256 take g = n/128 via the same rule; the gain
     per doubling shrinks ((g+1)/2g -> 1/2) while per-dot shapes hold at
-    128, so 'largest eligible' stays right."""
+    128, so 'largest eligible' stays right.  The executed rate that round
+    recorded falling with g (191 → 169 TF/s at g=8) was not the 128-wide
+    blocks: it was the Gram kernel's row block held live across its step-0
+    zeroing (0.32 ms of 3.66 a call) and its orientation, c = 128 rows
+    streamed per stationary MXU tile; at g=8 the Gram runs at 193 TF/s
+    executed (PERF.md §6)."""
     if override:
-        return override if _eligible(1 << 20, n, 1024, override) else 0
+        return override if _split_ok(n, override) else 0
     g = 2
     while n % (2 * g * 128) == 0:  # divisibility implies 128-wide blocks
         g *= 2
-    return g if _eligible(1 << 20, n, 1024, g) else 0
+    return g if _split_ok(n, g) else 0
 
 
 def gram_blocked(
     A: jnp.ndarray,
     *,
-    bm: int = 1024,
+    bm: int | None = None,
     g: int = 2,
     precision: str | None = None,
     interpret: bool | None = None,
@@ -156,29 +214,34 @@ def gram_blocked(
     """Upper-block-row gram of tall-skinny A at the g-way split: returns
     f32 (n, n) with block row j valid from column j·(n/g) (the strictly
     lower block triangle is zero — callers assemble the symmetric gram
-    with assemble_sym).  One HBM read of A total."""
+    with assemble_sym).  One HBM read of A total.  `bm` defaults to
+    tall_bm's."""
     if interpret is None:
         interpret = _interpret_default()
     m, n = A.shape
     c = n // g
-    bm = _shape_gate("gram_blocked", m, n, bm, g)
+    bm = _shape_gate("gram_blocked", "gram", A, bm, g)
     nsteps = m // bm
     acc = _acc_dtype(A.dtype)
 
     def kernel(a_ref, g_ref):
         i = pl.program_id(0)
-        a = a_ref[:]
 
         @pl.when(i == 0)
         def _():
             g_ref[:] = jnp.zeros_like(g_ref)
 
+        # loaded and transposed after the zeroing, not live across it (on
+        # v5e at 524,288 × 1024 bf16, g=8: 3.20 ms a call, 3.66 before it)
+        a = a_ref[:]
+        at = a.T  # once a step, so every product streams n − jc rows
         for j in range(g):
-            g_ref[j * c:(j + 1) * c, j * c:] += _dot(
-                a[:, j * c:(j + 1) * c], a[:, j * c:], acc,
-                trans_a=True, precision=precision,
+            rows = at[j * c:, :]
+            g_ref[j * c:, j * c:(j + 1) * c] += _dot(
+                rows, a[:, j * c:(j + 1) * c], acc, precision=precision,
             )
 
+    # the kernel accumulates the lower block columns: an n² transpose
     return pl.pallas_call(
         kernel,
         name=tracing.kernel_name("gram", "CQR::gram"),
@@ -198,14 +261,14 @@ def gram_blocked(
             transcendentals=0,
         ),
         interpret=interpret,
-    )(A)
+    )(A).T
 
 
 def scale_gram(
     A: jnp.ndarray,
     Rinv: jnp.ndarray,
     *,
-    bm: int = 1024,
+    bm: int | None = None,
     g: int = 2,
     precision: str | None = None,
     interpret: bool | None = None,
@@ -223,12 +286,18 @@ def scale_gram(
     if Rinv.shape != (n, n):
         raise ValueError(f"Rinv {Rinv.shape} does not match A {A.shape}")
     c = n // g
-    bm = _shape_gate("scale_gram", m, n, bm, g)
+    bm = _shape_gate("scale_gram", "scale_gram", A, bm, g)
     nsteps = m // bm
     acc = _acc_dtype(A.dtype)
 
     def kernel(a_ref, r_ref, q_ref, g_ref):
-        i = pl.program_id(0)
+        # zeroed first, so no block value is live across the branch (as in
+        # gram_blocked: 6.41 ms a call at 524,288 × 1024 bf16 on v5e, 6.49
+        # with the zeroing after Q)
+        @pl.when(pl.program_id(0) == 0)
+        def _():
+            g_ref[:] = jnp.zeros_like(g_ref)
+
         a = a_ref[:]
         # Q = A @ Rinv with the g-way structure: column block j of
         # upper-triangular Rinv has zeros below row (j+1)c, so it sees
@@ -246,11 +315,6 @@ def scale_gram(
             axis=1,
         ).astype(q_ref.dtype)
         q_ref[:] = q
-
-        @pl.when(i == 0)
-        def _():
-            g_ref[:] = jnp.zeros_like(g_ref)
-
         # sweep-2 gram from the rounded block, straight from registers
         for j in range(g):
             g_ref[j * c:(j + 1) * c, j * c:] += _dot(
@@ -292,7 +356,7 @@ def scale_blocked(
     A: jnp.ndarray,
     Rinv: jnp.ndarray,
     *,
-    bm: int = 1024,
+    bm: int | None = None,
     g: int = 2,
     precision: str | None = None,
     interpret: bool | None = None,
@@ -309,7 +373,7 @@ def scale_blocked(
     if Rinv.shape != (n, n):
         raise ValueError(f"Rinv {Rinv.shape} does not match A {A.shape}")
     c = n // g
-    bm = _shape_gate("scale_blocked", m, n, bm, g)
+    bm = _shape_gate("scale_blocked", "scale", A, bm, g)
     acc = _acc_dtype(A.dtype)
 
     def kernel(a_ref, r_ref, q_ref):
@@ -359,7 +423,7 @@ def assemble_sym(Gu: jnp.ndarray, c: int) -> jnp.ndarray:
     return Gu
 
 
-def fused_plan(grid, m: int, n: int, mode: str, bm: int = 1024, g: int = 2,
+def fused_plan(grid, m: int, n: int, mode: str, g: int = 2,
                *, dtype) -> str | None:
     """Which fused CQR2 pipeline can run?  Returns
 
@@ -376,48 +440,38 @@ def fused_plan(grid, m: int, n: int, mode: str, bm: int = 1024, g: int = 2,
                 at wide n the pipeline is MXU-bound (arithmetic intensity
                 ~n/6 flops/byte), so the extra pass is noise next to the
                 (g+1)/2g executed-flop drop;
+      'panels' — past every kernel's envelope, n % 512 == 0;
       None    — fall back to the unfused blocked sweeps.
 
-    Gating: pallas mode, the shared kernel eligibility rule (_eligible)
-    applied to the PER-SHARD row extent (on a mesh the kernels run per
-    shard inside shard_map — models/qr.py _cqr2_fused_sharded — so
-    eligibility is about each device's m/p rows), and the per-kernel VMEM
-    envelopes above."""
+    Gating: pallas mode, the shared column-split rule (_split_ok), and a
+    row block of each kernel (row_blocks: tall_bm, the rule the kernels
+    are built with) on the PER-SHARD row extent (on a mesh the kernels run
+    per shard inside shard_map — models/qr.py _cqr2_fused_sharded — so
+    eligibility is about each device's m/p rows).  Interpret mode has no
+    VMEM, so the CPU test rig takes the fused tiers wherever v5e's
+    divisibility allows."""
     p = grid.num_devices
     if p > 1 and m % p:
         return None  # shard_map needs the row axis to divide evenly
-    bm_ok = _eligible(m // p, n, bm, g)
-    if not (mode == "pallas" and bm_ok):
+    rows = m // p
+    if not (mode == "pallas" and _split_ok(n, g) and rows % 128 == 0):
         return None
-    # resolve interpret/VMEM against the GRID's platform, not the process
-    # default: callers outside a scoped entry point (e.g. the multichip
-    # dryrun probing eligibility) must not touch the default backend
-    with device_scope(grid.mesh.devices.flat[0]):
-        if _interpret_default():
-            # interpret mode has no VMEM: applying the hardware envelope
-            # here would route the CPU test rig differently from v5e (fused
-            # wide-n coverage would silently vanish from CI)
-            return "full"
-        item = jnp.dtype(dtype).itemsize
-        limit = 0.85 * (_device_budget()[1] or (16 << 20))
-        if 2 * bm_ok * n * item + n * n * (item + 4) <= limit:
-            return "full"
-        gram_res = bm_ok * n * item + 4 * n * n
-        scale_res = 2 * bm_ok * n * item + n * n * item
-        if max(gram_res, scale_res) <= limit:
-            return "split"
-        if n % 512 == 0:
-            # beyond every kernel envelope: the XLA-level panel pipeline
-            # (models/qr.py _cqr2_panels) — same (g+1)/2g saving, no VMEM
-            # constraint; at these widths the pipeline is MXU-bound
-            # (arithmetic intensity ~n/(g+1) flops/byte), so the extra
-            # panel reads the round-4 n=1024 measurement rejected are
-            # noise here
-            return "panels"
-        return None
+    bms = row_blocks(grid, rows, n, dtype)
+    if all(bms.values()):
+        return "full"
+    if bms["gram"] and bms["scale"]:
+        return "split"
+    if n % 512 == 0:
+        # beyond every kernel envelope: the XLA-level panel pipeline
+        # (models/qr.py _cqr2_panels) — same (g+1)/2g saving, no VMEM
+        # constraint; at these widths the pipeline is MXU-bound
+        # (arithmetic intensity ~n/(g+1) flops/byte), so the extra
+        # panel reads the round-4 n=1024 measurement rejected are
+        # noise here
+        return "panels"
+    return None
 
 
-def fused_ok(grid, m: int, n: int, mode: str, bm: int = 1024, g: int = 2,
-             *, dtype) -> bool:
+def fused_ok(grid, m: int, n: int, mode: str, g: int = 2, *, dtype) -> bool:
     """True when ANY fused pipeline tier can run (see fused_plan)."""
-    return fused_plan(grid, m, n, mode, bm, g, dtype=dtype) is not None
+    return fused_plan(grid, m, n, mode, g, dtype=dtype) is not None

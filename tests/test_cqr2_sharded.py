@@ -30,6 +30,10 @@ M, N = 4 * 2048, 1024
 CFG = qr.CacqrConfig(num_iter=2, regime="1d", mode="pallas")
 TOL = 6e-3
 SEEDS = (0, 1)
+# the row blocks the fused kernels are built with on a 2048-row shard
+# (qr_fused.tall_bm): the Gram and the final scale as deep as the shard,
+# scale_gram at 1024
+BMS = {"bm": 2048, "bm_scale_gram": 1024}
 
 
 @pytest.fixture(scope="module")
@@ -80,7 +84,7 @@ def test_cell_route_matches_float64(grid4, routes, seed):
     A = _operand(seed)
     Q, R = _factor(grid4, A)
     assert routes.snapshot() == {
-        "fused_sharded/full": {"builds": 1, "rows": 2048, "g": 8, "bm": 1024}}
+        "fused_sharded/full": {"builds": 1, "rows": 2048, "g": 8, **BMS}}
     assert Q.sharding.is_equivalent_to(grid4.rows_sharding(), 2)
     for Qref, Rref in (_cqr2_f64(A), _householder(A)):
         assert _gap(Q, Qref) < TOL
@@ -93,7 +97,7 @@ def test_route_span_carries_the_tags(grid4, routes, monkeypatch):
     _factor(grid4, _operand(0))
     (rec,) = log.records("qr.route")
     assert rec.tags == {"route": "fused_sharded/full", "rows": 2048, "g": 8,
-                        "bm": 1024}
+                        **BMS}
 
 
 def test_float8_operand_fails_the_tolerance(grid4):
@@ -116,8 +120,9 @@ def test_forced_fallback_takes_the_unfused_sweeps(grid4, routes, monkeypatch):
 
 
 @pytest.mark.parametrize("case,route,tags", [
-    ("flat4", "fused_sharded/full", {"rows": 2048, "g": 8, "bm": 1024}),
-    ("one", "fused/full", {"rows": 8192, "g": 8, "bm": 1024}),
+    ("flat4", "fused_sharded/full", {"rows": 2048, "g": 8, **BMS}),
+    ("one", "fused/full", {"rows": 8192, "g": 8, "bm": 4096,
+                           "bm_scale_gram": 1024}),
     ("flat4_robust", "sweeps_1d", {"rows": 2048, "g": 2, "bm": None}),
     ("flat4_xla", "sweeps_1d", {"rows": 2048, "g": 2, "bm": None}),
     ("one_wide", "panels", {"rows": 8192, "g": 8, "bm": None}),

@@ -4,6 +4,8 @@ The fused pipeline must agree with the unfused blocked pipeline (same
 grams-from-rounded-Q math, different reduction association) and pass the
 reference residual gates."""
 
+import types
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,7 +13,7 @@ import pytest
 
 from capital_tpu.models import qr
 from capital_tpu.models.qr import CacqrConfig
-from capital_tpu.ops import qr_fused
+from capital_tpu.ops import pallas_tpu, qr_fused
 from capital_tpu.parallel.topology import Grid
 from capital_tpu.utils import rand48, residual
 
@@ -52,37 +54,56 @@ class TestKernels:
             np.asarray(G), Qr.T @ Qr, rtol=1e-5, atol=1e-4
         )
 
-    @pytest.mark.parametrize("g", [4, 8])
-    def test_gram_blocked_finer_splits(self, g):
+    # the last case runs each kernel at tall_bm's block (4096 for the Gram
+    # and the final scale, 1024 for scale_gram) and holds it to bm=512
+    @pytest.mark.parametrize("g,m,bm", [
+        pytest.param(4, 2048, 512, id="4"),
+        pytest.param(8, 2048, 512, id="8"),
+        pytest.param(8, 8192, None, id="8-tall_bm"),
+    ])
+    def test_gram_blocked_finer_splits(self, g, m, bm):
         # in-kernel g=4/8 column blocking (VERDICT r3 #1): same gram,
         # fewer executed flops, block-triangular valid region
-        A = _tall(2048, 1024).astype(jnp.float32)
+        A = _tall(m, 1024).astype(jnp.float32)
         c = 1024 // g
-        Gu = qr_fused.gram_blocked(A, bm=512, g=g)
+        Gu = qr_fused.gram_blocked(A, bm=bm, g=g)
         G = qr_fused.assemble_sym(Gu, c)
         want = np.asarray(A, np.float64).T @ np.asarray(A, np.float64)
         np.testing.assert_allclose(np.asarray(G), want, rtol=1e-5, atol=1e-4)
         Gu_np = np.asarray(Gu)
         for i in range(1, g):
             np.testing.assert_array_equal(Gu_np[i * c:(i + 1) * c, : i * c], 0.0)
+        np.testing.assert_allclose(
+            Gu_np, np.asarray(qr_fused.gram_blocked(A, bm=512, g=g)),
+            rtol=1e-5, atol=1e-4,
+        )
 
-    @pytest.mark.parametrize("g", [4, 8])
-    def test_scale_gram_finer_splits(self, g):
+    @pytest.mark.parametrize("g,m,bm", [
+        pytest.param(4, 1024, 512, id="4"),
+        pytest.param(8, 1024, 512, id="8"),
+        pytest.param(8, 8192, None, id="8-tall_bm"),
+    ])
+    def test_scale_gram_finer_splits(self, g, m, bm):
         rng = np.random.default_rng(9)
-        A = _tall(1024, 1024, key=8).astype(jnp.float32)
+        A = _tall(m, 1024, key=8).astype(jnp.float32)
         n = 1024
         c = n // g
         Rinv = jnp.asarray(
             np.triu(rng.standard_normal((n, n)) * 0.1 + np.eye(n))
         ).astype(jnp.float32)
-        Q, Gu = qr_fused.scale_gram(A, Rinv, bm=512, g=g)
+        Q, Gu = qr_fused.scale_gram(A, Rinv, bm=bm, g=g)
         wantQ = np.asarray(A, np.float64) @ np.asarray(Rinv, np.float64)
         np.testing.assert_allclose(np.asarray(Q), wantQ, rtol=1e-4, atol=1e-3)
         Qr = np.asarray(Q, np.float64)
         G = qr_fused.assemble_sym(Gu, c)
         np.testing.assert_allclose(np.asarray(G), Qr.T @ Qr, rtol=1e-5, atol=1e-3)
-        Qs = qr_fused.scale_blocked(A, Rinv, bm=512, g=g)
+        Qs = qr_fused.scale_blocked(A, Rinv, bm=bm, g=g)
         np.testing.assert_allclose(np.asarray(Qs), wantQ, rtol=1e-4, atol=1e-3)
+        np.testing.assert_allclose(
+            np.asarray(Qs),
+            np.asarray(qr_fused.scale_blocked(A, Rinv, bm=512, g=g)),
+            rtol=1e-5, atol=1e-5,
+        )
 
     def test_f32_precision_high_three_pass(self):
         # precision='high' on f32 operands must take the in-kernel bf16x3
@@ -113,6 +134,39 @@ class TestKernels:
         assert qr_fused.pick_g(192) == 0  # no 128-aligned split
         assert qr_fused.pick_g(1024, override=4) == 4
         assert qr_fused.pick_g(384, override=8) == 0  # override ineligible
+
+    @pytest.mark.parametrize("kernel,m,n,dtype,want", [
+        # the cacqr.2Mx1024.x4 shard: the depths the v5e sweep chose
+        ("gram", 524288, 1024, jnp.bfloat16, 4096),
+        ("scale", 524288, 1024, jnp.bfloat16, 4096),
+        ("scale_gram", 524288, 1024, jnp.bfloat16, 1024),
+        # halves until it divides m, and never exceeds m
+        ("gram", 524288 + 2048, 1024, jnp.bfloat16, 2048),
+        ("gram", 3 * 128, 1024, jnp.bfloat16, 128),
+        ("scale", 2048, 512, jnp.bfloat16, 2048),
+        ("gram", 1000, 1024, jnp.bfloat16, 0),
+        # f32 shrinks the block: four 4-byte blocks and the copies of the
+        # f32 passes, in the 85 MiB the rule allows of v5e's 100 MiB
+        ("scale", 524288, 2048, jnp.bfloat16, 4096),
+        ("scale", 524288, 2048, jnp.float32, 1024),
+        ("scale_gram", 524288, 2048, jnp.float32, 512),
+        # n = 4096: the 64 MiB f32 Gram leaves room for 512 bf16 rows (the
+        # block twice, the loaded block and its transpose), 128 f32 rows;
+        # scale_gram fits at no depth (the 'split' tier)
+        ("gram", 524288, 4096, jnp.bfloat16, 512),
+        ("gram", 524288, 4096, jnp.float32, 128),
+        ("scale_gram", 524288, 4096, jnp.bfloat16, 0),
+    ])
+    def test_tall_bm_on_v5e(self, kernel, m, n, dtype, want):
+        v5e = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+        with pallas_tpu.device_scope(v5e):
+            assert qr_fused.tall_bm(kernel, m, n, dtype) == want
+
+    def test_tall_bm_in_interpret_mode(self):
+        # no VMEM: the measured depth, capped by m, at any width or dtype
+        assert qr_fused.tall_bm("gram", 524288, 8192, jnp.float32) == 4096
+        assert qr_fused.tall_bm("scale_gram", 524288, 8192, jnp.float32) == 1024
+        assert qr_fused.tall_bm("scale", 2048, 8192, jnp.float32) == 2048
 
     def test_shape_gates(self):
         A = _tall(1000, 512).astype(jnp.float32)  # 1000 not tileable
@@ -158,17 +212,16 @@ class TestFusedPipeline:
         assert float(residual.qr_orthogonality(Qs)) < 1e-14
 
     def test_fused_plan_tiers(self, grid1, monkeypatch):
-        # envelope arithmetic on a simulated v5e budget: narrow n -> 'full';
+        # envelope arithmetic on v5e's budget: narrow n -> 'full';
         # n=4096 exceeds scale_gram's envelope but not the per-kernel ones
-        # -> 'split'; n=8192's gram alone exceeds VMEM -> None
-        from capital_tpu.ops import pallas_tpu
-
+        # -> 'split'; n=8192's gram alone exceeds VMEM -> 'panels'
         monkeypatch.setattr(pallas_tpu, "_default_backend", lambda: "tpu")
         monkeypatch.setattr(
             qr_fused, "_interpret_default", lambda: False
         )
         monkeypatch.setattr(
-            qr_fused, "_device_budget", lambda: (512, 128 << 20)
+            qr_fused, "_device_budget",
+            lambda: pallas_tpu._TILE_BUDGET["TPU v5 lite"],
         )
         bf = jnp.bfloat16
         assert qr_fused.fused_plan(

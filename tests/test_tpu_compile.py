@@ -25,7 +25,7 @@ from jax.sharding import SingleDeviceSharding
 
 from capital_tpu.models import cholesky, qr
 from capital_tpu.obs import spans
-from capital_tpu.ops import batched_small, pallas_tpu, update_small
+from capital_tpu.ops import batched_small, pallas_tpu, qr_fused, update_small
 from capital_tpu.parallel.topology import Grid
 
 
@@ -105,10 +105,30 @@ def test_cqr2_rows_over_four_chips(topo, monkeypatch):
                              sharding=g.rows_sharding())
     txt = jax.jit(lambda a: qr.factor(g, a, cfg)).lower(A).compile().as_text()
     assert routes.snapshot() == {
-        "fused_sharded/full": {"builds": 1, "rows": 8192, "g": 8, "bm": 1024}}
+        "fused_sharded/full": {"builds": 1, "rows": 8192, "g": 8, "bm": 4096,
+                               "bm_scale_gram": 1024}}
     for kernel in ("CQR.gram.gram", "CQR.fused.scale_gram", "CQR.formR.scale"):
         assert kernel in txt, kernel
     assert len(re.findall(r"all-reduce(?:-start)?\(", txt)) == 2
+
+
+@pytest.mark.parametrize("kernel,n,dtype,bm", [
+    ("gram", 4096, jnp.bfloat16, 512),  # the 'split' tier's Gram
+    ("scale_gram", 2048, jnp.float32, 512),  # the f32 passes' copies
+])
+def test_tall_pass_kernel_at_its_row_block(chip, kernel, n, dtype, bm):
+    """The row block qr_fused.tall_bm picks near the edge of its VMEM model
+    compiles, at the precision CacqrConfig passes ("highest")."""
+    with pallas_tpu.device_scope(chip):
+        assert qr_fused.tall_bm(kernel, 65536, n, dtype) == bm
+    g = qr_fused.pick_g(n)
+    fn = {
+        "gram": lambda a, r: qr_fused.gram_blocked(a, g=g, precision="highest"),
+        "scale_gram": lambda a, r: qr_fused.scale_gram(a, r, g=g,
+                                                       precision="highest"),
+    }[kernel]
+    A, R = _sds(chip, (65536, n), dtype), _sds(chip, (n, n), dtype)
+    assert _mosaic_calls(fn, A, R, scope=chip) == 1
 
 
 @pytest.mark.parametrize("op,m", [("posv", 64), ("lstsq", 128)])

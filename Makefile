@@ -1,14 +1,13 @@
 # Convenience targets mirroring the reference's Makefile surface
 # (all / benchmarking / tune / clean — reference Makefile:1-29).  The real
-# build is standard Python packaging (pyproject.toml); the native host
-# engine compiles itself lazily (capital_tpu/native/__init__.py).
+# build is standard Python packaging (pyproject.toml).
 
 PY ?= python
 
 .PHONY: all test benchmarking bench-explicit bench-small bench-blocktri \
 	bench-blocktri-par bench-arrowhead bench-update bench-refine \
 	bench-session tune audit lint lint-concurrency robust serve-smoke \
-	serve-bench serve-replicas serve-trace native clean
+	serve-bench serve-replicas serve-trace clean
 
 all: test
 
@@ -306,9 +305,6 @@ serve-trace:
 # virtual mesh and enables x64
 robust:
 	$(PY) -m pytest tests/test_robust.py tests/test_faultinject.py -q
-
-native:
-	$(PY) -c "from capital_tpu import native; print('native engine available:', native.available())"
 
 clean:
 	rm -rf autotune_out .pytest_cache bench_explicit.jsonl serve_smoke.jsonl \

@@ -23,13 +23,18 @@ Package layout:
                (reference L3' src/blas + src/lapack)
   models/    - the algorithm families: cholesky (cholinv), qr (cacqr),
                inverse (rectri/newton), trsm (reference L4 src/alg)
-  utils/     - deterministic fillers, residual validation, tracing, config
-               (reference src/util + test/ + critter shims)
-  bench/     - benchmark drivers (reference bench/)
-  autotune/  - config sweep harness (reference autotune/)
-  native/    - C++ host engine (ctypes): coordinate-seeded fillers, layout
-               repacks, and the alpha-beta schedule planner, with NumPy
-               fallbacks (the host-native remainder of the reference's C++)
+  utils/     - deterministic fillers, residual validation (gates and test
+               operands), tracing, config (reference src/util + test/ +
+               critter shims)
+  robust/    - breakdown detection, recovery, refinement
+  serve/     - the batching solve engine, its stats and router
+  obs/, lint/ - program audits, ledgers, spans; the program sanitizer
+  bench/     - per-algorithm benchmark CLI (reference bench/); a leaf:
+               only autotune/ imports it
+  autotune/  - config sweep harness and its alpha-beta schedule planner
+               (reference autotune/)
+
+The measurement of record is the repo's benchmark/ harness, not bench/.
 """
 
 __version__ = "0.1.0"

@@ -38,7 +38,7 @@ def main(argv=None) -> None:
     )
     p.add_argument(
         "--top-k", type=int, default=0,
-        help="cholinv: measure only the native planner's top-k model candidates",
+        help="cholinv: measure only the planner's top-k model candidates",
     )
     p.add_argument(
         "--resume", action="store_true",

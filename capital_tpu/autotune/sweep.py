@@ -36,8 +36,10 @@ import numpy as np
 
 from capital_tpu.bench import harness
 from capital_tpu.models import cholesky, qr
+from capital_tpu.parallel import summa
 from capital_tpu.parallel.topology import Grid
-from capital_tpu.utils import tracing
+from capital_tpu.serve.stats import percentiles
+from capital_tpu.utils import residual, tracing
 from capital_tpu.utils.config import BaseCasePolicy
 
 
@@ -403,14 +405,6 @@ def run_sweep(
 # --------------------------------------------------------------------------
 
 
-def _spd(n: int, dtype) -> jnp.ndarray:
-    # one SPD builder for every harness consumer (3I shift + on-device
-    # generation — see drivers._spd for the numerical rationale)
-    from capital_tpu.bench.drivers import _spd as _drivers_spd
-
-    return _drivers_spd(n, dtype)
-
-
 def grid_space(
     devices=None,
     c_values: Iterable[int] = (1, 2, 4),
@@ -491,7 +485,7 @@ def cholinv_space(
     sweep.  `tail_depths` adds the fused-recursion-tail axis
     (CholinvConfig.tail_fuse_depth; depth 0 = unfused, the default, so
     existing config ids stay stable)."""
-    prec = None if jnp.dtype(dtype).itemsize < 4 else "highest"
+    prec = summa.default_precision(dtype)
     glist = _with_grids(grids, grid)
     for g, pol, bc, split, mode, bal, td in itertools.product(
         glist, policies, bc_dims, splits, modes, balances, tail_depths
@@ -544,7 +538,7 @@ def cacqr_space(
     """variant x bc x regime (x grid shape) — qr tune.cpp sweeps
     bcMultiplier x grid shape; pass grids=grid_space(include_flat=True) to
     sweep the topology axis on real hardware."""
-    prec = None if jnp.dtype(dtype).itemsize < 4 else "highest"
+    prec = summa.default_precision(dtype)
     glist = _with_grids(grids, grid)
     for g, variant, bc, regime in itertools.product(
         glist, variants, bc_dims, regimes
@@ -585,7 +579,7 @@ def trsm_space(
     trsm driver's jit-argument loop is the large-n path)."""
     from capital_tpu.models import trsm as trsm_mod
 
-    prec = None if jnp.dtype(dtype).itemsize < 4 else "highest"
+    prec = summa.default_precision(dtype)
     for bc, leaf, mode in itertools.product(bc_dims, leaves, modes):
         cfg = trsm_mod.TrsmConfig(
             base_case_dim=bc, mode=mode, precision=prec, leaf=leaf
@@ -615,7 +609,7 @@ def latency_measure(calls: int = 32, warmup: int = 3) -> Callable:
         samples = harness.latency_samples(
             lambda: fn(operand), calls=calls, warmup=warmup
         )
-        pcts = harness.percentiles(samples)
+        pcts = percentiles(samples)
         return pcts["p99"], {
             "wall_ms": {k: round(v * 1e3, 4) for k, v in pcts.items()}
         }
@@ -642,7 +636,7 @@ def batched_small_space(
     from capital_tpu.ops import batched_small
     from capital_tpu.serve import api
 
-    prec = None if jnp.dtype(dtype).itemsize < 4 else "highest"
+    prec = summa.default_precision(dtype)
     for impl in impls:
         if impl == "vmap":
             fn = api.batched(op, prec, "vmap")
@@ -777,7 +771,7 @@ def blocktri_space(
     from capital_tpu.models import blocktri
     from capital_tpu.ops import batched_small
 
-    prec = None if jnp.dtype(dtype).itemsize < 4 else "highest"
+    prec = summa.default_precision(dtype)
     for impl in impls:
         if impl not in ("xla", "pallas", "partitioned"):
             raise ValueError(
@@ -913,7 +907,7 @@ def arrowhead_space(
     from capital_tpu.ops import batched_small
 
     F, S, B_rhs, Bs = tail
-    prec = None if jnp.dtype(dtype).itemsize < 4 else "highest"
+    prec = summa.default_precision(dtype)
     for impl in impls:
         if impl not in ("xla", "pallas", "partitioned"):
             raise ValueError(
@@ -1064,7 +1058,7 @@ def update_small_space(
             f"'chol_downdate', got {op!r}"
         )
     fn = getattr(update_small, op)
-    prec = None if jnp.dtype(dtype).itemsize < 4 else "highest"
+    prec = summa.default_precision(dtype)
     for impl in impls:
         if impl not in ("xla", "pallas"):
             raise ValueError(
@@ -1165,8 +1159,6 @@ def tune_trsm(
     ledger: str | None = None,
     **space,
 ) -> list[SweepResult]:
-    from capital_tpu.bench.drivers import _tri_operand
-
     if n > 8192:
         raise ValueError(
             f"tune_trsm: n={n} exceeds the sweep bound (8192): the closed-"
@@ -1174,7 +1166,7 @@ def tune_trsm(
             "breaks the compile server at n >= 16384 (HTTP 413) — use the "
             "trsm bench driver's jit-argument loop for large-n measurement"
         )
-    L = _tri_operand(n, dtype)
+    L = residual.tri_operand(n, dtype)
     B = jax.block_until_ready(
         jax.random.normal(jax.random.key(1), (n, nrhs), dtype=dtype)
     )
@@ -1195,14 +1187,14 @@ def tune_cholinv(
     ledger: str | None = None,
     **space,
 ) -> list[SweepResult]:
-    """Sweep cholinv configs.  With prefilter_top_k > 0, the native
-    alpha-beta planner (native.cholinv_predict) ranks the (policy, bc) space
+    """Sweep cholinv configs.  With prefilter_top_k > 0, the alpha-beta
+    planner (planner.cholinv_predict) ranks the (policy, bc) space
     first and only the top-k model candidates are measured — the predictive
     upgrade over the reference's measure-everything sweep (tune.cpp:239-253)."""
-    A = _spd(n, dtype)
+    A = residual.spd_operand(n, dtype)
     configs = list(cholinv_space(grid, dtype, **space))
     if prefilter_top_k and prefilter_top_k < len(configs):
-        from capital_tpu import native
+        from capital_tpu.autotune import planner
 
         if len({c[1].get("layout", 0) for c in configs}) > 1:
             # the alpha-beta model is layout-insensitive (device ordering
@@ -1225,7 +1217,7 @@ def tune_cholinv(
             # cut across a layout axis prunes on modeled cost only.
             shape = tuple(cdict.get("grid_shape", (grid.dx, grid.dy, grid.c)))
             q = cdict.get("num_chunks", grid.num_chunks)
-            out, _ = native.cholinv_predict(
+            out, _ = planner.cholinv_predict(
                 n, shape,
                 [cdict["base_case_dim"]],
                 [BaseCasePolicy[cdict["policy"]]],

@@ -136,34 +136,6 @@ def run_guarded(
 NOISE_BAND_S = 0.002
 
 
-def percentiles(
-    samples, points: tuple[float, ...] = (50.0, 95.0, 99.0)
-) -> dict[str, float]:
-    """Nearest-rank percentiles of raw samples: {'p50': ..., 'p95': ...,
-    'p99': ...}.  The ONE quantile implementation shared by the bench
-    report lines (drivers._timed's wall_ms block) and the serving layer's
-    latency stats (serve/stats.py) — duplicated quantile code is how two
-    dashboards end up disagreeing about the same run.
-
-    Nearest-rank (ceil) deliberately: every reported value is a sample that
-    actually occurred, so a p99 can be shown next to the raw max without
-    interpolation artifacts.  Dependency-free (no numpy) so stats paths add
-    zero imports."""
-    s = sorted(samples)
-    if not s:
-        raise ValueError("percentiles() needs at least one sample")
-    import math
-
-    out = {}
-    for p in points:
-        if not 0.0 < p <= 100.0:
-            raise ValueError(f"percentile point {p} outside (0, 100]")
-        rank = max(1, math.ceil(p / 100.0 * len(s)))
-        label = f"p{int(p)}" if float(p).is_integer() else f"p{p}"
-        out[label] = s[rank - 1]
-    return out
-
-
 def latency_samples(fn, calls: int = 32, warmup: int = 3) -> list[float]:
     """Per-call wall seconds of `fn()` — the SERVING-latency protocol, the
     deliberate opposite of timed_loop's in-jit amortized one: each sample
@@ -171,8 +143,8 @@ def latency_samples(fn, calls: int = 32, warmup: int = 3) -> list[float]:
     served request pays exactly that, and a p99 over amortized loop bodies
     would hide the dispatch tail a latency SLO exists to catch.  Compile
     time stays out via the warmup calls.  Feed the result to
-    `percentiles()` — the shared quantile rule keeps a bench latency row
-    and a serve request_stats record on one scale."""
+    `serve.stats.percentiles` — the shared quantile rule keeps a bench
+    latency row and a serve request_stats record on one scale."""
     import time
 
     if calls < 1:
@@ -209,9 +181,9 @@ def paired_median_delta(
     """(per-iteration seconds, raw delta): median over INTERLEAVED
     (base, full) wall pairs of `run(1)` vs `run(k+1)`.
 
-    The one measurement protocol shared by the flagship bench.py and
-    timed_loop.  Adjacent pairs share a drift window, so the delta isolates
-    the in-jit iterations; sampling all bases then all fulls lets monotone
+    The measurement protocol under timed_loop and timed_oneshot.  Adjacent
+    pairs share a drift window, so the delta isolates the in-jit
+    iterations; sampling all bases then all fulls lets monotone
     drift between the blocks bias the result (observed: 16.8 ms/iter
     reported for a step whose device-counter op time is 26.6 ms and whose
     200-iteration sustained marginal is 24.9 ms).  The median rejects
@@ -220,9 +192,10 @@ def paired_median_delta(
     "time".
 
     `samples_out` (a list) collects the raw per-iteration seconds of each
-    pair (delta / k) for percentile reporting (percentiles()); individual
-    samples keep the jitter the median rejects — including possible
-    negatives — which is exactly what a spread statistic should see."""
+    pair (delta / k) for percentile reporting (serve.stats.percentiles);
+    individual samples keep the jitter the median rejects — including
+    possible negatives — which is exactly what a spread statistic should
+    see."""
     import statistics
 
     deltas = []
@@ -254,6 +227,26 @@ def _make_loop(step: Callable, coupling: str):
         return jnp.sum(out, dtype=jnp.float32)
 
     return loop
+
+
+def pallas_coupled(grid, m: int, n: int, mode: str, dtype) -> bool:
+    """True when a 1d qr.factor of an (m, n) operand returns outputs that
+    ride ops XLA cannot slice into (Q through pallas custom calls — the
+    blocked/fused kernels engaged — and R through a whole-input potrf
+    chain), making the one-element carry (coupling='elem') measurement-safe.
+    It asks the model which pipeline it builds (qr.route): the fused routes
+    ride Mosaic custom calls (coupled); 'panels' is pure XLA (one-element
+    consumption would let the simplifier drop every other panel — NOT
+    coupled); the unfused sweeps are coupled on one device in pallas mode
+    when the column-blocked scaling runs the live-tile trmm kernel (block
+    width <= 2048)."""
+    from capital_tpu.models import qr
+
+    route, tags = qr.route(grid, m, n, dtype, qr.CacqrConfig(mode=mode), "1d")
+    if route != "sweeps_1d":
+        return route != "panels"
+    g = tags["g"]
+    return grid.num_devices == 1 and mode == "pallas" and g > 1 and n // g <= 2048
 
 
 def device_ms_per_iter(
@@ -320,9 +313,6 @@ def timed_loop(
     deliberately: for arbitrary steps (xla-mode SUMMA, plain matmul chains)
     a one-element coupling would let the algebraic simplifier legitimately
     narrow slices into the producing ops and shrink the measured work.
-    bench.py's flagship loop uses the cheaper element coupling only because
-    its outputs come through chains of aliased pallas custom calls XLA
-    cannot slice through (verified on-device — see the comment there).
     The cost: up to ~4 extra HBM passes of harness overhead per iteration,
     so suite/autotune numbers are slightly conservative.
 
@@ -376,13 +366,13 @@ def timed_oneshot(
     repeats: int = 8,
     device_check: bool = False,
 ) -> tuple[float, float, dict]:
-    """The one-shot protocol (bench.py's large-n flagship discipline, made
-    reusable): the operand is REGENERATED inside the loop each iteration by
-    `gen(i)` (a fused elementwise program of the loop index — no persistent
-    operand carry, so peak memory excludes it) and `step(a)` must return a
+    """The one-shot protocol for operands too large to carry: the operand
+    is REGENERATED inside the loop each iteration by `gen(i)` (a fused
+    elementwise program of the loop index — no persistent operand carry,
+    so peak memory excludes it) and `step(a)` must return a
     scalar coupling value riding ops XLA cannot narrow (pallas chains /
     whole-input consumers — the caller asserts this, e.g.
-    qr.pallas_coupled).  A regen-only loop is measured separately and
+    pallas_coupled).  A regen-only loop is measured separately and
     subtracted; the subtracted time must clear the noise band on its own.
     Returns (net seconds/iter, regen seconds/iter, extras) — extras carries
     the drift-guard fields (device_ms, wall_ms_below_floor) when

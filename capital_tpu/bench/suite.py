@@ -66,14 +66,18 @@ def run(base: argparse.Namespace, scale: int = 1) -> list[dict]:
     # 16.01G of 15.75G").  Non-eligible configs (xla/explicit mode, scaled
     # n without the g=2 split, 1 < devices < 8) keep the per-device-scaled
     # m of rounds 1-2 rather than walking into the known OOM.
-    from capital_tpu.models import qr as _qr
-    from capital_tpu.parallel.topology import Grid as _Grid
+    from capital_tpu.bench import harness
+    from capital_tpu.parallel import summa
+    from capital_tpu.parallel.topology import Grid
 
     n8 = max(128, 1024 // scale)
     if d8 == 1:
-        g1 = _Grid.square(c=1, devices=jax.devices()[:1])
-        mode8 = drivers._resolve_mode(base.mode, g1)
-        full_ok = _qr.pallas_coupled(g1, n8, mode8)
+        g1 = Grid.square(c=1, devices=jax.devices()[:1])
+        mode8 = summa.resolve_mode(base.mode, g1)
+        full_ok = harness.pallas_coupled(
+            g1, max(2048, 2**21 // scale), n8, mode8,
+            jax.numpy.dtype(base.dtype),
+        )
     else:
         full_ok = d8 >= 8  # 8 devices shard the carry; odd counts scale
     m8 = max(2048, (2**21 if full_ok else 2**21 * d8 // 8) // scale)

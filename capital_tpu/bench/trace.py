@@ -38,7 +38,8 @@ import time
 import jax
 import jax.numpy as jnp
 
-from capital_tpu.utils import tracing
+from capital_tpu.parallel import summa
+from capital_tpu.utils import residual, tracing
 
 
 def _phase_tags() -> tuple[str, ...]:
@@ -442,77 +443,33 @@ def _aot_run(jitted, *args):
     return run
 
 
-def _cholinv_run(n: int, dtype, bc: int, iters: int, oneshot: bool, prec=None,
+def _cholinv_run(n: int, dtype, bc: int, iters: int, prec=None,
                  mode: str = "pallas"):
-    """The flagship loop (bench.py's shape: fori_loop + element coupling),
-    compiled once and traced for `iters` iterations."""
+    """The carry loop (fori_loop + element coupling), compiled once and
+    traced for `iters` iterations."""
     from capital_tpu.models import cholesky
     from capital_tpu.parallel.topology import Grid
 
     grid = Grid.square(c=1, devices=[jax.devices()[0]])
-    cfg = cholesky.CholinvConfig(
-        base_case_dim=bc, mode=mode,
-        precision=prec,
-        schur_in_place=oneshot,
-    )
+    cfg = cholesky.CholinvConfig(base_case_dim=bc, mode=mode, precision=prec)
+    A = residual.spd_operand(n, dtype)
     eps = jnp.asarray(0.0, jnp.float32)
 
-    if oneshot:
-        import importlib.util
-        import pathlib
+    @jax.jit
+    def loop(a, eps, k):
+        def body(_, carry):
+            R, Rinv = cholesky.factor(grid, carry, cfg)
+            d = R[0, 0] + Rinv[0, 0]
+            return carry.at[0, 0].add(eps.astype(carry.dtype) * d)
 
-        bench_path = pathlib.Path(__file__).resolve().parents[2] / "bench.py"
-        spec = importlib.util.spec_from_file_location("flagship_bench", bench_path)
-        bench = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bench)
-        if cholesky.padded_dim(n, bc) != n:
-            # same guard as bench.py: cropped outputs cannot serve as the
-            # next iteration's p x p carries
-            raise SystemExit(
-                f"--oneshot needs n = bc * 2^k (n={n}, bc={bc} pads to "
-                f"{cholesky.padded_dim(n, bc)})"
-            )
+        return jnp.sum(jax.lax.fori_loop(0, k, body, a), dtype=jnp.float32)
 
-        @jax.jit
-        def loop(eps, k):
-            def body(i, carry):
-                acc, Rp, RIp = carry
-                a = jax.lax.optimization_barrier(bench.spd_hash(n, dtype, i))
-                R, Rinv = cholesky.factor(grid, a, cfg, out_buffers=(Rp, RIp))
-                return (
-                    acc + eps * (R[0, 0] + Rinv[0, 0]).astype(jnp.float32),
-                    R, Rinv,
-                )
-
-            Rp0, RIp0 = cholesky.factor_buffers(grid, n, dtype, cfg)
-            out, _, _ = jax.lax.fori_loop(
-                0, k, body, (jnp.float32(0.0), Rp0, RIp0)
-            )
-            return out
-
-        run = _aot_run(loop, eps, jnp.int32(iters))
-    else:
-        from capital_tpu.bench.drivers import _spd
-
-        A = _spd(n, dtype)
-
-        @jax.jit
-        def loop(a, eps, k):
-            def body(_, carry):
-                R, Rinv = cholesky.factor(grid, carry, cfg)
-                d = R[0, 0] + Rinv[0, 0]
-                return carry.at[0, 0].add(eps.astype(carry.dtype) * d)
-
-            return jnp.sum(jax.lax.fori_loop(0, k, body, a), dtype=jnp.float32)
-
-        run = _aot_run(loop, A, eps, jnp.int32(iters))
-
+    run = _aot_run(loop, A, eps, jnp.int32(iters))
     run()  # warm (already AOT-compiled)
     return run
 
 
 def _rectri_run(n: int, dtype, bc: int, iters: int, prec=None):
-    from capital_tpu.bench.drivers import _tri_operand
     from capital_tpu.models import inverse
     from capital_tpu.parallel.topology import Grid
 
@@ -521,7 +478,7 @@ def _rectri_run(n: int, dtype, bc: int, iters: int, prec=None):
         base_case_dim=bc, mode="pallas",
         precision=prec,
     )
-    T = _tri_operand(n, dtype)
+    T = residual.tri_operand(n, dtype)
     eps = jnp.asarray(0.0, jnp.float32)
 
     @jax.jit
@@ -569,7 +526,6 @@ def _cacqr_run(m: int, n: int, dtype, bc: int, iters: int, prec=None):
 
 
 def _trsm_run(n: int, nrhs: int, dtype, bc: int, iters: int, prec=None):
-    from capital_tpu.bench.drivers import _tri_operand
     from capital_tpu.models import trsm as trsm_mod
     from capital_tpu.parallel.topology import Grid
 
@@ -578,7 +534,7 @@ def _trsm_run(n: int, nrhs: int, dtype, bc: int, iters: int, prec=None):
         base_case_dim=bc, mode="xla",
         precision=prec,
     )
-    L = _tri_operand(n, dtype)
+    L = residual.tri_operand(n, dtype)
     B = jax.block_until_ready(
         jax.random.normal(jax.random.key(1), (n, nrhs), dtype=dtype)
     )
@@ -607,9 +563,6 @@ def main(argv=None) -> None:
     p.add_argument("--bc", type=int, default=512)
     p.add_argument("--dtype", default="bfloat16")
     p.add_argument("--iters", type=int, default=3)
-    p.add_argument("--oneshot", action="store_true",
-                   help="cholinv: trace the one-shot regen loop (the large-n "
-                        "flagship protocol) instead of the carry loop")
     p.add_argument("--trace-dir", default=None,
                    help="keep the raw trace here instead of a temp dir")
     p.add_argument("--max-copy-frac", type=float, default=None,
@@ -641,18 +594,16 @@ def main(argv=None) -> None:
     if args.platform:
         jax.config.update("jax_platforms", args.platform)
     dtype = jnp.dtype(args.dtype)
-    # ONE precision rule shared with the drivers CLI (drivers._precision):
-    # 'default' -> None (TPU default), unset -> the dtype rule
-    from capital_tpu.bench.drivers import _precision
-
-    prec = _precision(args, dtype)
+    # 'default' -> None (the context default), unset -> the dtype rule
+    if args.precision:
+        prec = None if args.precision == "default" else args.precision
+    else:
+        prec = summa.default_precision(dtype)
     ptag = f" prec={args.precision}" if args.precision else ""
 
     if args.algo == "cholinv":
-        run = _cholinv_run(args.n, dtype, args.bc, args.iters, args.oneshot, prec)
-        label = f"cholinv n={args.n} bc={args.bc} {dtype}" + (
-            " oneshot" if args.oneshot else ""
-        ) + ptag
+        run = _cholinv_run(args.n, dtype, args.bc, args.iters, prec)
+        label = f"cholinv n={args.n} bc={args.bc} {dtype}" + ptag
     elif args.algo == "rectri":
         run = _rectri_run(args.n, dtype, args.bc, args.iters, prec)
         label = f"rectri n={args.n} bc={args.bc} {dtype}" + ptag
@@ -706,7 +657,7 @@ def main(argv=None) -> None:
                     dtype=dtype,
                     config={
                         "algo": args.algo, "n": args.n, "bc": args.bc,
-                        "iters": args.iters, "oneshot": bool(args.oneshot),
+                        "iters": args.iters,
                     },
                 ),
                 measured=meas,

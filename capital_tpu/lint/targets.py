@@ -1,8 +1,8 @@
 """Flagship program targets for the lint gate (`make lint`).
 
 The sanitizer is only as good as the programs it runs over; these builders
-construct the repo's flagship entry points the same way the bench drivers
-and the serve engine do — cholinv, cacqr, and one serve bucket ladder per
+construct the repo's flagship entry points the same way the drivers and
+the serve engine do — cholinv, cacqr, and one serve bucket ladder per
 op — sized for a compile-only CPU CI pass (the invariants are properties of
 the *program*, not of the wall clock; `make audit` already owns the big-N
 drift runs).
@@ -33,12 +33,12 @@ def _grid():
 
 
 def cholinv_target(n: int = 512, dtype=jnp.float32) -> ProgramTarget:
-    from capital_tpu.bench import drivers
     from capital_tpu.models import cholesky
+    from capital_tpu.utils import residual
 
     grid = _grid()
-    cfg = cholesky.CholinvConfig(base_case_dim=drivers.pick_bc(n, 0))
-    A = drivers._spd(n, dtype)
+    cfg = cholesky.CholinvConfig(base_case_dim=cholesky.pick_base_case(n))
+    A = residual.spd_operand(n, dtype)
 
     def step(a):
         R, Rinv = cholesky.factor(grid, a, cfg)
@@ -49,11 +49,10 @@ def cholinv_target(n: int = 512, dtype=jnp.float32) -> ProgramTarget:
 
 def cacqr_target(m: int = 4096, n: int = 256,
                  dtype=jnp.float32) -> ProgramTarget:
-    from capital_tpu.bench import drivers
     from capital_tpu.models import cholesky, qr
 
     grid = _grid()
-    bc = drivers.pick_bc(n, 0)
+    bc = cholesky.pick_base_case(n)
     cfg = qr.CacqrConfig(
         cholinv=cholesky.CholinvConfig(base_case_dim=bc),
     )
@@ -359,14 +358,14 @@ def cholinv_fused_target(n: int = 512, dtype=jnp.float32) -> ProgramTarget:
     False`` because the fused factor+solve sweeps execute inside the
     interpreted ``pallas_call`` on the CPU lint rig, invisible to XLA
     ``cost_analysis`` (same reasoning as batched_small_targets)."""
-    from capital_tpu.bench import drivers
     from capital_tpu.models import cholesky
+    from capital_tpu.utils import residual
 
     grid = _grid()
     cfg = cholesky.CholinvConfig(
         base_case_dim=128, mode="pallas", tail_fuse_depth=2,
     )
-    A = drivers._spd(n, dtype)
+    A = residual.spd_operand(n, dtype)
 
     def step(a):
         R, Rinv = cholesky.factor(grid, a, cfg)

@@ -135,12 +135,6 @@ class CholinvConfig:
     # more recursion level.  Applies in every mode including the d=1
     # explicit path; ignored on multi-device grids and under the
     # persistent tile-cyclic layout.
-    base_prefetch: int = 2  # base-case write-back streams in flight: 2
-    # routes the leaf's R / R⁻¹ transposes through ONE pallas_call with
-    # both output streams live per tile step (pallas_tpu.transpose_pair —
-    # the second stream's block loads overlap the first's compute/store,
-    # and one kernel launch replaces two); 1 keeps the sequential
-    # two-kernel spelling.  Single-device only; bitwise-identical results.
     robust: Optional[RobustConfig] = None  # breakdown DETECTION: factor()
     # returns (R, Rinv, info) with a LAPACK-style int32 status of R
     # (robust/detect.factor_info) instead of NaN-filling silently on a
@@ -173,6 +167,32 @@ def padded_dim(n: int, base_case_dim: int) -> int:
     while p < n:
         p *= 2
     return p
+
+
+def pick_base_case(n: int, override: int = 0, cholinv_family: bool = True) -> int:
+    """Padding-aware base-case pick for an n x n factor (`override` wins
+    when nonzero).  The cholinv family's leaf potrf chain is the latency
+    floor at small n, so finer leaves win below the measured crossovers
+    (docs/PERF.md "Small-N — round 5": at n=4096, 128/256/512 measure
+    25.3/24.7/23.5 TF/s; at n=8192, 57.5/60.3/59.1; 512 holds from 16384
+    up within drift).  Candidates that tile n exactly are preferred, so
+    n=49152 takes 384; when none does, the same preference order breaks
+    ties among the least-padding candidates.  Other algorithms
+    (cholinv_family=False) keep 512."""
+    if override:
+        return override
+    if not cholinv_family:
+        return 512
+    if n <= 4096:
+        order = (128, 256, 512, 384)
+    elif n <= 8192:
+        order = (256, 512, 384, 128)
+    else:
+        order = (512, 384, 256)
+    for cand in order:
+        if padded_dim(n, cand) == n:
+            return cand
+    return min(order, key=lambda c: (padded_dim(n, c), order.index(c)))
 
 
 def pad_embed_identity(X: jnp.ndarray, n: int, p: int) -> jnp.ndarray:
@@ -328,16 +348,9 @@ def _base_case_into(
             Linv = lax.linalg.triangular_solve(
                 L, jnp.eye(n, dtype=bc_dtype), left_side=True, lower=True
             )
-            if cfg.base_prefetch >= 2:
-                # double-buffered write-back: both transposes in one
-                # launch, two aliased output streams in flight per tile
-                # step (bitwise-identical math — see transpose_pair)
-                return pallas_tpu.transpose_pair(L, Linv, Rp, RIp, dest=dest)
-            Rp = pallas_tpu.transpose(L, out_uplo="U", out=Rp, out_off=(dest, dest))
-            RIp = pallas_tpu.transpose(
-                Linv, out_uplo="U", out=RIp, out_off=(dest, dest)
-            )
-            return Rp, RIp
+            # double-buffered write-back: both transposes in one launch,
+            # two aliased output streams in flight per tile step
+            return pallas_tpu.transpose_pair(L, Linv, Rp, RIp, dest=dest)
         if ptile:
             wperm, winv = summa.tile_cyclic_perm(n, grid.dx, ptile)
             window = summa.cyclic_window(

@@ -604,38 +604,6 @@ def solve_blocked(
 # --------------------------------------------------------------------------
 
 
-def pallas_coupled(
-    grid: Grid, n: int, mode: str, m: int | None = None, dtype=None
-) -> bool:
-    """True when a 1d factor's outputs ride ops XLA cannot slice into (Q
-    through pallas custom calls — the blocked/fused kernels engaged — and R
-    through a whole-input potrf chain), making a one-element benchmark
-    carry measurement-safe (harness.timed_loop coupling='elem').  It asks
-    factor()'s own routing (_route) and, on the sweeps, mirrors _sweep_1d's
-    tri_kernel gate — a stale copy in a driver would let the simplifier
-    silently narrow the measured work.
-
-    The fused routes ride Mosaic custom calls (coupled); 'panels' is pure
-    XLA (one-element consumption would let the simplifier drop every other
-    panel — NOT coupled).  Deciding the route needs the full (m, dtype)
-    question; callers that cannot supply them get the sweeps' answer on
-    one device and the conservative False on a mesh (full-consumption
-    coupling is always measurement-safe, just slower)."""
-    route = "sweeps_1d"
-    if m is not None and dtype is not None:
-        route, _ = _route(grid, m, n, dtype, CacqrConfig(mode=mode), "1d")
-    if route != "sweeps_1d":
-        return route != "panels"
-    # the unfused sweeps (or an m/dtype-less caller, which never benches
-    # the wide shapes): the nb cap mirrors _sweep_1d's tri_kernel envelope
-    return (
-        grid.num_devices == 1
-        and mode == "pallas"
-        and _col_blocks(n) > 1
-        and n // _col_blocks(n) <= 2048
-    )
-
-
 def _pick_regime(grid: Grid, n: int, cfg: CacqrConfig) -> str:
     # validate up front: an unknown string used to fall through to the dist
     # path silently, turning a typo ('1D', 'fused', ...) into a whole
@@ -651,7 +619,7 @@ def _pick_regime(grid: Grid, n: int, cfg: CacqrConfig) -> str:
     return "1d" if n <= cfg.dist_threshold else "dist"
 
 
-def _route(
+def route(
     grid: Grid, m: int, n: int, dtype, cfg: CacqrConfig, regime: str
 ) -> tuple[str, dict]:
     """Which pipeline a build of factor() traces, and what it traces it
@@ -727,10 +695,10 @@ def _factor_core(
     Each build counts its route in `spans.ROUTES` and traces it under a
     ``qr.route`` program span carrying the same tags."""
     m, n = A.shape
-    route, tags = _route(grid, m, n, A.dtype, cfg, regime)
-    spans.ROUTES.take(route, **tags)
-    with spans.span("qr.route", route=route, **tags):
-        kind, _, plan = route.partition("/")
+    name, tags = route(grid, m, n, A.dtype, cfg, regime)
+    spans.ROUTES.take(name, **tags)
+    with spans.span("qr.route", route=name, **tags):
+        kind, _, plan = name.partition("/")
         if kind == "fused_sharded":
             return _cqr2_fused_sharded(grid, A, cfg, tags["g"], plan)
         if kind == "fused":

@@ -56,20 +56,23 @@ from capital_tpu.utils.config import PLATFORM_HELP
 
 def _build(algo: str, args, grid):
     """(step, operand, cfg, dtype) for one driver config — the same
-    construction the bench drivers use, minus the measurement loop."""
+    construction the drivers CLI uses, minus the measurement loop."""
     import jax.numpy as jnp
 
-    from capital_tpu.bench import drivers
     from capital_tpu.models import cholesky, inverse, qr, trsm as trsm_mod
     from capital_tpu.parallel import summa
+    from capital_tpu.utils import residual
 
     dtype = jnp.dtype(args.dtype)
-    mode = drivers._resolve_mode(args.mode, grid)
-    prec = drivers._precision(args, dtype)
+    mode = summa.resolve_mode(args.mode, grid)
+    if args.precision:
+        prec = None if args.precision == "default" else args.precision
+    else:
+        prec = summa.default_precision(dtype)
     if algo in ("cholinv", "spd_inverse"):
-        bc = drivers.pick_bc(args.n, args.bc)
+        bc = cholesky.pick_base_case(args.n, args.bc)
         cfg = cholesky.CholinvConfig(base_case_dim=bc, mode=mode, precision=prec)
-        A = drivers._spd(args.n, dtype)
+        A = residual.spd_operand(args.n, dtype)
         if algo == "cholinv":
             def step(a):
                 R, Rinv = cholesky.factor(grid, a, cfg)
@@ -79,7 +82,7 @@ def _build(algo: str, args, grid):
                 return cholesky.spd_inverse(grid, a, cfg)
         return step, A, cfg, dtype
     if algo == "cacqr":
-        bc = drivers.pick_bc(args.n, args.bc)
+        bc = cholesky.pick_base_case(args.n, args.bc)
         cfg = qr.CacqrConfig(
             num_iter=args.variant, regime=args.regime, mode=mode,
             cholinv=cholesky.CholinvConfig(
@@ -97,18 +100,18 @@ def _build(algo: str, args, grid):
 
         return step, A, cfg, dtype
     if algo == "rectri":
-        bc = drivers.pick_bc(args.n, args.bc, cholinv_family=False)
+        bc = cholesky.pick_base_case(args.n, args.bc, cholinv_family=False)
         cfg = inverse.RectriConfig(base_case_dim=bc, mode=mode, precision=prec)
-        L = drivers._tri_operand(args.n, dtype)
+        L = residual.tri_operand(args.n, dtype)
 
         def step(a):
             return inverse.rectri(grid, a, "L", cfg)
 
         return step, L, cfg, dtype
     if algo == "trsm":
-        bc = drivers.pick_bc(args.n, args.bc, cholinv_family=False)
+        bc = cholesky.pick_base_case(args.n, args.bc, cholinv_family=False)
         cfg = trsm_mod.TrsmConfig(base_case_dim=bc, mode=mode, precision=prec)
-        L = drivers._tri_operand(args.n, dtype)
+        L = residual.tri_operand(args.n, dtype)
         nrhs = min(args.m, args.n)
         B = jax.block_until_ready(
             jax.random.normal(jax.random.key(1), (args.n, nrhs), dtype=dtype)
@@ -132,10 +135,14 @@ def _build(algo: str, args, grid):
 def _audit(args) -> int:
     import jax.numpy as jnp  # noqa: F401  (dtype resolution inside _build)
 
-    from capital_tpu.bench import drivers
     from capital_tpu.obs import ledger, xla_audit
+    from capital_tpu.parallel import summa
+    from capital_tpu.parallel.topology import Grid
 
-    grid = drivers._grid(args)
+    dev = jax.devices()[: args.devices or None]
+    grid = Grid.largest_square(
+        dev, c=args.c, layout=args.layout, num_chunks=args.chunks
+    )
     step, operand, cfg, dtype = _build(args.algo, args, grid)
     op_args = operand if isinstance(operand, tuple) else (operand,)
     rec = xla_audit.trace_model(step, *op_args)
@@ -150,7 +157,7 @@ def _audit(args) -> int:
         f"audit:{args.algo}",
         ledger.manifest(
             grid=grid, dtype=dtype, config=cfg,
-            n=args.n, m=args.m, mode=drivers._resolve_mode(args.mode, grid),
+            n=args.n, m=args.m, mode=summa.resolve_mode(args.mode, grid),
         ),
         model=ledger.model_costs(rec, dtype=dtype),
         audit=audit.asdict(),

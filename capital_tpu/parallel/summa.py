@@ -92,6 +92,26 @@ class SyrkArgs:
     precision: str | None = None
 
 
+def resolve_mode(mode: str, grid: Grid) -> str:
+    """'auto' picks the SUMMA mode for the topology: the dead-block-skipping
+    pallas kernels on a single TPU (mode='xla' leaves ~40% of cholinv
+    throughput on the table there), GSPMD planning on a mesh (pallas is
+    single-device-only and would fall back anyway).  Off-TPU, pallas means
+    the interpreter — orders of magnitude slower than xla — so CPU runs stay
+    on xla.  Any other mode passes through."""
+    if mode != "auto":
+        return mode
+    one_tpu = grid.num_devices == 1 and grid.platform == "tpu"
+    return "pallas" if one_tpu else "xla"
+
+
+def default_precision(dtype) -> str | None:
+    """The matmul precision for operands of `dtype`: 'highest' keeps f32 and
+    wider at full accuracy on the MXU; narrower operands take the context
+    default (the kernels drop 'highest' for them anyway)."""
+    return None if jnp.dtype(dtype).itemsize < 4 else "highest"
+
+
 # --------------------------------------------------------------------------
 # explicit shard_map schedule
 # --------------------------------------------------------------------------

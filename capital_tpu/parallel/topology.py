@@ -190,6 +190,34 @@ class Grid:
         )
 
     @staticmethod
+    def largest_square(
+        devices: Sequence[jax.Device],
+        c: int = 1,
+        layout: int = 0,
+        num_chunks: int = 0,
+    ) -> "Grid":
+        """The largest d x d x c grid the given devices support, preferring
+        replication depth ``c`` (reference rep_div knob,
+        bench/cholesky/cholinv.cpp:16) and trying 1, 2, 4, 8 after it;
+        devices past d*d*c stay unused."""
+        devices = list(devices)
+        n = len(devices)
+        if n == 1:
+            return Grid.square(c=1, devices=devices, num_chunks=num_chunks)
+        best = (1, 1)  # (d, c)
+        for cc in (c, 1, 2, 4, 8):
+            d = 1
+            while (d + 1) * (d + 1) * cc <= n:
+                d += 1
+            if d * d * cc <= n and d * d * cc > best[0] ** 2 * best[1]:
+                best = (d, cc)
+        d, c = best
+        return Grid.square(
+            c=c, devices=devices[: d * d * c], layout=layout,
+            num_chunks=num_chunks,
+        )
+
+    @staticmethod
     def rect(
         dx: int,
         dy: int,

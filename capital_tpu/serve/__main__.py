@@ -8,7 +8,7 @@ acceptance properties of docs/SERVING.md:
   buckets, every request-driven executable lookup must hit
   (cache misses == 0, hit_rate == 1.0);
 * **numerics**: the max per-request residual stays under the pinned
-  dtype gate (bench/drivers._tolerance; the lstsq normal-equation
+  dtype gate (utils/residual.tolerance; the lstsq normal-equation
   residual gets the same 10x allowance the qr drivers use — the gram
   squares the conditioning).
 
@@ -148,7 +148,7 @@ def _residual(op: str, A, B, x) -> float:
 def _smoke(args) -> int:
     import jax.numpy as jnp
 
-    from capital_tpu.bench.drivers import _tolerance
+    from capital_tpu.utils.residual import tolerance
     from capital_tpu.serve import ServeConfig, SolveEngine
 
     dtype = jnp.dtype(args.dtype)
@@ -194,7 +194,7 @@ def _smoke(args) -> int:
     eng.drain()
 
     failures = []
-    tol = _tolerance(dtype)
+    tol = tolerance(dtype)
     worst: dict[str, float] = {}
     buckets_seen = set()
     for (op, A, B), t in zip(work, tickets):
@@ -308,7 +308,7 @@ def _replicas(args) -> int:
     it at 0 — replicas and the mid-stream replacement all deserialize)."""
     import numpy as np
 
-    from capital_tpu.bench.drivers import _tolerance
+    from capital_tpu.utils.residual import tolerance
     from capital_tpu.serve import loadgen
     from capital_tpu.serve.engine import ServeConfig
     from capital_tpu.serve.router import Router, RouterConfig
@@ -373,7 +373,7 @@ def _replicas(args) -> int:
             print(f"# serve-replicas: drained {drained_id} under load "
                   f"(outstanding now {per['outstanding']})")
 
-    tol = _tolerance(np.dtype(args.dtype))
+    tol = tolerance(np.dtype(args.dtype))
     worst: dict[str, float] = {}
     landed = 0
     for op, A, B, t in tickets:

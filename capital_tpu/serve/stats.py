@@ -8,23 +8,48 @@ summarizes, and `ledger.diff` exempts from the metric-regression check the
 same way event/robust records are exempt (a served mix's latency profile is
 workload, not a kernel regression).
 
-Latency percentiles come from bench/harness.percentiles — the same
-nearest-rank p50/p95/p99 the bench report lines carry, so a request_stats
+Latency percentiles come from `percentiles` below — the one nearest-rank
+p50/p95/p99 rule, shared with the rolling windows (serve/telemetry.py), the
+bench report lines and the autotune latency sweeps, so a request_stats
 record and a bench row read on one scale.
 """
 
 from __future__ import annotations
 
+import math
 import random
 from collections import Counter
-
-from capital_tpu.bench.harness import percentiles
 
 #: Default bound on each raw-sample population a Collector retains.  A
 #: long-running replica records forever; without a cap its four latency
 #: lists grow without limit.  High enough that every tier-1 smoke and
 #: loadgen run stays exact (capped == False).
 DEFAULT_SAMPLE_CAP = 8192
+
+
+def percentiles(
+    samples, points: tuple[float, ...] = (50.0, 95.0, 99.0)
+) -> dict[str, float]:
+    """Nearest-rank percentiles of raw samples: {'p50': ..., 'p95': ...,
+    'p99': ...}.  The ONE quantile implementation of the repo — duplicated
+    quantile code is how two dashboards end up disagreeing about the same
+    run.
+
+    Nearest-rank (ceil) deliberately: every reported value is a sample that
+    actually occurred, so a p99 can be shown next to the raw max without
+    interpolation artifacts.  Dependency-free (no numpy) so stats paths add
+    zero imports."""
+    s = sorted(samples)
+    if not s:
+        raise ValueError("percentiles() needs at least one sample")
+    out = {}
+    for p in points:
+        if not 0.0 < p <= 100.0:
+            raise ValueError(f"percentile point {p} outside (0, 100]")
+        rank = max(1, math.ceil(p / 100.0 * len(s)))
+        label = f"p{int(p)}" if float(p).is_integer() else f"p{p}"
+        out[label] = s[rank - 1]
+    return out
 
 
 class Reservoir:

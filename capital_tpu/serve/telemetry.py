@@ -33,8 +33,7 @@ import time
 from collections import Counter
 from typing import Optional
 
-from capital_tpu.bench.harness import percentiles
-from capital_tpu.serve.stats import Reservoir
+from capital_tpu.serve.stats import Reservoir, percentiles
 
 #: Fixed log-spaced histogram bin edges (milliseconds).  Counts live in
 #: len(edges) + 1 bins: (-inf, e0], (e0, e1], ..., (e_last, +inf) — fixed

@@ -1,6 +1,6 @@
 """Where JAX's persistent compilation cache lives — placed from outside.
 
-The entry points that run on the chip (bench.py, chip_smoke.py and the
+The entry points that run on the chip (chip_smoke.py and the
 ``python -m capital_tpu.{bench,serve,autotune}`` CLIs) call `enable()`
 once, before their first compile:
 

@@ -9,7 +9,9 @@ computation is a global jnp reduction — XLA inserts the cross-device psum
 automatically from the operands' shardings, so one implementation serves both
 the single-chip and the multi-chip mesh cases.
 
-All functions accept (possibly sharded) jax Arrays and return scalars.
+The norms accept (possibly sharded) jax Arrays and return scalars; the
+gate (`tolerance`) and the test operands they are checked on
+(`spd_operand`, `tri_operand`) live here with them.
 """
 
 from __future__ import annotations
@@ -23,6 +25,48 @@ import jax.numpy as jnp
 # correct n=1024 f32 factor 'failing' at 4.6e-4 purely from the gate's own
 # product).  Gates are not on the timed path; full precision is free here.
 _PREC = "highest"
+
+
+def tolerance(dtype) -> float:
+    """Residual gate by dtype: the reference's f64/MPI runs sit at ~1e-14
+    (SURVEY §4); scaled to the working precision here."""
+    return {2: 5e-2, 4: 5e-5, 8: 1e-13}[jnp.dtype(dtype).itemsize]
+
+
+def spd_operand(n: int, dtype, seed: int = 0) -> jnp.ndarray:
+    """Well-conditioned SPD test matrix, built on device (Wigner + dominant
+    diagonal — same spectrum family as the reference's distribute_symmetric
+    diagonal dominance, structure.hpp:87-89)."""
+    @jax.jit
+    def make(key):
+        M = jax.random.normal(key, (n, n), dtype=jnp.float32)
+        A = (M + M.T) / jnp.sqrt(2.0 * n)
+        # 3I, not 2I: the Wigner semicircle edge sits at exactly 2, so a
+        # 2I shift leaves lambda_min grazing zero and f32 cholesky can NaN
+        # depending on the RNG stream
+        return (A + 3.0 * jnp.eye(n, dtype=M.dtype)).astype(dtype)
+
+    return jax.block_until_ready(make(jax.random.key(seed)))
+
+
+def tri_operand(n: int, dtype, seed: int = 0) -> jnp.ndarray:
+    """Well-conditioned lower-triangular test matrix, built DIRECTLY at
+    dtype (no chol-of-SPD setup — its two extra f32 n² staging buffers
+    OOM'd n=32768 on one v5e).  Off-diagonal scale 1/sqrt(n): kappa ~ 2 at
+    every n (measured 1.9-2.0 at 512-8192 in f64) while the off-diagonal
+    part carries ~23% of the matrix norm, so a residual gate still SEES
+    off-diagonal bugs — a 1/n scale would shrink them ~sqrt(n)x below the
+    bf16 tolerance."""
+
+    @jax.jit
+    def make(key):
+        G = jax.random.normal(key, (n, n), dtype=jnp.float32)
+        L = jnp.tril(G, -1) / jnp.sqrt(
+            jnp.asarray(n, jnp.float32)
+        ) + 3.0 * jnp.eye(n, dtype=jnp.float32)
+        return L.astype(dtype)
+
+    return jax.block_until_ready(make(jax.random.key(seed)))
 
 
 def rel_fro(err: jnp.ndarray, ref: jnp.ndarray) -> jnp.ndarray:

@@ -4,12 +4,12 @@
     python3 chip_smoke.py --chips 4    # four chips: mesh cholinv, sharded
                                        # CQR2, a 4-replica Router
 
-Sizes are BASELINE.md's: cholinv N=16384 bf16 (bc from drivers.pick_bc),
-single-rank CholeskyQR2 65536x512 f32, and a SolveEngine answering f32
-posv/lstsq over three small-N buckets (n <= 128, the Pallas batched-grid
-route) and one mid-n bucket.  Operands are made on the device from
+Sizes are BASELINE.md's: cholinv N=16384 bf16 (bc from
+cholesky.pick_base_case), single-rank CholeskyQR2 65536x512 f32, and a
+SolveEngine answering f32 posv/lstsq over three small-N buckets (n <= 128,
+the Pallas batched-grid route) and one mid-n bucket.  Operands are made on the device from
 ``--seed``.  Every result is checked: the factorizations by the repo's
-residual gates (utils/residual, drivers._tolerance per dtype), served
+residual gates (utils/residual, residual.tolerance per dtype), served
 answers against a NumPy f64 solve, and the cholinv/serve programs must
 contain ``tpu_custom_call`` (Mosaic ran, not the interpreter).
 
@@ -104,18 +104,19 @@ def _qr_gates(A, Q, R) -> tuple[float, float]:
 def phase_cholinv(dev, seed: int, n: int = CHOLINV_N) -> dict:
     import jax.numpy as jnp
 
-    from capital_tpu.bench.drivers import _spd, _tolerance, pick_bc
     from capital_tpu.models import cholesky
     from capital_tpu.parallel.topology import Grid
+    from capital_tpu.utils.residual import spd_operand, tolerance
 
     dtype = jnp.bfloat16
     grid = Grid.square(c=1, devices=[dev])
-    cfg = cholesky.CholinvConfig(base_case_dim=pick_bc(n), mode="pallas")
-    A = _spd(n, dtype, seed)
+    cfg = cholesky.CholinvConfig(
+        base_case_dim=cholesky.pick_base_case(n), mode="pallas")
+    A = spd_operand(n, dtype, seed)
     exe, compile_s = _timed_compile(lambda a: cholesky.factor(grid, a, cfg), A)
     (R, Rinv), run_s = _timed_run(exe, A)
     fr, ir = _chol_gates(A, R, Rinv)
-    tol = _tolerance(dtype)
+    tol = tolerance(dtype)
     line = {"phase": "cholinv", "n": n, "bc": cfg.base_case_dim,
             "dtype": "bfloat16", "mode": "pallas",
             "compile_s": compile_s, "run_s": run_s,
@@ -130,9 +131,9 @@ def phase_cacqr(dev, seed: int, m: int = CQR_M, n: int = CQR_N) -> dict:
     import jax
     import jax.numpy as jnp
 
-    from capital_tpu.bench.drivers import _tolerance
     from capital_tpu.models import qr
     from capital_tpu.parallel.topology import Grid
+    from capital_tpu.utils.residual import tolerance
 
     dtype = jnp.float32
     grid = Grid.square(c=1, devices=[dev])
@@ -142,7 +143,7 @@ def phase_cacqr(dev, seed: int, m: int = CQR_M, n: int = CQR_N) -> dict:
     exe, compile_s = _timed_compile(lambda a: qr.factor(grid, a, cfg), A)
     (Q, R), run_s = _timed_run(exe, A)
     orth, res = _qr_gates(A, Q, R)
-    tol = _tolerance(dtype)
+    tol = tolerance(dtype)
     line = {"phase": "cacqr", "m": m, "n": n, "dtype": "float32",
             "mode": "pallas", "compile_s": compile_s, "run_s": run_s,
             "orthogonality": orth, "residual": res, "tol": tol,
@@ -194,11 +195,11 @@ def _reference_error(op: str, A, B, x) -> float:
 def _answer_tol(op: str) -> float:
     import jax.numpy as jnp
 
-    from capital_tpu.bench.drivers import _tolerance
+    from capital_tpu.utils.residual import tolerance
 
     # the normal-equations route squares the conditioning: 10x, as the
     # serve smoke gates lstsq
-    return _tolerance(jnp.float32) * (10 if op == "lstsq" else 1)
+    return tolerance(jnp.float32) * (10 if op == "lstsq" else 1)
 
 
 def phase_serve(dev, seed: int, ns=SMALL_NS + (MID_N,)) -> dict:
@@ -258,15 +259,15 @@ def phase_cholinv_mesh(devs, seed: int, n: int = CHOLINV_N) -> dict:
     import jax
     import jax.numpy as jnp
 
-    from capital_tpu.bench.drivers import _spd, _tolerance, pick_bc
     from capital_tpu.models import cholesky
     from capital_tpu.parallel.topology import Grid
+    from capital_tpu.utils.residual import spd_operand, tolerance
 
     dtype = jnp.bfloat16
-    bc = pick_bc(n)
+    bc = cholesky.pick_base_case(n)
     mesh = Grid.square(c=1, devices=devs[:4])
     one = Grid.square(c=1, devices=devs[:1])
-    A = _spd(n, dtype, seed)
+    A = spd_operand(n, dtype, seed)
     Am = jax.device_put(A, mesh.face_sharding())
     cfg_m = cholesky.CholinvConfig(base_case_dim=bc, mode="explicit")
     cfg_1 = cholesky.CholinvConfig(base_case_dim=bc, mode="pallas")
@@ -280,7 +281,7 @@ def phase_cholinv_mesh(devs, seed: int, n: int = CHOLINV_N) -> dict:
     diff = float(jax.jit(
         lambda r, r1: jnp.linalg.norm(r.astype(f32) - r1.astype(f32))
         / jnp.linalg.norm(r1.astype(f32)))(jax.device_put(R, devs[0]), R1))
-    tol = _tolerance(dtype)
+    tol = tolerance(dtype)
     line = {"phase": "cholinv_mesh", "grid": "2x2x1", "n": n, "bc": bc,
             "dtype": "bfloat16", "mode": "explicit",
             "compile_s": compile_s, "run_s": run_s,
@@ -300,9 +301,9 @@ def phase_cacqr_sharded(devs, seed: int, m: int = CQR_M,
     import jax
     import jax.numpy as jnp
 
-    from capital_tpu.bench.drivers import _tolerance
     from capital_tpu.models import qr
     from capital_tpu.parallel.topology import Grid
+    from capital_tpu.utils.residual import tolerance
 
     dtype = jnp.float32
     flat = Grid.flat(devs[:4])
@@ -318,7 +319,7 @@ def phase_cacqr_sharded(devs, seed: int, m: int = CQR_M,
     _, R1 = exe1(A)
     diff = float(jnp.linalg.norm(jax.device_put(R, devs[0]) - R1)
                  / jnp.linalg.norm(R1))
-    tol = _tolerance(dtype)
+    tol = tolerance(dtype)
     line = {"phase": "cacqr_sharded", "m": m, "n": n, "chips": 4,
             "dtype": "float32", "compile_s": compile_s, "run_s": run_s,
             "orthogonality": orth, "residual": res,

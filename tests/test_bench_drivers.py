@@ -36,58 +36,22 @@ def test_suite_scaled():
     _run(["suite", "--dtype", "float32", "--iters", "1", "--scale", "64", "--validate"])
 
 
-def test_flagship_auto_base_case(capsys):
-    # bench.py's base-case pick must keep the flagship n tiled exactly —
-    # a wrong pick silently pads (up to 2.4x flops) or misaligns every
-    # pallas view window
-    import importlib.util
-    import pathlib
+def test_flagship_auto_base_case():
+    # the base-case pick must keep the flagship n tiled exactly — a wrong
+    # pick silently pads (up to 2.4x flops) or misaligns every pallas
+    # view window
+    from capital_tpu.models.cholesky import padded_dim, pick_base_case
 
-    path = pathlib.Path(__file__).resolve().parent.parent / "bench.py"
-    spec = importlib.util.spec_from_file_location("flagship_bench", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    from capital_tpu.models.cholesky import padded_dim
-
-    assert mod.auto_base_case(32768) == 512
-    assert mod.auto_base_case(49152) == 384
-    assert mod.auto_base_case(16384) == 512
-    assert mod.auto_base_case(24576) == 384
+    assert pick_base_case(32768) == 512
+    assert pick_base_case(49152) == 384
+    assert pick_base_case(16384) == 512
+    assert pick_base_case(24576) == 384
     for n in (32768, 49152, 24576):
-        bc = mod.auto_base_case(n)
+        bc = pick_base_case(n)
         assert padded_dim(n, bc) == n and bc % 128 == 0
-    # untileable n: falls back to the least-padding candidate and says so
+    # untileable n: falls back to the least-padding candidate
     # (40000 pads to 49152 under bc=384 vs 65536 under 512/256)
-    assert mod.auto_base_case(40000) == 384
-    assert "padding to" in capsys.readouterr().err
-
-
-def test_flagship_spd_hash_contract():
-    """The one-shot loop's fused operand generator: exactly symmetric (hash
-    of (min, max) index pair), well-SPD (3I shift vs ~1.16 spectral norm of
-    the random part), and salt-dependent (so XLA cannot hoist it out of the
-    timed loop)."""
-    import importlib.util
-    import pathlib
-
-    import jax.numpy as jnp
-    import numpy as np
-
-    path = pathlib.Path(__file__).resolve().parent.parent / "bench.py"
-    spec = importlib.util.spec_from_file_location("flagship_bench2", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-
-    A = np.asarray(mod.spd_hash(256, jnp.float32, 3))
-    np.testing.assert_array_equal(A, A.T)
-    w = np.linalg.eigvalsh(A.astype(np.float64))
-    assert w.min() > 1.0 and w.max() < 5.0  # 3 ± ~1.16 spectral band
-    B = np.asarray(mod.spd_hash(256, jnp.float32, 4))
-    assert np.abs(A - B).max() > 0.01  # salt actually changes the operand
-    # deterministic: same salt, same matrix
-    np.testing.assert_array_equal(
-        A, np.asarray(mod.spd_hash(256, jnp.float32, 3))
-    )
+    assert pick_base_case(40000) == 384
 
 
 def test_newton_reports_executed_iters():

@@ -145,5 +145,5 @@ def test_route_table(case, route, tags, monkeypatch):
     if case == "flat4_robust":
         monkeypatch.setattr(qr, "_ROBUST", [object()])
     n = 4096 if case == "one_wide" else N
-    assert qr._route(grid, M, n, jnp.bfloat16, cfg, cfg.regime) == (route,
-                                                                       tags)
+    assert qr.route(grid, M, n, jnp.bfloat16, cfg, cfg.regime) == (route,
+                                                                      tags)

@@ -211,7 +211,7 @@ class TestLedgerValidation:
 class TestEndToEndAttribution:
     def test_cholinv_loop_attributes_to_registered_phases(self):
         run = trace._cholinv_run(
-            256, jnp.float32, 128, 1, False, "highest", mode="xla"
+            256, jnp.float32, 128, 1, "highest", mode="xla"
         )
         phase_s, bubble, wall = trace.phase_attribution(run, 1)
         assert phase_s, "nothing attributed on the CPU rig"

@@ -237,9 +237,9 @@ class TestRobustCholesky:
         assert int(info) != 0
 
     def test_spd_info_zero_and_values_unchanged(self, grid2x2x1):
-        from capital_tpu.bench.drivers import _spd
+        from capital_tpu.utils.residual import spd_operand
 
-        A = _spd(64, jnp.float64)
+        A = spd_operand(64, jnp.float64)
         cfg = CholinvConfig(robust=RobustConfig())
         R, Rinv, info = cholesky.factor(grid2x2x1, A, cfg)
         assert int(info) == 0

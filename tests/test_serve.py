@@ -22,7 +22,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from capital_tpu.bench import harness
 from capital_tpu.models import cholesky
 from capital_tpu.obs import __main__ as obs_main
 from capital_tpu.obs import ledger, spans
@@ -30,6 +29,7 @@ from capital_tpu.ops import lapack, masking
 from capital_tpu.robust import faultinject
 from capital_tpu.robust.config import RobustConfig, RobustInfo
 from capital_tpu.serve import ServeConfig, SolveEngine, batching, stats
+from capital_tpu.serve.stats import percentiles
 
 # Small ladders so every executable compiles in well under a second; the
 # huge max_delay_s means the deadline path only fires when a test passes an
@@ -465,26 +465,25 @@ class TestEngineAcceptance:
 
 class TestPercentiles:
     def test_nearest_rank(self):
-        out = harness.percentiles(range(1, 101))
+        out = percentiles(range(1, 101))
         assert out == {"p50": 50, "p95": 95, "p99": 99}
         # every reported value is a sample that actually occurred
-        assert harness.percentiles([40.0, 10.0, 30.0, 20.0]) == {
+        assert percentiles([40.0, 10.0, 30.0, 20.0]) == {
             "p50": 20.0, "p95": 40.0, "p99": 40.0,
         }
 
     def test_single_sample(self):
-        assert harness.percentiles([7.0]) == {"p50": 7.0, "p95": 7.0,
-                                              "p99": 7.0}
+        assert percentiles([7.0]) == {"p50": 7.0, "p95": 7.0, "p99": 7.0}
 
     def test_custom_points(self):
-        out = harness.percentiles(range(1, 11), points=(10.0, 100.0))
+        out = percentiles(range(1, 11), points=(10.0, 100.0))
         assert out == {"p10": 1, "p100": 10}
 
     def test_errors(self):
         with pytest.raises(ValueError, match="at least one"):
-            harness.percentiles([])
+            percentiles([])
         with pytest.raises(ValueError, match="outside"):
-            harness.percentiles([1.0], points=(0.0,))
+            percentiles([1.0], points=(0.0,))
 
 
 class TestStatsCollector:

@@ -433,7 +433,7 @@ class TestMergeSnapshots:
         assert m["requests"] == 6 and m["replicas"] == 2
         assert m["replica_ids"] == ["r0", "r1"]
         # exact pooled p50 of the union, NOT a mean of the two p50s
-        from capital_tpu.bench.harness import percentiles
+        from capital_tpu.serve.stats import percentiles
 
         pool = [0.001, 0.002, 0.003, 0.1, 0.2, 0.3]
         want = round(percentiles(pool)["p50"] * 1e3, 4)

@@ -30,7 +30,7 @@ import time
 import numpy as np
 import pytest
 
-from capital_tpu.bench.harness import percentiles
+from capital_tpu.serve.stats import percentiles
 from capital_tpu.obs import __main__ as obs_main
 from capital_tpu.obs import ledger, spans
 from capital_tpu.serve import ServeConfig, SolveEngine, telemetry
@@ -721,7 +721,7 @@ class TestReservoirAndMerge:
 
 
 # ---------------------------------------------------------------------------
-# bench/harness.percentiles: nearest-rank on tiny samples
+# serve/stats.percentiles: nearest-rank on tiny samples
 # ---------------------------------------------------------------------------
 
 

@@ -312,12 +312,16 @@ def _base_case_into(
     z=0 layer + depth broadcast (REPLICATE_COMP), or the root device + mesh
     broadcast (NO_REPLICATION[_OVERLAP]).
 
-    Single-device path: the window read, the symmetric-panel rebuild, and
-    both output writes run through the layout-opaque Pallas transpose kernel
-    with views/in-place aliasing (no slice or scatter materialization, and
-    no XLA-visible `.T` — see ops/lapack.py:potrf_trtri_upper for why that
-    matters).  Multi-device grids materialize the window (the panel is being
-    replicated across the mesh anyway).
+    Single-device path: where `lapack.pallas_chol_fits`, one Pallas kernel
+    reads the window, factors and inverts it, and writes both results in
+    place (pallas_tpu.potrf_trtri_upper).  Otherwise the window read, the
+    symmetric-panel rebuild, and both output writes run through the
+    layout-opaque Pallas transpose kernel with views/in-place aliasing (no
+    slice or scatter materialization, and no XLA-visible `.T` — see
+    ops/lapack.py:potrf_trtri_upper for why that matters) around XLA's
+    Cholesky and triangular solve.  Either way the leaf counts its path
+    (`lapack.count_chol_route`).  Multi-device grids materialize the window
+    (the panel is being replicated across the mesh anyway).
     """
     bc_dtype = cfg.base_case_dtype
     if bc_dtype is None:
@@ -339,6 +343,14 @@ def _base_case_into(
             flops=tracing.potrf_trtri_flops(n), comm_bytes=comm, collectives=ncoll
         )
         if grid.num_devices == 1:
+            pallas = jnp.dtype(bc_dtype) == jnp.float32 and (
+                lapack.pallas_chol_fits(n, bc_dtype, off, dest, *buf.shape,
+                                        *Rp.shape))
+            lapack.count_chol_route(pallas, n)
+            if pallas:
+                # one kernel reads the window and writes both results in place
+                return pallas_tpu.potrf_trtri_upper(
+                    buf, off=off, n=n, Rp=Rp, RIp=RIp, dest=dest)
             # cholesky reads only the lower triangle (symmetrize_input=False)
             # = the transpose of the window's valid upper half
             P_low = pallas_tpu.transpose(

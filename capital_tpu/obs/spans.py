@@ -12,8 +12,9 @@ Two span kinds share this module and its clock:
   ``build.compile`` — and counts each backend build as a persistent-cache
   load or a compile (`BUILDS`); `KERNELS` counts the Pallas call sites
   and the distinct kernels built for them; `ROUTES` counts the routes the
-  builds of `qr.factor` took.  The span vocabulary is in
-  docs/OBSERVABILITY.md "Program spans".
+  builds of `qr.factor` took, `CHOL_ROUTES` the path each factor-and-invert
+  site took (one Pallas kernel or XLA's Cholesky and triangular solve).
+  The span vocabulary is in docs/OBSERVABILITY.md "Program spans".
 * **Request chains** (`RequestTrace`): every request the SolveEngine
   admits carries an ordered chain of spans covering its whole life —
 
@@ -348,6 +349,10 @@ class RouteCounter:
 
 
 ROUTES = RouteCounter()
+#: The factor-and-invert sites' paths, counted as each is traced:
+#: ``potrf_trtri/pallas`` or ``potrf_trtri/xla``, tagged with the panel's
+#: ``n``.  Apart from `ROUTES`, whose every route is one of `qr.factor`.
+CHOL_ROUTES = RouteCounter()
 _WATCH_LOCK = threading.Lock()
 _watching = False
 

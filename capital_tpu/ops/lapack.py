@@ -26,6 +26,7 @@ from jax import lax
 # robust.{detect,faultinject} depend only on jax + tracing, so this import
 # cannot cycle back here.  The taps are identity when no fault plan is
 # active; with_info=False keeps every wrapper's signature unchanged.
+from capital_tpu.obs import spans
 from capital_tpu.robust import detect, faultinject
 
 
@@ -253,12 +254,46 @@ def potrf_trtri(A: jnp.ndarray, uplo: str = "U", with_info: bool = False):
     return (T, Tinv, detect.factor_info(T)) if with_info else (T, Tinv)
 
 
+#: The panel sizes `potrf_trtri_upper` gives the one-kernel factor and
+#: inverse (pallas_tpu.potrf_trtri_upper) on a TPU.  Above the top, five
+#: n×n f32 arrays no longer sit well inside the kernel's VMEM budget (and
+#: one-device grams of 2048 and more go to cholinv, qr._gram_chol).
+PALLAS_CHOL_MIN, PALLAS_CHOL_MAX = 128, 1024
+
+
+def pallas_chol_fits(n: int, dtype, *aligned: int) -> bool:
+    """Whether an n×n upper-valid panel of `dtype` is factored and inverted
+    by the Pallas kernel: a TPU platform (the scoped one,
+    pallas_tpu.device_scope), an f32 compute dtype, n a multiple of the
+    kernel's panel within [PALLAS_CHOL_MIN, PALLAS_CHOL_MAX], and every
+    window offset or buffer dim in `aligned` a multiple of n.  Anything else
+    (the CPU rig, f64, odd n) takes XLA's Cholesky and triangular solve."""
+    from capital_tpu.ops import pallas_tpu
+
+    return (
+        pallas_tpu._platform() == "tpu"
+        and _compute_dtype(dtype) == jnp.float32
+        and n % pallas_tpu.CHOL_PANEL == 0
+        and PALLAS_CHOL_MIN <= n <= PALLAS_CHOL_MAX
+        and all(a % n == 0 for a in aligned)
+    )
+
+
+def count_chol_route(pallas: bool, n: int) -> None:
+    """Count a factor-and-invert site's path, at trace time
+    (`spans.CHOL_ROUTES`)."""
+    spans.CHOL_ROUTES.take(
+        "potrf_trtri/pallas" if pallas else "potrf_trtri/xla", n=n)
+
+
 def potrf_trtri_upper(P: jnp.ndarray, with_info: bool = False):
     """(R, R⁻¹) upper-triangular from a symmetric panel whose **upper**
     triangle holds the valid content (the lower half may be garbage — e.g. a
     Schur window produced by an uplo='U' syrk).
 
-    Functionally potrf_trtri(symmetrize_from(P, 'U')), but with every
+    Where `pallas_chol_fits`, one Pallas kernel factors and inverts the
+    panel in VMEM (pallas_tpu.potrf_trtri_upper).  Elsewhere it is
+    functionally potrf_trtri(symmetrize_from(P, 'U')), but with every
     transpose routed through the layout-opaque Pallas kernel
     (ops/pallas_tpu.transpose): the naive spelling plants `.T` ops at every
     recursion leaf, and XLA layout assignment answers leaf-sized transposes
@@ -266,19 +301,26 @@ def potrf_trtri_upper(P: jnp.ndarray, with_info: bool = False):
     (~4.7ms/iter at n=16k on v5e).  Here cholesky/triangular_solve run in
     their native lower form (no symmetrize pass: cholesky with
     symmetrize_input=False reads only the lower triangle) and the three
-    transposes stay panel-sized.
+    transposes stay panel-sized.  Each call counts its path
+    (`count_chol_route`).
 
     with_info=True appends the int32 breakdown status of R."""
     from capital_tpu.ops import pallas_tpu
 
     P = faultinject.tap(P)
-    ct = _compute_dtype(P.dtype)
-    P_low = pallas_tpu.transpose(P, out_uplo="L", out_dtype=ct)
-    L = lax.linalg.cholesky(P_low, symmetrize_input=False)
-    eye = jnp.eye(P.shape[-1], dtype=ct)
-    Linv = lax.linalg.triangular_solve(L, eye, left_side=True, lower=True)
-    R = pallas_tpu.transpose(L, out_uplo="U", out_dtype=P.dtype)
-    Rinv = pallas_tpu.transpose(Linv, out_uplo="U", out_dtype=P.dtype)
+    n = P.shape[-1]
+    pallas = pallas_chol_fits(n, P.dtype)
+    count_chol_route(pallas, n)
+    if pallas:
+        R, Rinv = pallas_tpu.potrf_trtri_upper(P)
+    else:
+        ct = _compute_dtype(P.dtype)
+        P_low = pallas_tpu.transpose(P, out_uplo="L", out_dtype=ct)
+        L = lax.linalg.cholesky(P_low, symmetrize_input=False)
+        eye = jnp.eye(n, dtype=ct)
+        Linv = lax.linalg.triangular_solve(L, eye, left_side=True, lower=True)
+        R = pallas_tpu.transpose(L, out_uplo="U", out_dtype=P.dtype)
+        Rinv = pallas_tpu.transpose(Linv, out_uplo="U", out_dtype=P.dtype)
     return (R, Rinv, detect.factor_info(R)) if with_info else (R, Rinv)
 
 

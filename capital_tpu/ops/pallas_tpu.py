@@ -879,6 +879,209 @@ def transpose_pair(
         _offsets(dest // bn, dest // bm), L, Linv, Rp, RIp, spec=spec)
 
 
+#: Panel width of `potrf_trtri_upper`'s blocked sweep: one MXU tile, and
+#: one vreg of lanes, so every column step of a panel indexes statically.
+CHOL_PANEL = 128
+
+
+def _chol_panel(x_ref):
+    """Right-looking column sweep, on the VPU, of the symmetric block held
+    in the left CHOL_PANEL lanes of `x_ref` beside I in its right ones:
+    the block becomes its upper factor R (block = RᵀR) and I becomes R⁻ᵀ —
+    the sweep's row operations applied to the identity, as Gauss-Jordan
+    does, one row operation on both halves at once.  Column j divides row
+    j by its pivot and subtracts its multiple from the rows below it; the
+    rows at and above j keep their values (a select in j's own 8-row
+    group), so a breakdown at pivot j leaves the rows before it as they
+    were and `detect.factor_info` names j + 1.
+
+    Written in `lax` primitives, one row operation for both halves: the
+    128 columns unroll, and every op traced here is paid again by each
+    process's build of the kernel."""
+    b, w = CHOL_PANEL, 2 * CHOL_PANEL
+    # lanes >= j keep row j: R's upper part and all of R⁻ᵀ's row (lower)
+    lane = lax.broadcasted_iota(jnp.int32, (1, w), 1)
+    group = lax.broadcasted_iota(jnp.int32, (8, 1), 0)
+    zero = lax.full((1, w), 0.0, jnp.float32)
+
+    def eliminate(x, j, v):  # x - x[:, j]·v
+        m = x.shape[0]
+        c = lax.broadcast_in_dim(lax.slice(x, (0, j), (m, j + 1)), (m, w),
+                                 (0, 1))
+        return lax.sub(x, lax.mul(c, lax.broadcast_in_dim(v, (m, w), (0, 1))))
+
+    for j in range(b):
+        row = x_ref[j:j + 1, :]
+        piv = lax.broadcast_in_dim(lax.slice(row, (0, j), (1, j + 1)), (1, w),
+                                   (0, 1))
+        v = lax.div(row, piv)
+        lo = (j + 1) // 8 * 8
+        if lo <= j:  # row j's own 8-row group
+            g = x_ref[lo:lo + 8, :]
+            below = lax.broadcast_in_dim(group > j - lo, (8, w), (0, 1))
+            x_ref[lo:lo + 8, :] = lax.select(below, eliminate(g, j, v), g)
+            lo += 8
+        if lo < b:
+            x_ref[lo:, :] = eliminate(x_ref[lo:, :], j, v)
+        x_ref[j:j + 1, :] = lax.select(lane >= j, lax.mul(row, lax.rsqrt(piv)),
+                                       zero)
+
+
+@dataclasses.dataclass(frozen=True)
+class _CholInvSpec:
+    n: int
+    alias: bool  # the results land in windows of Rp, RIp (operands)
+    interpret: bool
+    phase: str
+
+
+@_kernel_cache
+def _potrf_trtri_kernel(offs, P, *rest, spec: _CholInvSpec):
+    n, b = spec.n, CHOL_PANEL
+    nb = n // b
+    f32 = jnp.float32
+
+    def dot(x, y, cx=1):
+        # cx=0 contracts x's rows: xᵀ·y without forming xᵀ
+        return precision_dot(x, y, (((cx,), (0,)), ((), ())), f32, "highest")
+
+    def kernel(o_ref, p_ref, *refs):
+        del o_ref  # read by the index maps
+        r_out, ri_out, s_ref, x_ref, acc_ref, de_ref = refs[-6:]
+        r = lax.broadcasted_iota(jnp.int32, (b, b), 0)
+        c = lax.broadcasted_iota(jnp.int32, (b, b), 1)
+        upper, eye = r <= c, (r == c).astype(f32)
+        # s_ref: the trailing matrix, each row panel replaced by its rows of
+        # R once factored; x_ref: R⁻¹, built one block column at a time
+        s_ref[...] = p_ref[...].astype(f32)
+        x_ref[...] = jnp.zeros((n, n), f32)
+
+        def panel(k, carry):
+            k0 = pl.multiple_of(k * b, b)
+            kb = pl.ds(k0, b)
+            w = s_ref[kb, kb]
+            # never reads the lower half
+            de_ref[:, :b] = jnp.where(upper, w, w.T)
+            de_ref[:, b:] = eye
+            _chol_panel(de_ref)
+            rkk, ekk = de_ref[:, :b], de_ref[:, b:]
+            xkk = ekk.T
+            # the panel's rows of R: zero left of the diagonal block, R_kk,
+            # then R_kk⁻ᵀ times the panel's rows of the trailing matrix
+            col = lax.broadcasted_iota(jnp.int32, (b, n), 1)
+            rk = jnp.where(col >= k0 + b, dot(ekk, s_ref[kb, :]), 0.0)
+            s_ref[kb, :] = rk
+            s_ref[kb, kb] = rkk
+            # trailing update, one block row at a time (its upper part)
+            for i in range(1, nb):
+                i0 = i * b
+
+                @pl.when(i > k)
+                def _():
+                    s_ref[i0:i0 + b, i0:] = s_ref[i0:i0 + b, i0:] - dot(
+                        rk[:, i0:i0 + b], rk[:, i0:], cx=0)
+
+            # R⁻¹'s block column k: -(R⁻¹[:k, :k]·R[:k, k])·R_kk⁻¹, summed
+            # over the block rows p < k of R
+            acc_ref[...] = jnp.zeros((n, b), f32)
+            for p in range(nb - 1):
+                p0, p1 = p * b, (p + 1) * b
+
+                @pl.when(p < k)
+                def _():
+                    acc_ref[:p1, :] = acc_ref[:p1, :] + dot(
+                        x_ref[:p1, p0:p1], s_ref[p0:p1, kb])
+
+            rows = lax.broadcasted_iota(jnp.int32, (n, b), 0)
+            x_ref[:, kb] = jnp.where(rows < k0, -dot(acc_ref[...], xkk), 0.0)
+            x_ref[kb, kb] = xkk
+            return carry
+
+        lax.fori_loop(jnp.int32(0), jnp.int32(nb), panel, 0)
+        r_out[...] = s_ref[...].astype(r_out.dtype)
+        ri_out[...] = x_ref[...].astype(ri_out.dtype)
+
+    # offs: the window's block index in P, then the results' in Rp and RIp
+    in_specs = [pl.BlockSpec((n, n), lambda q, o: (o[0], o[0]),
+                             memory_space=pltpu.VMEM)]
+    if spec.alias:
+        in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
+        out_shape = [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in rest]
+    else:
+        out_shape = [jax.ShapeDtypeStruct((n, n), P.dtype)] * 2
+    out_block = pl.BlockSpec((n, n), lambda q, o: (o[1], o[1]),
+                             memory_space=pltpu.VMEM)
+    return pl.pallas_call(
+        kernel,
+        name=tracing.kernel_name("potrf_trtri", spec.phase),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(1,),
+            in_specs=in_specs,
+            out_specs=[out_block, out_block],
+            scratch_shapes=[
+                pltpu.VMEM((n, n), f32), pltpu.VMEM((n, n), f32),
+                pltpu.VMEM((n, b), f32), pltpu.VMEM((b, 2 * b), f32),
+            ],
+        ),
+        out_shape=out_shape,
+        input_output_aliases={2: 0, 3: 1} if spec.alias else {},
+        cost_estimate=pl.CostEstimate(
+            flops=int(tracing.potrf_trtri_flops(n)),
+            bytes_accessed=3 * n * n * jnp.dtype(P.dtype).itemsize,
+            transcendentals=2 * n,
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_device_budget()[1]),
+        interpret=spec.interpret,
+    )(offs, P, *rest)
+
+
+def potrf_trtri_upper(
+    P: jnp.ndarray,
+    *,
+    off: int = 0,
+    n: int | None = None,
+    Rp: jnp.ndarray | None = None,
+    RIp: jnp.ndarray | None = None,
+    dest: int = 0,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """(triu(R), triu(R⁻¹)) with RᵀR = the (off, off, n, n) window of P,
+    in ONE kernel that keeps the panel in VMEM: the window's upper triangle
+    holds the valid content and its lower half is never read (it may hold
+    garbage, NaN included).  All arithmetic is f32; the products run at f32
+    'highest'.  The results come back as a fresh (n, n) pair in P's dtype,
+    or, given `Rp` and `RIp`, land in their (dest, dest, n, n) windows in
+    place (aliased: the caller must treat the passed-in buffers as
+    consumed).  `off` and `dest` reach the kernel as a runtime operand, so
+    every window of one size shares one kernel.
+
+    A blocked right-looking sweep in CHOL_PANEL-wide panels: each diagonal
+    block is factored, and inverted, by VPU column steps (`_chol_panel`);
+    the MXU then forms the panel's rows of R, the trailing update (block
+    row by block row) and R⁻¹'s block column from R's rows above it.  So
+    the sequential chain is one VPU step per column, not the column sweep
+    of XLA's Cholesky and triangular-solve custom calls.  n must be a
+    multiple of CHOL_PANEL, and `off`, `dest` and the buffers' dims
+    multiples of n; `lapack.potrf_trtri_upper` decides when this kernel
+    runs (`lapack.pallas_chol_fits`)."""
+    n = P.shape[0] if n is None else n
+    alias = Rp is not None
+    dims = (off, dest, *P.shape, *(Rp.shape if alias else ()))
+    if (n % CHOL_PANEL or any(d % n for d in dims) or (RIp is None) == alias
+            or (alias and Rp.shape != RIp.shape)):
+        raise ValueError(
+            f"potrf_trtri_upper: a window of n={n} (a multiple of "
+            f"{CHOL_PANEL}) at off={off} of P{P.shape}, dest={dest}, Rp and "
+            f"RIp both or neither, alike: offsets and dims multiples of n")
+    spec = _CholInvSpec(n=n, alias=alias, interpret=_interpret_default(),
+                        phase=tracing.active_phase("CI::factor_diag"))
+    return tuple(_potrf_trtri_kernel(
+        _offsets(off // n, dest // n), P, *((Rp, RIp) if alias else ()),
+        spec=spec))
+
+
 def fused_tail(
     buf: jnp.ndarray,
     Rp: jnp.ndarray,

@@ -107,7 +107,8 @@ def test_cqr2_rows_over_four_chips(topo, monkeypatch):
     assert routes.snapshot() == {
         "fused_sharded/full": {"builds": 1, "rows": 8192, "g": 8, "bm": 4096,
                                "bm_scale_gram": 1024}}
-    for kernel in ("CQR.gram.gram", "CQR.fused.scale_gram", "CQR.formR.scale"):
+    for kernel in ("CQR.gram.gram", "CQR.fused.scale_gram", "CQR.formR.scale",
+                   "CQR.chol.potrf_trtri"):
         assert kernel in txt, kernel
     assert len(re.findall(r"all-reduce(?:-start)?\(", txt)) == 2
 
@@ -129,6 +130,21 @@ def test_tall_pass_kernel_at_its_row_block(chip, kernel, n, dtype, bm):
     }[kernel]
     A, R = _sds(chip, (65536, n), dtype), _sds(chip, (n, n), dtype)
     assert _mosaic_calls(fn, A, R, scope=chip) == 1
+
+
+@pytest.mark.parametrize("n,N", [(1024, None), (384, 3072)])
+def test_factor_and_invert_kernel(chip, n, N):
+    """The one-kernel factor and inverse at the Gram's 1024 and, in place
+    in windows of larger bf16 buffers, at cholinv's 384 leaf."""
+    if N is None:
+        fn = pallas_tpu.potrf_trtri_upper
+        args = (_sds(chip, (n, n), jnp.bfloat16),)
+    else:
+        def fn(buf, rp, rip):
+            return pallas_tpu.potrf_trtri_upper(buf, off=n, n=n, Rp=rp,
+                                                RIp=rip, dest=2 * n)
+        args = tuple(_sds(chip, (N, N), jnp.bfloat16) for _ in range(3))
+    assert _mosaic_calls(fn, *args, scope=chip) == 1
 
 
 @pytest.mark.parametrize("op,m", [("posv", 64), ("lstsq", 128)])

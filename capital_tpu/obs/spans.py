@@ -13,7 +13,8 @@ Two span kinds share this module and its clock:
   load or a compile (`BUILDS`); `KERNELS` counts the Pallas call sites
   and the distinct kernels built for them; `ROUTES` counts the routes the
   builds of `qr.factor` took, `CHOL_ROUTES` the path each factor-and-invert
-  site took (one Pallas kernel or XLA's Cholesky and triangular solve).
+  site took (one Pallas kernel or XLA's Cholesky and triangular solve),
+  `REFINE_ROUTES` the route of the dense refined solve's FP64 residual.
   The span vocabulary is in docs/OBSERVABILITY.md "Program spans".
 * **Request chains** (`RequestTrace`): every request the SolveEngine
   admits carries an ordered chain of spans covering its whole life —
@@ -353,6 +354,9 @@ ROUTES = RouteCounter()
 #: ``potrf_trtri/pallas`` or ``potrf_trtri/xla``, tagged with the panel's
 #: ``n``.  Apart from `ROUTES`, whose every route is one of `qr.factor`.
 CHOL_ROUTES = RouteCounter()
+#: The FP64-grade residual's route in `refine.posv_dense`, counted as each
+#: build traces it: ``refine/xla_f64``, tagged with the system's ``n``.
+REFINE_ROUTES = RouteCounter()
 _WATCH_LOCK = threading.Lock()
 _watching = False
 

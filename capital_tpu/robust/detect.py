@@ -34,19 +34,25 @@ def factor_info(R) -> jnp.ndarray:
     Works on either triangle convention (only the diagonal sign matters)
     and is jit/vmap-safe: a pure O(n^2) reduction, no host callback.
     """
-    d = jnp.diagonal(R)
-    bad_diag = ~(jnp.isfinite(d) & (d > 0))
-    # argmax on bool gives the first True; guard with any() so an all-good
-    # diagonal maps to 0 rather than index-0's "1".
-    first_bad = jnp.where(
-        jnp.any(bad_diag), jnp.argmax(bad_diag).astype(jnp.int32) + 1, 0
-    )
+    first_bad = diag_info(jnp.diagonal(R))
     off_bad = ~jnp.all(jnp.isfinite(R))
     n = R.shape[-1]
     return jnp.where(
         first_bad > 0,
         first_bad,
         jnp.where(off_bad, jnp.int32(n + 1), jnp.int32(0)),
+    ).astype(jnp.int32)
+
+
+def diag_info(d) -> jnp.ndarray:
+    """The diagonal half of `factor_info`, from the diagonal d alone (an
+    O(n) check): 0 when every entry is finite and positive, else the
+    1-based index of the first that is not."""
+    bad = ~(jnp.isfinite(d) & (d > 0))
+    # argmax on bool gives the first True; guard with any() so an all-good
+    # diagonal maps to 0 rather than index-0's "1".
+    return jnp.where(
+        jnp.any(bad), jnp.argmax(bad).astype(jnp.int32) + 1, 0
     ).astype(jnp.int32)
 
 

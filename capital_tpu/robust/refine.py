@@ -151,16 +151,25 @@ def _pnorm(X):
 
 
 def _refine_loop(X0, resid_fn, err_fn, correct_fn, *, max_iters: int,
-                 tol: float):
+                 tol: float, update_fn=None):
     """The shared sweep loop.  resid_fn(X) -> r at the correction dtype;
     err_fn(X, r) -> per-problem (batch,) f32 backward error; correct_fn(r)
-    -> d.  Per-problem freezing: a problem stops the moment it converges,
-    stops improving (error not halved — divergence comes back loud, not
-    spun on), or hits the cap; the while_loop runs until every problem
-    froze.  Returns (X, RefineInfo)."""
-    batch = X0.shape[0]
+    -> d; update_fn(X, d, act) -> X with d added to the problems where the
+    (batch,) mask `act` is set (default: the masked X + d; X may be any
+    pytree the three functions agree on).  Per-problem freezing: a problem
+    stops the moment it converges, stops improving (error not halved —
+    divergence comes back loud, not spun on), or hits the cap; the
+    while_loop runs until every problem froze.  Returns (X, RefineInfo)."""
     r0 = resid_fn(X0)
     e0 = err_fn(X0, r0)
+    batch = e0.shape[0]
+
+    def _mask(act, x):
+        return act.reshape((batch,) + (1,) * (x.ndim - 1))
+
+    if update_fn is None:
+        def update_fn(X, d, act):
+            return X + jnp.where(_mask(act, X), d, jnp.zeros_like(d))
 
     def _active(e, prev, it):
         return (e > tol) & (e < 0.5 * prev) & (it < max_iters)
@@ -173,13 +182,12 @@ def _refine_loop(X0, resid_fn, err_fn, correct_fn, *, max_iters: int,
         X, r, e, prev, it = carry
         act = _active(e, prev, it)
         d = correct_fn(r)
-        mask = act.reshape((batch,) + (1,) * (X.ndim - 1))
-        Xn = X + jnp.where(mask, d, jnp.zeros_like(d))
+        Xn = update_fn(X, d, act)
         rn = resid_fn(Xn)
         en = err_fn(Xn, rn)
         return (
             Xn,
-            jnp.where(mask, rn, r),
+            jnp.where(_mask(act, r), rn, r),
             jnp.where(act, en, e),
             jnp.where(act, e, prev),
             it + act.astype(jnp.int32),
@@ -280,6 +288,164 @@ def posv(A, B, *, factor_dtype, correction_dtype,
     X, rinfo = _refine_loop(X0, resid, err, correct,
                             max_iters=max_iters, tol=tol)
     return X.astype(B.dtype), info, rinfo
+
+
+# --------------------------------------------------------------------------
+# one large dense SPD system, refined to HPL-MxP's FP64 residual check
+# --------------------------------------------------------------------------
+
+#: HPL-MxP's acceptance threshold on the scaled residual
+#: ‖b − Ax‖∞ / ((‖A‖∞‖x‖∞ + ‖b‖∞) · n · ε)
+HPL_THRESHOLD = 16.0
+#: the ε of that check: the unit roundoff of float64, 2⁻⁵³ (LAPACK
+#: dlamch('E'), as HPL computes it)
+HPL_EPS = 2.0**-53
+#: posv_dense's default stopping point, half the threshold.  The program's
+#: own scaled residual is FP64-grade like the check made elsewhere on the
+#: returned solution (they agree to about 1% on a v5e at n = 32768), so
+#: the factor of two covers their difference with room; a tighter stop
+#: buys nothing the check can see and costs a sweep (~100x a sweep there)
+DENSE_TOL = HPL_THRESHOLD / 2.0
+
+
+def _ff_add(hi, lo, d):
+    """(hi, lo) + d for float-float pairs of f32 (hi + lo to about 48
+    bits): Knuth's TwoSum of hi and d, the error folded into lo, then
+    renormalised so that |lo| stays under half an ulp of hi."""
+    s = hi + d
+    bb = s - hi
+    err = (hi - (s - bb)) + (d - bb)
+    lo = lo + err
+    hi = s + lo
+    return hi, lo - (hi - s)
+
+
+def _residual_f64(A, B, Xh, Xl):
+    """FP64-grade residual B − A·X of the float-float solution X = Xh + Xl,
+    rounded to f32 (its own f32 rounding is far below what a correction
+    or the ∞-norm of r needs).  XLA's float64 under a scoped
+    `jax.enable_x64`, written as a product and a row sum (no dot): on a
+    TPU, which has no f64 unit, XLA emulates both in pairs of f32 and
+    fuses them into one pass over A; its emulated f64 dot would instead
+    split A into many n² pieces.  Counted as route ``refine/xla_f64``."""
+    from capital_tpu.obs import spans
+
+    spans.REFINE_ROUTES.take("refine/xla_f64", n=int(A.shape[0]))
+    with tracing.scope("IR::residual"), jax.enable_x64(True):
+        f64 = jnp.float64
+        x = Xh.astype(f64) + Xl.astype(f64)
+        ax = jnp.sum(A.astype(f64)[:, :, None] * x[None, :, :], axis=1)
+        return (B.astype(f64) - ax).astype(jnp.float32)
+
+
+def _split_mv(M, v, *, trans: bool = False):
+    """M·v (or Mᵀ·v) in f32, one pass over M.  Against a bf16 M, v is split
+    into a bf16 head and tail so that the product keeps v's f32 accuracy
+    on an MXU that rounds its operands to bf16; a wider M runs at
+    'highest'."""
+    dims = (((0,), (0,)), ((), ())) if trans else (((1,), (0,)), ((), ()))
+    if M.dtype.itemsize >= 4:
+        return lax.dot_general(M, v.astype(M.dtype), dims,
+                               precision=lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+    head = v.astype(jnp.bfloat16)
+    tail = (v - head.astype(v.dtype)).astype(jnp.bfloat16)
+    V = jnp.concatenate([head, tail], axis=1).astype(M.dtype)
+    out = lax.dot_general(M, V, dims, preferred_element_type=jnp.float32)
+    k = v.shape[1]
+    return out[:, :k] + out[:, k:]
+
+
+def posv_dense(grid, A, B, *, max_iters: int = DEFAULT_MAX_ITERS):
+    """One dense SPD system A·X = B on one device, factored at low
+    precision and refined to an FP64-grade answer (HPL-MxP's protocol).
+
+    A (n, n) stays resident and unchanged; B is (n,) or (n, k).  The
+    factor is `cholesky.factor` at the `guaranteed` tier's factor dtype
+    (`plan`: A's own dtype, f32 for f64), `pick_base_case(n)`, the SUMMA
+    mode for the grid (`summa.resolve_mode`: the Pallas kernels on one
+    TPU), Schur complements in fresh trailing windows, so that A is only
+    read (in place they would need a working copy of A beside it: a v5e
+    compile at n = 32768 reserves 4.1 n² bf16 of temporaries that way
+    against 2.6 n² this way), and ``complete_inv=False``: the correction
+    d = R⁻¹R⁻ᵀr is a one-level block substitution, with R12 and the two
+    diagonal inverse blocks of R⁻¹ the factor leaves (`_split_mv`), under
+    ``IR::correct``.  The residual is FP64-grade (`_residual_f64`, under
+    ``IR::residual``) and X is a float-float pair of f32.  The loop
+    (`_refine_loop`: the in-program test, the progress guard, the sweep
+    cap `max_iters`; 0 returns the factor's own solve) stops when the
+    scaled residual ‖b − Ax‖∞ / ((‖A‖∞‖x‖∞ + ‖b‖∞) · n · `HPL_EPS`),
+    worst over the columns, is at most `DENSE_TOL`.
+
+    Returns ((X_hi, X_lo), info, RefineInfo): X_hi + X_lo shaped like B
+    (f32 each; their sum in f64 is the answer), info the int32 status of
+    the factor's diagonal (`detect.diag_info`), and RefineInfo of one
+    problem ((1,) arrays; `resid` the scaled residual).  A factor that
+    breaks or a refinement that stalls comes back with converged == 0."""
+    from capital_tpu.models import cholesky
+    from capital_tpu.parallel import summa
+    from capital_tpu.robust import detect
+
+    n = A.shape[0]
+    if A.shape != (n, n) or B.shape[0] != n or B.ndim not in (1, 2):
+        raise ValueError(f"posv_dense needs A (n, n) and B (n,) or (n, k), "
+                         f"got {A.shape}, {B.shape}")
+    fd = plan("guaranteed", A.dtype).factor_dtype
+    cfg = cholesky.CholinvConfig(
+        complete_inv=False, base_case_dim=cholesky.pick_base_case(n),
+        mode=summa.resolve_mode("auto", grid),
+        precision=summa.default_precision(fd))
+    R, Rinv = cholesky.factor(grid, A.astype(fd), cfg)
+    info = detect.diag_info(jnp.diagonal(R))
+    h = cholesky.top_split(n, cfg)
+
+    Bm = (B[:, None] if B.ndim == 1 else B).astype(jnp.float32)
+    k = Bm.shape[1]
+    f32 = jnp.float32
+    with tracing.scope("IR::residual"):
+        # the check's scale, one pass over A a solve; the model prices one
+        # residual (the sweeps are counted where they run, RefineInfo)
+        anorm = jnp.max(jnp.sum(jnp.abs(A.astype(f32)), axis=1))
+        tracing.emit(flops=2.0 * n * n * k)
+    bnorm = jnp.max(jnp.abs(Bm), axis=0)
+    scale = f32(n * HPL_EPS)
+    with tracing.scope("IR::correct"):
+        tracing.emit(flops=tracing.refine_sweep_flops(n, k) - 2.0 * n * n * k)
+
+    def correct(r):
+        r = r[0]
+        with tracing.scope("IR::correct"):
+            if h == n:  # one base-case window: R⁻¹ is whole
+                d = _split_mv(Rinv, _split_mv(Rinv, r, trans=True))
+                return d[None]
+            R11i, R22i, R12 = Rinv[:h, :h], Rinv[h:, h:], R[:h, h:]
+            z1 = _split_mv(R11i, r[:h], trans=True)
+            z2 = _split_mv(R22i, r[h:] - _split_mv(R12, z1, trans=True),
+                           trans=True)
+            d2 = _split_mv(R22i, z2)
+            d1 = _split_mv(R11i, z1 - _split_mv(R12, d2))
+            return jnp.concatenate([d1, d2])[None]
+
+    def resid(X):
+        return _residual_f64(A, Bm, X[0][0], X[1][0])[None]
+
+    def err(X, r):
+        rmax = jnp.max(jnp.abs(r[0]), axis=0)
+        xmax = jnp.max(jnp.abs(X[0][0]), axis=0)
+        # a NaN stays NaN: a broken factor never reads as converged
+        return jnp.max(rmax / ((anorm * xmax + bnorm) * scale))[None]
+
+    def update(X, d, act):
+        return _ff_add(X[0], X[1], jnp.where(act[0], d, 0.0))
+
+    x0 = correct(Bm[None])
+    (Xh, Xl), rinfo = _refine_loop(
+        (x0, jnp.zeros_like(x0)), resid, err, correct,
+        max_iters=max_iters, tol=DENSE_TOL, update_fn=update)
+    Xh, Xl = Xh[0], Xl[0]
+    if B.ndim == 1:
+        Xh, Xl = Xh[:, 0], Xl[:, 0]
+    return (Xh, Xl), info, rinfo
 
 
 def lstsq(A, B, *, factor_dtype, correction_dtype,

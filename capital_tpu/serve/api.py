@@ -561,8 +561,12 @@ def batched(op: str, precision: str | None = "highest",
     return auto
 
 
+#: the tiered requests the oversize route serves: (op, accuracy_tier)
+SINGLE_TIERS = (("posv", "guaranteed"),)
+
+
 def single(op: str, grid, precision: str | None = "highest", robust=None,
-           tail_fuse_depth: int = 0):
+           tail_fuse_depth: int = 0, tier: str = "balanced"):
     """The oversize route: one exact-shape problem through the models/
     schedules on the engine's grid.  Uniform return contract (X, info):
     info is a scalar int32 (posv/inv) or a RobustInfo pytree (lstsq under
@@ -570,7 +574,27 @@ def single(op: str, grid, precision: str | None = "highest", robust=None,
     `tail_fuse_depth` threads ServeConfig's fused-recursion-tail knob into
     every CholinvConfig built here — it changes the compiled program, so
     the engine keys it into the cache config-hash.
+
+    `tier` 'guaranteed' (posv only, `SINGLE_TIERS`) is the dense refined
+    solve, robust/refine.posv_dense: FIVE outputs (X, iters, converged,
+    resid, info) like the batched guaranteed programs, X the refined
+    float-float answer rounded to the request dtype, info the factor's
+    diagonal status.  posv_dense picks its own factor settings from the
+    grid and the operand, so `precision` and `tail_fuse_depth` do not
+    apply.
     """
+    if tier != "balanced":
+        if (op, tier) not in SINGLE_TIERS:
+            raise ValueError(f"no oversize route for {op} at accuracy_tier="
+                             f"{tier!r}")
+        from capital_tpu.robust import refine
+
+        def f(a, b):
+            (hi, lo), info, ri = refine.posv_dense(grid, a, b)
+            x = hi.astype(b.dtype) + lo.astype(b.dtype)
+            return x, ri.iters[0], ri.converged[0], ri.resid[0], info
+
+        return f
     if op == "posv":
         ccfg = cholesky.CholinvConfig(precision=precision, robust=robust,
                                       tail_fuse_depth=tail_fuse_depth)

@@ -373,15 +373,17 @@ class SolveEngine:
 
         return self.cache.get(key, build, warmup=warmup)
 
-    def _get_single(self, op: str, a_sds, b_sds, warmup: bool = False):
+    def _get_single(self, op: str, a_sds, b_sds, warmup: bool = False,
+                    tier: str = "balanced"):
         key = ("single", op, str(a_sds.dtype), a_sds.shape,
                b_sds.shape if b_sds is not None else None,
-               self._grid_key, self._cfg_hash)
+               self._grid_key, self._cfg_hash, tier)
 
         def build():
             fn = api.single(op, self.grid, self.cfg.precision,
                             self.cfg.robust,
-                            tail_fuse_depth=self.cfg.tail_fuse_depth)
+                            tail_fuse_depth=self.cfg.tail_fuse_depth,
+                            tier=tier)
             specs = (a_sds,) if b_sds is None else (a_sds, b_sds)
             return jax.jit(fn).lower(*specs).compile()
 
@@ -416,11 +418,12 @@ class SolveEngine:
             )
             if bucket is not None:
                 self._get_batched(bucket, warmup=True)
-            elif self.cfg.oversize == "models":
+            elif self.cfg.oversize == "models" and (
+                    tier == "balanced" or (op, tier) in api.SINGLE_TIERS):
                 a_sds = jax.ShapeDtypeStruct(tuple(a_shape), dt)
                 b_sds = (jax.ShapeDtypeStruct(tuple(b_shape), dt)
                          if b_shape else None)
-                self._get_single(op, a_sds, b_sds, warmup=True)
+                self._get_single(op, a_sds, b_sds, warmup=True, tier=tier)
         return self.cache.warmup_compiles - before
 
     # ---- request path ------------------------------------------------------
@@ -562,9 +565,11 @@ class SolveEngine:
             op, A.shape, B.shape if B is not None else None,
             str(A.dtype), self.cfg, tier=accuracy_tier,
         )
-        if bucket is None and accuracy_tier != "balanced":
-            # the oversize models/ route has no tiered program — silently
-            # serving a 'guaranteed' request at balanced precision (or a
+        if (bucket is None and accuracy_tier != "balanced"
+                and (op, accuracy_tier) not in api.SINGLE_TIERS):
+            # the oversize route has a tiered program only for a
+            # 'guaranteed' posv (robust/refine.posv_dense) — silently
+            # serving any other tiered request at balanced precision (or a
             # 'fast' one at full) would betray the contract, so fail loud
             self.executor.fail(
                 ticket, op,
@@ -591,7 +596,7 @@ class SolveEngine:
                     t_enq,
                 )
             else:
-                self._run_single(ticket, op, A, B, t_enq)
+                self._run_single(ticket, op, A, B, t_enq, accuracy_tier)
             return ticket
         pa, pb = batching.pad_operands(op, A, B, bucket)
         if bucket.tier == "guaranteed":
@@ -1386,7 +1391,7 @@ class SolveEngine:
         return self.cache.get(key, build, warmup=True)
 
     def _run_single(self, ticket: Ticket, op: str, A, B,
-                    t_enq: float) -> None:
+                    t_enq: float, tier: str = "balanced") -> None:
         tr = ticket.trace
         if tr is not None:
             # oversize singles never queue or batch: the chain collapses
@@ -1396,7 +1401,8 @@ class SolveEngine:
         a_sds = jax.ShapeDtypeStruct(A.shape, A.dtype)
         b_sds = (jax.ShapeDtypeStruct(B.shape, B.dtype)
                  if B is not None else None)
-        exe = self._get_single(op, a_sds, b_sds)
+        exe = self._get_single(op, a_sds, b_sds, tier=tier)
         if tr is not None:
             tr.extend("cache_lookup")
-        self.executor.run_single(ticket, op, A, B, exe, t_enq)
+        sink = self._refine_sink(op) if tier == "guaranteed" else None
+        self.executor.run_single(ticket, op, A, B, exe, t_enq, sink=sink)

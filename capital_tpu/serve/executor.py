@@ -255,23 +255,9 @@ class Executor:
                     tr.extend("refine")  # sink bookkeeping ran host-side
             op = p.client_op or fl.bucket.op
             if err is not None:
-                # the sink refused the result (double-failed downdate
-                # degrade): land it as a LOUD failure, never a silent
-                # wrong answer (docs/ROBUSTNESS.md)
-                lat = t_land - p.t_enq
-                p.ticket.response = Response(
-                    request_id=p.ticket.request_id, op=op, ok=False,
-                    x=None, info=self._norm_info(ri), error=err,
-                    bucket=fl.bucket.key, batched=True, latency_s=lat,
-                    queue_wait_s=max(0.0, fl.t0 - p.t_enq),
-                    device_s=max(0.0, t_land - fl.t0),
-                    trace=tr,
-                )
-                if tr is not None:
-                    tr.extend("respond")
-                self.stats.record_request(
-                    op, lat, ok=False, failed=True,
-                    bucket=batching.bucket_label(fl.bucket))
+                self._refuse(p.ticket, op, ri, err, fl.bucket.key,
+                             batched=True, t_enq=p.t_enq, t0=fl.t0,
+                             t_land=t_land)
                 continue
             self._finish(
                 p.ticket, op, xi, ri, fl.bucket.key,
@@ -284,18 +270,28 @@ class Executor:
     # ---- single-problem (oversize) route ----------------------------------
 
     def run_single(self, ticket: Ticket, op: str, A, B, exe,
-                   t_enq: float) -> None:
+                   t_enq: float, sink=None) -> None:
         """Oversize requests stay synchronous: one exact-shape problem
         through the models/ schedules, landed immediately (no batch to
         overlap against, and the models paths carry their own internal
-        pipelining)."""
+        pipelining).  The program returns (X, *extras, info); a landing
+        `sink` consumes the extras as it does for a batch (the guaranteed
+        tier's refine sink)."""
         t0 = spans.now()
         ticket.t0 = t0
-        x, raw = exe(A) if B is None else exe(A, B)
-        x, raw = jax.block_until_ready((x, raw))
+        outs = exe(A) if B is None else exe(A, B)
+        x, *extras, raw = jax.block_until_ready(outs)
         t_land = spans.now()
         if ticket.trace is not None:
             ticket.trace.extend("device", t_land)
+        if sink is not None:
+            x, raw, err = sink(x, tuple(extras), raw)
+            if ticket.trace is not None:
+                ticket.trace.extend("refine")
+            if err is not None:
+                self._refuse(ticket, op, raw, err, None, batched=False,
+                             t_enq=t_enq, t0=t0, t_land=t_land)
+                return
         self._finish(ticket, op, x, raw, None, batched=False, t_enq=t_enq,
                      t0=t0, t_land=t_land)
 
@@ -321,6 +317,28 @@ class Executor:
             latency_s=lat, trace=tr,
         )
         self.stats.record_request(op, lat, ok=False, failed=True)
+
+    def _refuse(self, ticket: Ticket, op: str, raw_info, err: str,
+                bucket_key: Optional[tuple], batched: bool, t_enq: float,
+                t0: float, t_land: float) -> None:
+        """Land a result its sink refused (a guaranteed solve that did not
+        converge, a double-failed downdate degrade) as a LOUD failure,
+        never a silent wrong answer (docs/ROBUSTNESS.md)."""
+        lat = t_land - t_enq
+        tr = ticket.trace
+        ticket.response = Response(
+            request_id=ticket.request_id, op=op, ok=False, x=None,
+            info=self._norm_info(raw_info), error=err, bucket=bucket_key,
+            batched=batched, latency_s=lat,
+            queue_wait_s=max(0.0, t0 - t_enq),
+            device_s=max(0.0, t_land - t0), trace=tr,
+        )
+        if tr is not None:
+            tr.extend("respond")
+        self.stats.record_request(
+            op, lat, ok=False, failed=True,
+            bucket=(batching.bucket_label(bucket_key)
+                    if bucket_key is not None else None))
 
     def _norm_info(self, raw) -> Optional[RobustInfo]:
         if self.cfg.robust is None:

@@ -164,8 +164,14 @@ class TestRefinePosv:
         # cond 1e8 > 1/u32: the f32 factor still completes (info 0) but
         # the error floors orders of magnitude above the f64 tolerance,
         # so the progress guard freezes the problem and reports it —
-        # never a spin, never a silent wrong answer
-        rng = np.random.default_rng(7)
+        # never a spin, never a silent wrong answer.  Beyond the envelope
+        # whether a sweep gains depends on the factor's rounding, so the
+        # probed operand is one whose first sweep GROWS the error (2.6x
+        # through the batched-grid Pallas factor, 5.1x through LAPACK's):
+        # the freeze holds with room on either factor route.  Seed 7, the
+        # case probed before, halves its error sweep after sweep to a
+        # true f64-grade answer (test_beyond_envelope_reports_honestly).
+        rng = np.random.default_rng(21)
         n = 16
         bad = _spd_cond(rng, n, 1e8)
         A = np.concatenate([bad, bad])  # (2, n, n): the shared-shape class
@@ -177,6 +183,28 @@ class TestRefinePosv:
         assert np.all(np.asarray(ri.iters) <= 2)  # froze, didn't spin
         assert np.all(np.asarray(ri.resid) > refine.tolerance(
             n, jnp.float64))  # the measured error says why
+
+    @pytest.mark.parametrize("seed", [7, 8, 21])
+    def test_beyond_envelope_reports_honestly(self, seed):
+        # beyond the envelope a problem may stall or may still converge;
+        # either way the report is true: converged means the f64
+        # backward error, measured here in NumPy, is under tolerance, and
+        # not converged comes with a measured error above it
+        rng = np.random.default_rng(seed)
+        n = 16
+        bad = _spd_cond(rng, n, 1e8)
+        A = np.concatenate([bad, bad])
+        b1 = rng.standard_normal((1, n, 2))
+        B = np.concatenate([b1, b1])
+        X, info, ri = _posv(jnp.asarray(A), jnp.asarray(B))
+        tol = refine.tolerance(n, jnp.float64)
+        assert not np.any(np.asarray(info))
+        for i in range(2):
+            if int(ri.converged[i]):
+                assert _bwerr(A[i:i + 1], X[i:i + 1], B[i:i + 1]) < tol
+            else:
+                assert float(ri.resid[i]) > tol
+            assert int(ri.iters[i]) <= refine.DEFAULT_MAX_ITERS
 
     def test_per_problem_freeze_is_independent(self):
         # batch mixing a clean problem with a beyond-envelope one: the
@@ -445,12 +473,19 @@ class TestServeTiers:
             engine.solve("inv", A, accuracy_tier="guaranteed")
 
     def test_oversize_tiered_request_fails_loud(self, engine):
+        # the oversize route's one tiered program is the guaranteed posv
+        # (robust/refine.posv_dense); a 'fast' posv or a guaranteed lstsq
+        # beyond the ladder has none and fails loud
         rng = np.random.default_rng(41)
         n = 64  # beyond the (16,) ladder
         G = rng.standard_normal((n, n)).astype(np.float32)
         A = (G @ G.T / n + 3.0 * np.eye(n, dtype=np.float32))
         B = rng.standard_normal((n, 2)).astype(np.float32)
-        r = engine.solve("posv", A, B, accuracy_tier="guaranteed")
+        r = engine.solve("posv", A, B, accuracy_tier="fast")
+        assert not r.ok
+        assert "no oversize route" in r.error
+        T = rng.standard_normal((4 * n, n)).astype(np.float32)
+        r = engine.solve("lstsq", T, T[:, :2], accuracy_tier="guaranteed")
         assert not r.ok
         assert "no oversize route" in r.error
 

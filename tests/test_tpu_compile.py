@@ -147,6 +147,29 @@ def test_factor_and_invert_kernel(chip, n, N):
     assert _mosaic_calls(fn, *args, scope=chip) == 1
 
 
+def test_dense_refined_solve(chip, monkeypatch):
+    """refine.posv_dense, the mxp cell's program at a smaller n: the bf16
+    factor on the Pallas kernels, A only read (no working copy of it), and
+    the FP64-grade residual in XLA's emulated f64 with no n² temporary
+    beside R, R⁻¹ and the trailing windows (the emulated f64 dot would
+    split A into many n² pieces)."""
+    from capital_tpu.robust import refine
+
+    routes = spans.RouteCounter()
+    monkeypatch.setattr(spans, "REFINE_ROUTES", routes)
+    n = 4096  # at 2048 the temporaries do not show a working copy of A
+    g = Grid.square(c=1, devices=[chip])
+    compiled = jax.jit(lambda a, b: refine.posv_dense(g, a, b)).lower(
+        _sds(chip, (n, n), jnp.bfloat16), _sds(chip, (n,), jnp.float32),
+    ).compile()
+    txt = compiled.as_text()
+    assert "tpu_custom_call" in txt
+    assert not re.search(rf"bf16\[{n},{n}\]\S* copy\(", txt)
+    assert routes.snapshot()["refine/xla_f64"]["n"] == n
+    # R, R⁻¹ and the trailing windows; a working copy of A adds 2 n²
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * n * n * 2
+
+
 @pytest.mark.parametrize("op,m", [("posv", 64), ("lstsq", 128)])
 def test_batched_small(chip, op, m):
     A = _sds(chip, (32, m, 64), jnp.float32)

@@ -355,7 +355,10 @@ ROUTES = RouteCounter()
 #: ``n``.  Apart from `ROUTES`, whose every route is one of `qr.factor`.
 CHOL_ROUTES = RouteCounter()
 #: The FP64-grade residual's route in `refine.posv_dense`, counted as each
-#: build traces it: ``refine/xla_f64``, tagged with the system's ``n``.
+#: build traces it, tagged with the system's ``n``: ``refine/pallas`` (the
+#: kernel `pallas_tpu.residual_bf16`: bf16 A on a TPU, n a multiple of
+#: 128, at most 2 right-hand sides, a grid step that fits the VMEM) or
+#: ``refine/xla_f64`` (XLA's emulated f64: anything else).
 REFINE_ROUTES = RouteCounter()
 _WATCH_LOCK = threading.Lock()
 _watching = False

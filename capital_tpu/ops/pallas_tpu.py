@@ -1701,3 +1701,234 @@ def tri_matmul(
     if out is not None:
         return res
     return res[:am, :bnd] if (M != am or N != bnd) else res
+
+
+# --------------------------------------------------------------------------
+# FP64-grade residual of a bf16 matrix, one read of it
+# --------------------------------------------------------------------------
+
+#: The largest (rows, columns) of A a grid step of `residual_bf16` takes;
+#: each shrinks by halves to divide n, then the columns (then the rows) to
+#: fit the step in the device's VMEM (`_residual_vmem`).  On a v5e at n =
+#: 32768 1024 × 4096 read A at 700-705 GB/s, 1024 × 8192 at 710-716,
+#: 512 × 8192 at 703.
+RESID_BLOCKS = (1024, 4096)
+#: Rows of A a slab of the kernel's inner loop: its accumulators stay in
+#: vector registers across the tile's 128-column chunks (slabs of 16 or
+#: 64 rows read A 8% and 1% slower on a v5e).  The chunks are unrolled
+#: whole: a loop over groups of 8 of them read A at 621-626 GB/s.
+RESID_SLAB = 32
+#: Bits of x_hi's head in `residual_bf16`: times a bf16 A_ij (8 bits) it
+#: is exact in f32's 24, and so is the 8-bit tail's product.
+RESID_HEAD_BITS = 16
+#: The most columns of B `residual_bf16` takes.  Each column adds three
+#: (slab, 128) f32 accumulators, 12 of the 64 vector registers, and one
+#: more copy of the unrolled chunk body; at 2 they still fit in registers.
+RESID_MAX_K = 2
+#: Mosaic's scoped-VMEM limit where `_TILE_BUDGET` raises none (a v4).
+MOSAIC_VMEM_DEFAULT = 16 * 2**20
+
+
+def _residual_vmem(bm: int, bn: int, k: int) -> int:
+    """VMEM bytes a grid step of `residual_bf16` holds: A's tile, x's rows,
+    B's and r's blocks (lanes padded to 128), each double-buffered, and
+    the accumulators and x's broadcast rows."""
+    return (2 * bm * bn * 2 + 2 * _round_up(3 * k, 8) * bn * 4
+            + 4 * bm * 128 * 4 + 3 * k * (bm * 128 + 8 * bn) * 4)
+
+
+def residual_blocks(n: int, k: int = 1) -> tuple[int, int] | None:
+    """(bm, bn) of `residual_bf16` for an n×n A and k columns: the largest
+    halving of each of `RESID_BLOCKS` that divides n, the columns halved
+    (then the rows, down to a slab) until a step fills at most 3/4 of the
+    scoped device's VMEM limit (Mosaic's own where `_device_budget` gives
+    none; the rest is Mosaic's own scratch: for 1024 × 4096 at k = 1 it
+    asks 22.3 MiB where `_residual_vmem` counts 20.1); None unless n is a
+    multiple of 128 and 1 ≤ k ≤ `RESID_MAX_K`."""
+    if n % 128 or not 1 <= k <= RESID_MAX_K:
+        return None
+
+    def fit(b):
+        while n % b:
+            b //= 2
+        return b
+
+    bm, bn = (fit(b) for b in RESID_BLOCKS)
+    room = (_device_budget()[1] or MOSAIC_VMEM_DEFAULT) * 3 // 4
+    while _residual_vmem(bm, bn, k) > room:
+        if bn > 128:
+            bn //= 2
+        elif bm > RESID_SLAB:
+            bm //= 2
+        else:
+            return None
+    return bm, bn
+
+
+def _pow2_above(v):
+    """The least power of two at or above v > 0 (1 at v == 0): frexp's
+    exponent, exact."""
+    _, e = jnp.frexp(v)
+    return jnp.ldexp(jnp.float32(1.0), e)
+
+
+def two_sum(a, b):
+    """Knuth's TwoSum: s + e == a + b exactly, s = fl(a + b)."""
+    s = a + b
+    bb = s - a
+    return s, (a - (s - bb)) + (b - bb)
+
+
+@dataclasses.dataclass(frozen=True)
+class _ResidualSpec:
+    n: int
+    k: int
+    bm: int
+    bn: int
+    slab: int
+    interpret: bool
+    phase: str
+
+
+@_kernel_cache
+def _residual_kernel(sig, A, B, P, *, spec: _ResidualSpec):
+    n, k, bm, bn, slab = spec.n, spec.k, spec.bm, spec.bn, spec.slab
+    f32 = jnp.float32
+
+    def kernel(sig_ref, a_ref, b_ref, p_ref, r_ref, acc_ref, x_ref):
+        j = pl.program_id(1)
+        s1, s2 = sig_ref[0], sig_ref[1]
+        # x's rows broadcast over a vreg's 8 sublanes, once a step
+        for i in range(3 * k):
+            x_ref[i] = jnp.broadcast_to(p_ref[i:i + 1, :], (8, bn))
+
+        @pl.when(j == 0)
+        def _():
+            for c in range(k):
+                acc_ref[3 * c] = jnp.full((bm, 128), s1, f32)
+                acc_ref[3 * c + 1] = jnp.full((bm, 128), s2, f32)
+                acc_ref[3 * c + 2] = jnp.zeros((bm, 128), f32)
+
+        g = (slab // 8, 8, 128)
+
+        def rows(t, carry):
+            rs = pl.ds(pl.multiple_of(t * slab, slab), slab)
+            acc = [acc_ref[i, rs, :].reshape(g) for i in range(3 * k)]
+            for q in range(bn // 128):
+                cs = slice(q * 128, (q + 1) * 128)
+                a = a_ref[rs, cs].astype(f32).reshape(g)
+                for c in range(k):
+                    hi, mid, lo = acc[3 * c:3 * c + 3]
+                    # FastTwoSum into sums biased by s1, s2 (the bias keeps
+                    # each sum above every term, so what an add rounds away
+                    # is exact): the head's exact product into hi, what
+                    # that rounds away and the tail's exact product into
+                    # mid, what mid rounds away and x_lo's product into lo
+                    p = a * x_ref[3 * c, :, cs]
+                    s = hi + p
+                    e1 = p - (s - hi)
+                    hi = s
+                    s = mid + e1
+                    e2 = e1 - (s - mid)
+                    mid = s
+                    p = a * x_ref[3 * c + 1, :, cs]
+                    s = mid + p
+                    e3 = p - (s - mid)
+                    mid = s
+                    lo = lo + e2 + e3 + a * x_ref[3 * c + 2, :, cs]
+                    acc[3 * c:3 * c + 3] = [hi, mid, lo]
+            for i in range(3 * k):
+                acc_ref[i, rs, :] = acc[i].reshape(slab, 128)
+            return carry
+
+        lax.fori_loop(jnp.int32(0), jnp.int32(bm // slab), rows, 0)
+
+        @pl.when(j == pl.num_programs(1) - 1)
+        def _():
+            s3 = s2 * f32(256.0)
+            for c in range(k):
+                # unbiased, each lane's sums are exact; so is hi's sum over
+                # the lanes (all of it lies under s1), and mid's once split
+                # against s3 = 256·s2 into a coarse and a fine part
+                hi = jnp.sum(acc_ref[3 * c] - s1, axis=1, keepdims=True)
+                mid = acc_ref[3 * c + 1] - s2
+                coarse = (s3 + mid) - s3
+                fine = jnp.sum(mid - coarse, axis=1, keepdims=True)
+                coarse = jnp.sum(coarse, axis=1, keepdims=True)
+                lo = jnp.sum(acc_ref[3 * c + 2], axis=1, keepdims=True)
+                rh, rl = two_sum(b_ref[:, c:c + 1], -hi)
+                for d in (-coarse, -fine, -lo):
+                    rh, e = two_sum(rh, d)
+                    rl = rl + e
+                r_ref[:, c:c + 1] = rh + rl
+
+    return pl.pallas_call(
+        kernel,
+        name=tracing.kernel_name("residual", spec.phase),
+        grid=(n // bm, n // bn),
+        in_specs=[
+            pl.BlockSpec((2,), lambda i, j: (_I0,), memory_space=pltpu.SMEM),
+            pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
+            pl.BlockSpec((bm, k), lambda i, j: (i, _I0)),
+            pl.BlockSpec((3 * k, bn), lambda i, j: (_I0, j)),
+        ],
+        out_specs=pl.BlockSpec((bm, k), lambda i, j: (i, _I0)),
+        out_shape=jax.ShapeDtypeStruct((n, k), f32),
+        scratch_shapes=[pltpu.VMEM((3 * k, bm, 128), f32),
+                        pltpu.VMEM((3 * k, 8, bn), f32)],
+        cost_estimate=pl.CostEstimate(
+            flops=2 * n * n * k, bytes_accessed=2 * n * n + 16 * n * k,
+            transcendentals=0),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_device_budget()[1]),
+        interpret=spec.interpret,
+    )(sig, A, B, P)
+
+
+def residual_bf16(A, B, Xh, Xl, *, anorm):
+    """r = B − A·(Xh + Xl) to FP64 accuracy, rounded to f32, in one read of
+    A: A (n, n) bf16 with n a multiple of 128, B, Xh, Xl (n, k) f32 with
+    k ≤ `RESID_MAX_K` (`residual_blocks`), Xh + Xl a float-float pair
+    (|Xl| at most half an ulp of Xh), `anorm` a
+    bound on ‖A‖∞ from above (to within a few percent; the looser, the
+    less accurate).
+
+    No f64 arithmetic: A is exact in bf16 (8 significant bits), so Xh is
+    split into a 16-bit head and an 8-bit tail (Veltkamp) whose products
+    with A are exact in f32; Xl's product rounds at about 2⁻⁴⁸ of
+    |A_ij·x_j|.  Each of 128 lanes sums its share of a row in FastTwoSum
+    chains biased by powers of two (after Rump, Ogita and Oishi's
+    extraction): the bias keeps a sum above every term, so what each add
+    rounds away is exact.  The head's products go to a chain biased by
+    σ1 ≥ 2.5·anorm·‖Xh‖∞, whose roundings (at most 2⁻²⁴σ1) and the
+    tail's products go to one biased by σ2 ≥ 2.5·((n/128)·2⁻²⁴σ1 +
+    2⁻¹⁵·anorm·‖Xh‖∞); what that one rounds away, and Xl's products, go to
+    a plain f32 sum.  So the first two are exact, and the third errs at
+    about 2⁻²⁴ of its terms, each a few ulps of σ2.  The lanes' sums are
+    summed exactly and taken from B in float-float.  16 vector operations
+    an element of A, 3 of them products."""
+    n, k = B.shape
+    blocks = residual_blocks(n, k)
+    if A.shape != (n, n) or A.dtype != jnp.bfloat16 or blocks is None:
+        raise ValueError(f"residual_bf16: A (n, n) bf16 with n a multiple of "
+                         f"128 and B (n, k ≤ {RESID_MAX_K}), got {A.shape} "
+                         f"{A.dtype}, B {B.shape}")
+    f32 = jnp.float32
+    Xh, Xl = Xh.astype(f32), Xl.astype(f32)
+    c = Xh * f32(2.0 ** (24 - RESID_HEAD_BITS) + 1.0)  # Veltkamp's split
+    head = c - (c - Xh)
+    tail = Xh - head
+    T = jnp.asarray(anorm, f32) * jnp.max(jnp.abs(Xh))
+    s1 = _pow2_above(f32(2.5) * T)
+    s2 = _pow2_above(f32(2.5) * (f32(n // 128 * 2.0**-24) * s1
+                                 + f32(2.0 ** (1 - RESID_HEAD_BITS)) * T))
+    # (3k, n): rows head, tail, Xl of each column of X
+    P = jnp.stack([head, tail, Xl], axis=0).transpose(2, 0, 1).reshape(
+        3 * k, n)
+    spec = _ResidualSpec(n=n, k=k, bm=blocks[0], bn=blocks[1],
+                         slab=RESID_SLAB,
+                         interpret=_interpret_default(),
+                         phase=tracing.active_phase("IR::residual"))
+    return _residual_kernel(jnp.stack([s1, s2]), A, B.astype(f32), P,
+                            spec=spec)

@@ -312,9 +312,9 @@ def _ff_add(hi, lo, d):
     """(hi, lo) + d for float-float pairs of f32 (hi + lo to about 48
     bits): Knuth's TwoSum of hi and d, the error folded into lo, then
     renormalised so that |lo| stays under half an ulp of hi."""
-    s = hi + d
-    bb = s - hi
-    err = (hi - (s - bb)) + (d - bb)
+    from capital_tpu.ops import pallas_tpu
+
+    s, err = pallas_tpu.two_sum(hi, d)
     lo = lo + err
     hi = s + lo
     return hi, lo - (hi - s)
@@ -323,19 +323,52 @@ def _ff_add(hi, lo, d):
 def _residual_f64(A, B, Xh, Xl):
     """FP64-grade residual B − A·X of the float-float solution X = Xh + Xl,
     rounded to f32 (its own f32 rounding is far below what a correction
-    or the ∞-norm of r needs).  XLA's float64 under a scoped
+    or the ∞-norm of r needs), in XLA's float64 under a scoped
     `jax.enable_x64`, written as a product and a row sum (no dot): on a
     TPU, which has no f64 unit, XLA emulates both in pairs of f32 and
     fuses them into one pass over A; its emulated f64 dot would instead
-    split A into many n² pieces.  Counted as route ``refine/xla_f64``."""
-    from capital_tpu.obs import spans
-
-    spans.REFINE_ROUTES.take("refine/xla_f64", n=int(A.shape[0]))
+    split A into many n² pieces.  Route ``refine/xla_f64`` of `_residual`:
+    any A dtype, any n, any platform."""
     with tracing.scope("IR::residual"), jax.enable_x64(True):
         f64 = jnp.float64
         x = Xh.astype(f64) + Xl.astype(f64)
         ax = jnp.sum(A.astype(f64)[:, :, None] * x[None, :, :], axis=1)
         return (B.astype(f64) - ax).astype(jnp.float32)
+
+
+def _pallas_residual(A, B) -> bool:
+    """Whether `_residual` takes the Pallas kernel for A and B (n, k): the
+    scoped platform is a TPU (pallas_tpu.device_scope), A is bf16 (the
+    kernel's exact products need A's 8-bit significand), n is a multiple
+    of 128, k ≤ `pallas_tpu.RESID_MAX_K` and a grid step fits the
+    device's VMEM (`pallas_tpu.residual_blocks`)."""
+    from capital_tpu.ops import pallas_tpu
+
+    return (pallas_tpu._platform() == "tpu" and A.dtype == jnp.bfloat16
+            and pallas_tpu.residual_blocks(*B.shape) is not None)
+
+
+def _residual(A, B, Xh, Xl, anorm):
+    """The FP64-grade residual B − A·(Xh + Xl), rounded to f32, under
+    ``IR::residual``, by one of two routes, chosen at trace time and
+    counted in `spans.REFINE_ROUTES` with the system's ``n``:
+
+    * ``refine/pallas`` where `_pallas_residual` holds (bf16 A on a TPU, n
+      a multiple of 128, at most `pallas_tpu.RESID_MAX_K` columns):
+      `pallas_tpu.residual_bf16`, one read of A with exact products and
+      error-free sums in f32, scaled by `anorm` (‖A‖∞, from above);
+    * ``refine/xla_f64`` otherwise (f32 or f64 A, the CPU, any other n or
+      more columns): `_residual_f64`."""
+    from capital_tpu.obs import spans
+    from capital_tpu.ops import pallas_tpu
+
+    n = int(A.shape[0])
+    if _pallas_residual(A, B):
+        spans.REFINE_ROUTES.take("refine/pallas", n=n)
+        with tracing.scope("IR::residual"):
+            return pallas_tpu.residual_bf16(A, B, Xh, Xl, anorm=anorm)
+    spans.REFINE_ROUTES.take("refine/xla_f64", n=n)
+    return _residual_f64(A, B, Xh, Xl)
 
 
 def _split_mv(M, v, *, trans: bool = False):
@@ -370,8 +403,13 @@ def posv_dense(grid, A, B, *, max_iters: int = DEFAULT_MAX_ITERS):
     against 2.6 n² this way), and ``complete_inv=False``: the correction
     d = R⁻¹R⁻ᵀr is a one-level block substitution, with R12 and the two
     diagonal inverse blocks of R⁻¹ the factor leaves (`_split_mv`), under
-    ``IR::correct``.  The residual is FP64-grade (`_residual_f64`, under
-    ``IR::residual``) and X is a float-float pair of f32.  The loop
+    ``IR::correct``.  The residual is FP64-grade (`_residual`, under
+    ``IR::residual``): for bf16 A on a TPU, with n a multiple of 128 and
+    at most `pallas_tpu.RESID_MAX_K` columns in B, one Pallas kernel
+    that reads A once
+    (`pallas_tpu.residual_bf16`, route ``refine/pallas``), else XLA's
+    emulated f64 (`_residual_f64`, route ``refine/xla_f64``); X is a
+    float-float pair of f32.  The loop
     (`_refine_loop`: the in-program test, the progress guard, the sweep
     cap `max_iters`; 0 returns the factor's own solve) stops when the
     scaled residual ‖b − Ax‖∞ / ((‖A‖∞‖x‖∞ + ‖b‖∞) · n · `HPL_EPS`),
@@ -383,6 +421,7 @@ def posv_dense(grid, A, B, *, max_iters: int = DEFAULT_MAX_ITERS):
     problem ((1,) arrays; `resid` the scaled residual).  A factor that
     breaks or a refinement that stalls comes back with converged == 0."""
     from capital_tpu.models import cholesky
+    from capital_tpu.ops import pallas_tpu
     from capital_tpu.parallel import summa
     from capital_tpu.robust import detect
 
@@ -427,7 +466,8 @@ def posv_dense(grid, A, B, *, max_iters: int = DEFAULT_MAX_ITERS):
             return jnp.concatenate([d1, d2])[None]
 
     def resid(X):
-        return _residual_f64(A, Bm, X[0][0], X[1][0])[None]
+        with pallas_tpu.device_scope(grid.mesh.devices.flat[0]):
+            return _residual(A, Bm, X[0][0], X[1][0], anorm)[None]
 
     def err(X, r):
         rmax = jnp.max(jnp.abs(r[0]), axis=0)

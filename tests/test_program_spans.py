@@ -278,7 +278,7 @@ def test_every_pallas_call_site_passes_a_name():
                     and node.func.attr == "pallas_call"):
                 kw = {k.arg: k.value for k in node.keywords}
                 sites[(os.path.basename(path), node.lineno)] = kw
-    assert len(sites) == 14
+    assert len(sites) == 15
     for site, kw in sites.items():
         assert "name" in kw, site
         call = kw["name"]

@@ -55,6 +55,20 @@ def chip(topo):
     return topo.devices[0]
 
 
+@pytest.fixture(scope="module")
+def v4_chip(topo):
+    """One chip of a described TPU v4, whose kind keeps Mosaic's own
+    16 MiB of scoped VMEM (`pallas_tpu._TILE_BUDGET`)."""
+    from jax.experimental import topologies
+
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v4:2x2x1")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot describe"
+        pytest.skip(f"no v4:2x2x1 topology can be described here: {e}")
+    return t.devices[0]
+
+
 def _sds(chip, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype,
                                 sharding=SingleDeviceSharding(chip))
@@ -150,9 +164,8 @@ def test_factor_and_invert_kernel(chip, n, N):
 def test_dense_refined_solve(chip, monkeypatch):
     """refine.posv_dense, the mxp cell's program at a smaller n: the bf16
     factor on the Pallas kernels, A only read (no working copy of it), and
-    the FP64-grade residual in XLA's emulated f64 with no n² temporary
-    beside R, R⁻¹ and the trailing windows (the emulated f64 dot would
-    split A into many n² pieces)."""
+    the FP64-grade residual in its Pallas kernel (route refine/pallas),
+    with no n² temporary beside R, R⁻¹ and the trailing windows."""
     from capital_tpu.robust import refine
 
     routes = spans.RouteCounter()
@@ -165,9 +178,44 @@ def test_dense_refined_solve(chip, monkeypatch):
     txt = compiled.as_text()
     assert "tpu_custom_call" in txt
     assert not re.search(rf"bf16\[{n},{n}\]\S* copy\(", txt)
-    assert routes.snapshot()["refine/xla_f64"]["n"] == n
+    assert list(routes.snapshot()) == ["refine/pallas"]
+    assert routes.snapshot()["refine/pallas"]["n"] == n
+    assert "IR.residual.residual" in txt
     # R, R⁻¹ and the trailing windows; a working copy of A adds 2 n²
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * n * n * 2
+
+
+@pytest.mark.parametrize("k,route", [(2, "refine/pallas"),
+                                     (8, "refine/xla_f64"),
+                                     (64, "refine/xla_f64")])
+def test_dense_refined_solve_columns(chip, monkeypatch, k, route):
+    """posv_dense on a bf16 A with k right-hand sides, as the serve tier's
+    oversize guaranteed posv sends them: up to pallas_tpu.RESID_MAX_K
+    columns the residual kernel, more the XLA route; either compiles."""
+    from capital_tpu.robust import refine
+
+    routes = spans.RouteCounter()
+    monkeypatch.setattr(spans, "REFINE_ROUTES", routes)
+    n = 2048
+    g = Grid.square(c=1, devices=[chip])
+    txt = jax.jit(lambda a, b: refine.posv_dense(g, a, b)).lower(
+        _sds(chip, (n, n), jnp.bfloat16), _sds(chip, (n, k), jnp.float32),
+    ).compile().as_text()
+    assert list(routes.snapshot()) == [route]
+    assert ("IR.residual.residual" in txt) is (route == "refine/pallas")
+
+
+@pytest.mark.parametrize("k", [1, 2])
+def test_residual_kernel_fits_a_v4(v4_chip, k):
+    """The residual kernel narrows its grid step to Mosaic's 16 MiB on a
+    TPU kind with no raised VMEM limit (1024 × 4096 needs ~22 MiB)."""
+    n = 4096
+    with pallas_tpu.device_scope(v4_chip):
+        assert pallas_tpu.residual_blocks(n, k)[1] < 4096
+    b = _sds(v4_chip, (n, k), jnp.float32)
+    assert _mosaic_calls(
+        lambda a, b: pallas_tpu.residual_bf16(a, b, b, b, anorm=3.0),
+        _sds(v4_chip, (n, n), jnp.bfloat16), b, scope=v4_chip) == 1
 
 
 @pytest.mark.parametrize("op,m", [("posv", 64), ("lstsq", 128)])

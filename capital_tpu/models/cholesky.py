@@ -363,6 +363,8 @@ def _base_case_into(
             # double-buffered write-back: both transposes in one launch,
             # two aliased output streams in flight per tile step
             return pallas_tpu.transpose_pair(L, Linv, Rp, RIp, dest=dest)
+        # a mesh's leaf is XLA's potrf + trtri on the replicated panel
+        lapack.count_chol_route(False, n)
         if ptile:
             wperm, winv = summa.tile_cyclic_perm(n, grid.dx, ptile)
             window = summa.cyclic_window(
@@ -371,6 +373,9 @@ def _base_case_into(
             window = lax.with_sharding_constraint(
                 window, grid.replicated_sharding()
             )
+            # the panel is replicated: index gathers stay on each chip
+            # (summa.cyclic_permute's transposes would be partitioned
+            # toward the sharded buffers they feed)
             iw = jnp.asarray(winv)
             R, Rinv = _scoped_base_factor(grid, window[iw][:, iw], scope_)
             pw = jnp.asarray(wperm)
@@ -721,15 +726,11 @@ def factor(
     # shuffles below) replace the 2-3 shuffles PER trmm/syrk call of
     # balance='tile_cyclic'.
     ptile = 0
-    unperm = None
     if cfg.balance == "tile_cyclic_persistent":
         ptile = persistent_tile(grid, node, cfg)
         if ptile:
-            perm, pinv = summa.tile_cyclic_perm(p, grid.dx, ptile)
-            pj = jnp.asarray(perm)
-            unperm = jnp.asarray(pinv)
             with tracing.scope("CI::io"):
-                Ap = grid.pin(Ap[pj][:, pj])
+                Ap = grid.pin(summa.cyclic_permute(Ap, grid.dx, ptile))
                 cbytes, ncoll = tracing.transpose_cost(grid, p, p, Ap.dtype)
                 tracing.emit(comm_bytes=3 * cbytes, collectives=3 * ncoll)
         else:
@@ -756,14 +757,14 @@ def factor(
             # and the persistent layout are documented as an either/or
             # (docs/DISTRIBUTED.md).
             with tracing.scope("CI::io"):
-                Rp = grid.pin(Rp[pj][:, pj])
-                RIp = grid.pin(RIp[pj][:, pj])
+                Rp = grid.pin(summa.cyclic_permute(Rp, grid.dx, ptile))
+                RIp = grid.pin(summa.cyclic_permute(RIp, grid.dx, ptile))
                 cbytes, ncoll = tracing.transpose_cost(grid, p, p, Rp.dtype)
                 tracing.emit(comm_bytes=2 * cbytes, collectives=2 * ncoll)
         _, R, Rinv = _recurse(
             grid, Ap, 0, node, cfg, True, Rp, RIp, ptile, tail_infos
         )
-        return _exit(grid, R, Rinv, n, unperm, cfg, tail_infos)
+        return _exit(grid, R, Rinv, n, ptile, cfg, tail_infos)
 
     tile = _zeros_plan(grid, node, cfg)
     if tile:
@@ -792,17 +793,17 @@ def factor(
     _, R, Rinv = _recurse(
         grid, Ap, 0, node, cfg, True, Rp, RIp, ptile, tail_infos
     )
-    return _exit(grid, R, Rinv, n, unperm, cfg, tail_infos)
+    return _exit(grid, R, Rinv, n, ptile, cfg, tail_infos)
 
 
-def _exit(grid, R, Rinv, n, unperm, cfg, tail_infos):
+def _exit(grid, R, Rinv, n, ptile, cfg, tail_infos):
     """factor's outputs, under CI::io: back in the original order (the
-    persistent layout), the padding cropped, and with cfg.robust the
-    breakdown status of the cropped factor."""
+    persistent layout of tile `ptile`, when nonzero), the padding cropped,
+    and with cfg.robust the breakdown status of the cropped factor."""
     with tracing.scope("CI::io"):
-        if unperm is not None:
-            R = R[unperm][:, unperm]
-            Rinv = Rinv[unperm][:, unperm]
+        if ptile:
+            R = summa.cyclic_permute(R, grid.dx, ptile, inverse=True)
+            Rinv = summa.cyclic_permute(Rinv, grid.dx, ptile, inverse=True)
         R, Rinv = grid.pin(R), grid.pin(Rinv)
         if R.shape[0] != n:
             R, Rinv = R[:n, :n], Rinv[:n, :n]

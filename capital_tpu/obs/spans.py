@@ -14,7 +14,9 @@ Two span kinds share this module and its clock:
   and the distinct kernels built for them; `ROUTES` counts the routes the
   builds of `qr.factor` took, `CHOL_ROUTES` the path each factor-and-invert
   site took (one Pallas kernel or XLA's Cholesky and triangular solve),
-  `REFINE_ROUTES` the route of the dense refined solve's FP64 residual.
+  `REFINE_ROUTES` the route of the dense refined solve's FP64 residual,
+  `SUMMA_ROUTES` the schedule and per-shard product of each trmm and syrk
+  site.
   The span vocabulary is in docs/OBSERVABILITY.md "Program spans".
 * **Request chains** (`RequestTrace`): every request the SolveEngine
   admits carries an ordered chain of spans covering its whole life —
@@ -360,6 +362,13 @@ CHOL_ROUTES = RouteCounter()
 #: 128, at most 2 right-hand sides, a grid step that fits the VMEM) or
 #: ``refine/xla_f64`` (XLA's emulated f64: anything else).
 REFINE_ROUTES = RouteCounter()
+#: The route of each trmm and syrk site of `parallel/summa.py`, counted as
+#: each is traced: ``<op>/<schedule>/<local>`` (``op`` trmm or syrk; the
+#: schedule that ran: ``pallas``, ``xla``, ``explicit_block``,
+#: ``explicit_cyclic`` or ``explicit_persistent``; each shard's product:
+#: ``live_tiles``, ``dense`` or ``gspmd``), tagged with the product's
+#: ``M``, ``K``, ``N`` and the grid's ``dx``, ``dy``, ``c``.
+SUMMA_ROUTES = RouteCounter()
 _WATCH_LOCK = threading.Lock()
 _watching = False
 

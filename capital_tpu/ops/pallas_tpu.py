@@ -1206,6 +1206,7 @@ class _MatmulSpec:
     out_uplo: str | None
     alpha: float
     beta: float  # nonzero: the C operand follows A and B (syrk's beta*C)
+    out_tiles: bool  # out_uplo skips dead tiles but masks no element
     # None (a fresh result), "a" / "b" (out IS that operand), "out" (a
     # separate buffer, after A and B) or "c" (the syrk read-modify-write:
     # out IS the C operand)
@@ -1341,7 +1342,8 @@ def _tri_matmul_kernel(offs, A, B, *rest, spec: _MatmulSpec):
             @pl.when(k == nk - 1)
             def _():
                 _flush(
-                    acc_ref, out_ref, alpha, out_uplo, i * bm, j * bn,
+                    acc_ref, out_ref, alpha,
+                    None if spec.out_tiles else out_uplo, i * bm, j * bn,
                     c_ref=refs[0] if fused_c else None, beta=beta,
                 )
 
@@ -1405,7 +1407,14 @@ def _tri_matmul_kernel(offs, A, B, *rest, spec: _MatmulSpec):
             # dead half explicitly (cheap elementwise, fuses with the crop
             # below).  With fused beta*C the dead half stays UNDEFINED by
             # contract — no full-matrix mask pass.
-            res = _global_tri_mask(res, 0, 0, out_uplo)
+            if spec.out_tiles:
+                r = jax.lax.broadcasted_iota(jnp.int32, res.shape, 0) // bm
+                c = jax.lax.broadcasted_iota(jnp.int32, res.shape, 1) // bn
+                live = (r * bm < (c + 1) * bn if out_uplo == "U"
+                        else c * bn < (r + 1) * bm)
+                res = jnp.where(live, res, jnp.zeros_like(res))
+            else:
+                res = _global_tri_mask(res, 0, 0, out_uplo)
         return res
 
     # ---- tri-operand (trmm): enumerate live (tile-row, k) pairs ----------
@@ -1527,9 +1536,15 @@ def tri_matmul(
     c: jnp.ndarray | None = None,
     c_view: tuple[int, int, int, int] | None = None,
     beta: float = 0.0,
+    out_tiles: bool = False,
 ) -> jnp.ndarray:
     """C = alpha * op(A) @ op(B) with dead blocks of triangular operands /
     results never visited.  See module docstring.
+
+    out_tiles (with out_uplo): skip the dead triangle's whole tiles but
+    mask no element of a live tile — for a caller whose triangle is coarser
+    than the elements (the tile-cyclic syrk of parallel/summa.py, where a
+    shard's diagonal tiles may be wholly live).  Dead tiles read zero.
 
     precision: MXU precision for the in-kernel dot_general ('highest' runs
     f32 operands through full-precision passes).  Without it f32 inputs get
@@ -1648,6 +1663,7 @@ def tri_matmul(
                 Am, Bm, a_uplo=a_uplo, a_trans=a_trans, b_uplo=b_uplo,
                 b_trans=b_trans, out_uplo=out_uplo, alpha=alpha, blocks=blocks,
                 interpret=interpret, vmem_limit=vmem_limit, precision=precision,
+                out_tiles=out_tiles,
             )
             if fused_c:
                 Cw = c if c_view is None else _window(c, c_view)
@@ -1684,7 +1700,8 @@ def tri_matmul(
     spec = _MatmulSpec(
         M=M, K=K, N=N, blocks=(bm, bn, bk),
         a_uplo=a_uplo, a_trans=a_trans, b_uplo=b_uplo, b_trans=b_trans,
-        out_uplo=out_uplo, alpha=alpha, beta=beta, alias=alias,
+        out_uplo=out_uplo, alpha=alpha, beta=beta,
+        out_tiles=out_tiles and out_uplo is not None, alias=alias,
         out_dtype=jnp.dtype(out_dtype), acc_dtype=jnp.dtype(acc_dtype),
         precision=precision, interpret=interpret, vmem_limit=vmem_limit,
         phase=tracing.active_phase(home),

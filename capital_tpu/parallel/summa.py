@@ -45,6 +45,7 @@ layouts internally) and are jit-compatible.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from functools import partial
 
@@ -53,6 +54,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from capital_tpu.obs import spans
 from capital_tpu.ops import masking, pallas_tpu
 from capital_tpu.parallel.topology import Grid
 from capital_tpu.utils import tracing
@@ -112,6 +114,23 @@ def default_precision(dtype) -> str | None:
     return None if jnp.dtype(dtype).itemsize < 4 else "highest"
 
 
+def _route(op: str, schedule: str, local: str, grid: Grid, M: int, K: int,
+           N: int):
+    """Count one trmm/syrk site at trace time in `spans.SUMMA_ROUTES` under
+    ``<op>/<schedule>/<local>`` and return the ``summa.route`` span, with
+    the same tags, that its product is traced under.  `schedule` is what
+    ran: ``pallas`` (the single-device kernels), ``xla`` (GSPMD), or
+    ``explicit_block`` / ``explicit_cyclic`` / ``explicit_persistent``;
+    `local` is each shard's product: ``live_tiles`` (a Pallas kernel that
+    skips the dead triangle's tiles), ``dense`` (XLA dots that multiply
+    part of it) or ``gspmd``."""
+    name = f"{op}/{schedule}/{local}"
+    tags = {"M": M, "K": K, "N": N, "dx": grid.dx, "dy": grid.dy,
+            "c": grid.c}
+    spans.SUMMA_ROUTES.take(name, **tags)
+    return spans.span("summa.route", route=name, **tags)
+
+
 # --------------------------------------------------------------------------
 # explicit shard_map schedule
 # --------------------------------------------------------------------------
@@ -153,6 +172,29 @@ def tile_cyclic_perm(m: int, d: int, tile: int):
     return perm, inv
 
 
+def cyclic_permute(X: jnp.ndarray, d: int, tile: int, axes=(0, 1),
+                   inverse: bool = False) -> jnp.ndarray:
+    """X[perm] along each of `axes` (perm = tile_cyclic_perm(m, d, tile)),
+    or X[inv] with inverse: the same shuffle as the index gather, spelled
+    as a reshape and a transpose of tile indices, (m/(d·tile), d) ->
+    (d, m/(d·tile)).  On a block-sharded axis that is one all-to-all of
+    the array; a gather by a constant index array is partitioned by
+    replicating the operand first (an n² buffer a chip)."""
+    for ax in axes:
+        m = X.shape[ax]
+        if m % (d * tile):
+            raise ValueError(f"cyclic_permute: {d} devices x tile {tile} "
+                             f"must tile {m}")
+        nt = m // (d * tile)
+        split = (d, nt) if inverse else (nt, d)
+        shape = X.shape[:ax] + split + (tile,) + X.shape[ax + 1:]
+        order = list(range(X.ndim + 2))
+        order[ax], order[ax + 1] = order[ax + 1], order[ax]
+        X = X.reshape(shape).transpose(order).reshape(
+            X.shape[:ax] + (m,) + X.shape[ax + 1:])
+    return X
+
+
 def cyclic_window(V: jnp.ndarray, view, d: int, tile: int) -> jnp.ndarray:
     """Extract the LOGICAL window ``view = (r0, c0, rows, cols)`` of a buffer
     stored in the PERSISTENT symmetric tile-cyclic layout V = X[perm][:, perm]
@@ -175,9 +217,8 @@ def cyclic_window(V: jnp.ndarray, view, d: int, tile: int) -> jnp.ndarray:
             f"cyclic_window: view {view} of {(p, pc)} must align to "
             f"d*tile = {g}"
         )
-    W = V.reshape(d, p // g, tile, pc)[:, r0 // g : (r0 + rows) // g]
-    W = W.reshape(rows, pc)
-    W = W.reshape(rows, d, pc // g, tile)[:, :, c0 // g : (c0 + cols) // g]
+    V6 = V.reshape(d, p // g, tile, d, pc // g, tile)
+    W = V6[:, r0 // g : (r0 + rows) // g, :, :, c0 // g : (c0 + cols) // g]
     return W.reshape(rows, cols)
 
 
@@ -186,9 +227,9 @@ def cyclic_window_update(
 ) -> jnp.ndarray:
     """Write a window-local tile-cyclic result W back into the window `view`
     of the persistent-layout buffer V (inverse of cyclic_window; V is
-    consumed).  Touches only the window's chunk slices — the read-modify-
-    write is band-sized, not buffer-sized (the whole-buffer dus round-trip
-    this layout exists to remove)."""
+    consumed).  One update of the window's chunk slices in a 6-d view of
+    V, in place and shard-local: no band of V is materialized (the
+    whole-buffer dus round-trip this layout exists to remove)."""
     r0, c0, rows, cols = view
     p, pc = V.shape
     g = d * tile
@@ -199,15 +240,9 @@ def cyclic_window_update(
         )
     a, b = r0 // g, (r0 + rows) // g
     e, f = c0 // g, (c0 + cols) // g
-    V4 = V.reshape(d, p // g, tile, pc)
-    band = V4[:, a:b].reshape(rows, pc)
-    band = (
-        band.reshape(rows, d, pc // g, tile)
-        .at[:, :, e:f]
-        .set(W.astype(V.dtype).reshape(rows, d, f - e, tile))
-        .reshape(rows, pc)
-    )
-    return V4.at[:, a:b].set(band.reshape(d, b - a, tile, pc)).reshape(p, pc)
+    V6 = V.reshape(d, p // g, tile, d, pc // g, tile)
+    W6 = W.astype(V.dtype).reshape(d, b - a, tile, d, f - e, tile)
+    return V6.at[:, a:b, :, :, e:f].set(W6).reshape(p, pc)
 
 
 def _pick_cyclic_tile(grid: Grid, dim: int, override: int) -> int:
@@ -561,6 +596,37 @@ def _shard_sched_gate(grid, M, K, N, a_uplo, b_uplo, out_uplo,
     return _sched_pairs(grid, M, K, N, a_uplo, b_uplo)
 
 
+def _cyclic_out_blocks(grid, M, K, N, out_uplo, cyclic_out, itemsize):
+    """The tri_matmul blocks of the tile-cyclic tri-output (syrk) route, or
+    None.  Under the cyclic layout local output tile (ti, tj) of device
+    (xi, yi) is original tile pair (ti*d + xi, tj*d + yi), which touches
+    the upper triangle iff ti < tj, or ti == tj and xi <= yi (|xi - yi| <
+    d): every shard's live tiles lie in the upper triangle of its local
+    tiles, diagonal tiles whole — what tri_matmul's out_uplo skipping
+    visits, with no element mask (out_tiles), at any block that is a whole
+    number of layout tiles (the lower triangle likewise).  One Pallas
+    kernel per shard then replaces the (local tiles)² XLA dots the
+    cyclic_out loop unrolls.  Needs c == 1, an unchunked grid and
+    128-aligned local extents; shared by the router and the cost model.
+    Layout tiles wider than the kernel's default block (the per-call
+    tile_cyclic picks ~a quarter of the local extent) keep the XLA loop:
+    their blocks would not fit the VMEM."""
+    d, c = grid.dx, grid.c
+    if (not cyclic_out or out_uplo is None or c != 1
+            or max(1, grid.num_chunks) != 1):
+        return None
+    mb, nb = M // d, N // d
+    if mb % 128 or nb % 128 or K % 128 or cyclic_out % 128:
+        return None
+    bm, bn, bk = pallas_tpu.default_blocks(mb, K, nb, itemsize)
+    if cyclic_out > min(bm, bn):
+        return None  # a layout tile wider than a block: the XLA loop
+    bm, bn = bm // cyclic_out * cyclic_out, bn // cyclic_out * cyclic_out
+    if mb % bm or nb % bn or K % bk:
+        return None
+    return bm, bn, bk
+
+
 def _explicit_matmul(
     grid: Grid,
     A: jnp.ndarray,
@@ -710,8 +776,15 @@ def _explicit_matmul(
     shard_kernels = _shard_kernels_gate(
         grid, M, K, N, a_uplo, b_uplo, out_uplo, cyclic_rows, cyclic_out
     )
+    cyc_blocks = _cyclic_out_blocks(
+        grid, M, K, N, out_uplo, cyclic_out,
+        jnp.dtype(jnp.result_type(A, B)).itemsize,
+    )
     if shard_kernels:
         tracing.note("explicit::shard_kernels")
+        sched = None
+    elif cyc_blocks is not None:
+        tracing.note("explicit::cyclic_out_kernel")
         sched = None
     elif sched is None:  # direct callers: build what _matmul forwards
         sched = _shard_sched_gate(
@@ -748,12 +821,13 @@ def _explicit_matmul(
                 token[0] = lax.slice(res.reshape(-1), (0,), (1,))
             return res
 
-        if shard_kernels:
+        if shard_kernels or cyc_blocks is not None:
             a_ch = stamp(lax.all_gather(chain(a), "y", axis=1, tiled=True))
             b_ch = stamp(lax.all_gather(chain(b), "x", axis=0, tiled=True))
             if out_uplo is not None:
                 part = pallas_tpu.tri_matmul(
-                    a_ch, b_ch, out_uplo=out_uplo, precision=precision
+                    a_ch, b_ch, out_uplo=out_uplo, precision=precision,
+                    blocks=cyc_blocks, out_tiles=cyc_blocks is not None,
                 )
             else:
                 part = pallas_tpu.tri_matmul(
@@ -961,7 +1035,8 @@ def _explicit_matmul(
         mesh=grid.mesh,
         in_specs=(P("x", "y"), P("x", "y")),
         out_specs=P("x", "y"),
-        check_vma=not (shard_kernels or sched is not None),
+        check_vma=not (shard_kernels or sched is not None
+                       or cyc_blocks is not None),
     )(grid.pin(A), grid.pin(B))
 
 
@@ -982,6 +1057,8 @@ def _matmul(
     cyclic_rows: int = 0,
     cyclic_out: int = 0,
     sched_override=None,
+    op: str | None = None,
+    schedule: str = "explicit_block",
 ) -> jnp.ndarray:
     """The uplo flags describe triangular structure of the (already masked)
     operands/result; only mode='explicit' exploits them (dead K-segments /
@@ -991,14 +1068,18 @@ def _matmul(
     which with block distribution still runs up to the full contraction —
     see tri_fractions).  sched_override hands in an externally built
     per-device tile schedule (_sched_pairs_cyclic — the persistent layout,
-    whose liveness the gates here cannot derive from shapes alone)."""
+    whose liveness the gates here cannot derive from shapes alone).
+    A trmm or syrk passes its `op` and the explicit `schedule` it runs, and
+    the product is counted and spanned as that site's route (_route)."""
     # cost-model attribution (no-op without an active tracing.Recorder)
     M, K, N = A.shape[0], A.shape[1], B.shape[1]
     flops, comm, ncoll = tracing.gemm_cost(
         grid, M, N, K, jnp.result_type(A, B)
     )
+    local = "gspmd"
     if mode == "explicit":
         sched = None
+        local = "live_tiles"
         if sched_override is not None:
             sched = sched_override
             mean_f = max_f = sched[1]
@@ -1007,6 +1088,13 @@ def _matmul(
         ):
             # per-shard live-tile kernels: same /2 executed convention as
             # the single-device pallas branches (tile skipping)
+            mean_f = max_f = 0.5
+        elif _cyclic_out_blocks(
+            grid, M, K, N, out_uplo, cyclic_out,
+            jnp.dtype(jnp.result_type(A, B)).itemsize,
+        ):
+            # the cyclic tri-output kernel per shard: the balanced layout
+            # leaves every shard about half its tiles
             mean_f = max_f = 0.5
         elif (
             sched := _shard_sched_gate(
@@ -1018,6 +1106,7 @@ def _matmul(
             # padded maximum schedule, so mean == max == L/(nt*nk)
             mean_f = max_f = sched[1]
         else:
+            local = "dense"
             mean_f, max_f = tri_fractions(
                 grid, M, K, N, a_uplo, b_uplo, out_uplo,
                 cyclic_rows=cyclic_rows, cyclic_out=cyclic_out,
@@ -1028,14 +1117,21 @@ def _matmul(
         flops=flops, comm_bytes=comm, collectives=ncoll,
         flops_vol=flops * mean_f, flops_max=flops * max_f,
     )
-    if mode in ("xla", "pallas"):  # gemm has no dead blocks: XLA is optimal
-        return grid.pin(jnp.matmul(grid.pin(A), grid.pin(B), precision=precision))
-    if mode == "explicit":
-        return _explicit_matmul(
-            grid, A, B, precision, a_uplo, b_uplo, out_uplo, cyclic_rows,
-            cyclic_out, sched=sched,
-        )
-    raise ValueError(f"unknown summa mode {mode!r}")
+    if mode not in ("xla", "pallas", "explicit"):
+        raise ValueError(f"unknown summa mode {mode!r}")
+    route = contextlib.nullcontext()
+    if op is not None:
+        route = _route(op, schedule if mode == "explicit" else "xla", local,
+                       grid, M, K, N)
+    with route:
+        if mode == "explicit":
+            return _explicit_matmul(
+                grid, A, B, precision, a_uplo, b_uplo, out_uplo, cyclic_rows,
+                cyclic_out, sched=sched,
+            )
+        # gemm has no dead blocks: XLA is optimal
+        return grid.pin(jnp.matmul(grid.pin(A), grid.pin(B),
+                                   precision=precision))
 
 
 @pallas_tpu.scoped_by_grid
@@ -1143,12 +1239,14 @@ def _trmm_persistent(
         )
         if sched is None:
             tracing.note("trmm::persistent_dense")
-            res = _matmul(grid, Top, Bw, mode, args.precision)
+            res = _matmul(grid, Top, Bw, mode, args.precision, op="trmm",
+                          schedule="explicit_persistent")
         else:
             tracing.note("trmm::persistent_cyclic")
             res = _matmul(
                 grid, Top, Bw, mode, args.precision, a_uplo=eff_uplo,
-                sched_override=sched,
+                sched_override=sched, op="trmm",
+                schedule="explicit_persistent",
             )
     elif args.side == "R":
         sched = _sched_pairs_cyclic(
@@ -1156,12 +1254,14 @@ def _trmm_persistent(
         )
         if sched is None:
             tracing.note("trmm::persistent_dense")
-            res = _matmul(grid, Bw, Top, mode, args.precision)
+            res = _matmul(grid, Bw, Top, mode, args.precision, op="trmm",
+                          schedule="explicit_persistent")
         else:
             tracing.note("trmm::persistent_cyclic")
             res = _matmul(
                 grid, Bw, Top, mode, args.precision, b_uplo=eff_uplo,
-                sched_override=sched,
+                sched_override=sched, op="trmm",
+                schedule="explicit_persistent",
             )
     else:
         raise ValueError(f"side must be 'L' or 'R', got {args.side!r}")
@@ -1195,7 +1295,7 @@ def _syrk_persistent(grid, A, C, args, mode, a_view, c_view, in_place,
     Aop = (Aw.T, Aw) if args.trans else (Aw, Aw.T)
     D = _matmul(
         grid, Aop[0], Aop[1], mode, args.precision, out_uplo=args.uplo,
-        cyclic_out=t,
+        cyclic_out=t, op="syrk", schedule="explicit_persistent",
     )
     tracing.note("syrk::persistent_cyclic")
     live = masking.take_triangle_cyclic(D, args.uplo, d, t)
@@ -1307,19 +1407,23 @@ def trmm(
             )
         else:
             tracing.emit(flops=flops / 2, comm_bytes=comm, collectives=ncoll)
-        if args.side == "L":
-            return pallas_tpu.tri_matmul(
-                A, B, a_uplo=args.uplo, a_trans=args.trans_a,
-                alpha=args.alpha, precision=args.precision,
-                a_view=a_view, b_view=b_view, out=out, out_off=out_off,
-            )
-        elif args.side == "R":
+        if args.side not in ("L", "R"):
+            raise ValueError(f"side must be 'L' or 'R', got {args.side!r}")
+        mkn = ((a_dims[0], a_dims[1], b_dims[1]) if args.side == "L"
+               else (b_dims[0], b_dims[1], a_dims[1]))
+        with _route("trmm", "pallas" if mode == "pallas" else
+                    "explicit_block", "live_tiles", grid, *mkn):
+            if args.side == "L":
+                return pallas_tpu.tri_matmul(
+                    A, B, a_uplo=args.uplo, a_trans=args.trans_a,
+                    alpha=args.alpha, precision=args.precision,
+                    a_view=a_view, b_view=b_view, out=out, out_off=out_off,
+                )
             return pallas_tpu.tri_matmul(
                 B, A, b_uplo=args.uplo, b_trans=args.trans_a,
                 alpha=args.alpha, precision=args.precision,
                 a_view=b_view, b_view=a_view, out=out, out_off=out_off,
             )
-        raise ValueError(f"side must be 'L' or 'R', got {args.side!r}")
     if balance == "tile_cyclic_persistent":
         return _trmm_persistent(
             grid, A, B, args, mode, a_view, b_view, out, out_off, cyclic_tile
@@ -1345,7 +1449,6 @@ def trmm(
             else 0
         )
         if tile:
-            perm, inv = tile_cyclic_perm(M, grid.dx, tile)
             # two row-shuffles priced like grid transposes (block
             # exchanges across the face): the M x M triangular operand in,
             # the M x N product out
@@ -1353,17 +1456,21 @@ def trmm(
             comm_o, nc_o = tracing.transpose_cost(grid, M, Bw.shape[1], Top.dtype)
             tracing.emit(comm_bytes=comm_a + comm_o, collectives=nc_a + nc_o)
             res = _matmul(
-                grid, grid.pin(Top[jnp.asarray(perm)]), Bw, mode,
-                args.precision, a_uplo=eff_uplo, cyclic_rows=tile,
+                grid, grid.pin(cyclic_permute(Top, grid.dx, tile, (0,))),
+                Bw, mode, args.precision, a_uplo=eff_uplo, cyclic_rows=tile,
+                op="trmm", schedule="explicit_cyclic",
             )
-            res = grid.pin(res[jnp.asarray(inv)])
+            res = grid.pin(cyclic_permute(res, grid.dx, tile, (0,),
+                                          inverse=True))
         else:
             tracing.note("trmm::tile_cyclic_fallback")
     if res is None:
         if args.side == "L":
-            res = _matmul(grid, Top, Bw, mode, args.precision, a_uplo=eff_uplo)
+            res = _matmul(grid, Top, Bw, mode, args.precision, a_uplo=eff_uplo,
+                          op="trmm")
         elif args.side == "R":
-            res = _matmul(grid, Bw, Top, mode, args.precision, b_uplo=eff_uplo)
+            res = _matmul(grid, Bw, Top, mode, args.precision, b_uplo=eff_uplo,
+                          op="trmm")
         else:
             raise ValueError(f"side must be 'L' or 'R', got {args.side!r}")
     if args.alpha != 1.0:
@@ -1485,14 +1592,16 @@ def syrk(
                 out=C,
                 out_off=(c_view[0], c_view[1]) if c_view is not None else (0, 0),
             )
-        return pallas_tpu.tri_matmul(
-            A, A,
-            a_trans=args.trans, b_trans=not args.trans,
-            out_uplo=args.uplo, alpha=args.alpha, precision=args.precision,
-            a_view=a_view, b_view=a_view,
-            c=C, c_view=c_view, beta=args.beta,
-            **out_kw,
-        )
+        with _route("syrk", "pallas" if mode == "pallas" else
+                    "explicit_block", "live_tiles", grid, n_out, k_in, n_out):
+            return pallas_tpu.tri_matmul(
+                A, A,
+                a_trans=args.trans, b_trans=not args.trans,
+                out_uplo=args.uplo, alpha=args.alpha, precision=args.precision,
+                a_view=a_view, b_view=a_view,
+                c=C, c_view=c_view, beta=args.beta,
+                **out_kw,
+            )
     if balance == "tile_cyclic_persistent":
         return _syrk_persistent(
             grid, A, C, args, mode, a_view, c_view, in_place, cyclic_tile
@@ -1515,14 +1624,12 @@ def syrk(
         # of whole blocks being dead — the syrk analog of trmm's balanced
         # schedule (see trmm's docstring; same decision calculus).
         cyc = 0
-        perm = inv = None
         if balance == "tile_cyclic":
             n_out = Aw.shape[1] if args.trans else Aw.shape[0]
             T = _pick_cyclic_tile(grid, n_out, cyclic_tile)
             if T:
-                perm, inv = tile_cyclic_perm(n_out, grid.dx, T)
-                pj = jnp.asarray(perm)
-                Aw = Aw[:, pj] if args.trans else Aw[pj, :]
+                Aw = cyclic_permute(Aw, grid.dx, T, (1,) if args.trans
+                                    else (0,))
                 cyc = T
                 # three shuffles, each priced at its true shape: the whole
                 # A operand in, then C's rows AND cols out (two n_out²
@@ -1535,18 +1642,18 @@ def syrk(
         Aop = (Aw.T, Aw) if args.trans else (Aw, Aw.T)
         D = _matmul(
             grid, Aop[0], Aop[1], mode, args.precision, out_uplo=args.uplo,
-            cyclic_out=cyc,
+            cyclic_out=cyc, op="syrk",
+            schedule="explicit_cyclic" if cyc else "explicit_block",
         )
         if cyc:
-            ij = jnp.asarray(inv)
-            D = grid.pin(D[ij][:, ij])
+            D = grid.pin(cyclic_permute(D, grid.dx, cyc, inverse=True))
         if args.uplo == "U":
             out = jnp.triu(D) + transpose(grid, jnp.triu(D, 1))
         else:
             out = jnp.tril(D) + transpose(grid, jnp.tril(D, -1))
     else:
         Aop = (Aw.T, Aw) if args.trans else (Aw, Aw.T)
-        out = _matmul(grid, Aop[0], Aop[1], mode, args.precision)
+        out = _matmul(grid, Aop[0], Aop[1], mode, args.precision, op="syrk")
     if args.alpha != 1.0:
         out = args.alpha * out
     # copy-bytes attribution (see trmm): the .T operand, window slices, the

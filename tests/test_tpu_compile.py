@@ -233,3 +233,39 @@ def test_update_small(chip):
         lambda r, v: update_small.chol_update(r, v, impl="pallas"), R, V,
         scope=chip,
     ) == 1
+
+
+def test_cholinv_mesh_cell(topo, monkeypatch):
+    """The mesh cell's program on a described 2x2 face at n = 2048: the
+    cell names the explicit schedule, while 'auto' stays xla on a TPU
+    mesh; under the persistent
+    tile-cyclic balance every trmm and syrk site runs a live-tile kernel
+    per shard, the leaves are XLA's replicated pair, and the panels move
+    by all-gathers."""
+    import json
+
+    from capital_tpu.parallel import summa
+
+    cell = json.load(open(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmark", "configs", "cholinv-mesh.json")))
+    routes, chol = spans.RouteCounter(), spans.RouteCounter()
+    monkeypatch.setattr(spans, "SUMMA_ROUTES", routes)
+    monkeypatch.setattr(spans, "CHOL_ROUTES", chol)
+    g = Grid.square(c=1, devices=topo.devices[:4])
+    assert summa.resolve_mode("auto", g) == "xla"
+    mode = summa.resolve_mode(cell["mode"], g)
+    assert mode == "explicit"
+    n = 2048
+    cfg = cholesky.CholinvConfig(
+        base_case_dim=cell["base_case_dim"], mode=mode,
+        balance=cell["balance"], precision=None, schur_in_place=True)
+    A = jax.ShapeDtypeStruct((n, n), jnp.bfloat16, sharding=g.face_sharding())
+    txt = jax.jit(lambda a, rp, rip: cholesky.factor(
+        g, a, cfg, out_buffers=(rp, rip)), donate_argnums=(1, 2)).lower(
+        A, A, A).compile().as_text()
+    assert set(routes.snapshot()) == {"trmm/explicit_persistent/live_tiles",
+                                      "syrk/explicit_persistent/live_tiles"}
+    assert chol.snapshot() == {"potrf_trtri/xla": {"builds": 4, "n": 512}}
+    assert "tpu_custom_call" in txt
+    assert re.search(r"all-gather(?:-start)?\(", txt)
